@@ -1,53 +1,24 @@
-"""jax version compatibility shims.
+"""The two jax names the tree imports from one place.
 
-The tree targets the current jax surface (``jax.shard_map`` with the
-``check_vma`` kwarg, the ``jax_num_cpu_devices`` config); CI images and
-user installs routinely lag a few minor versions behind, where the same
-functionality lives under ``jax.experimental.shard_map`` (kwarg
-``check_rep``) and the CPU device count is an XLA flag.  Everything in
-the repo imports these names from here so a version skew degrades to a
-one-line shim instead of an ImportError at collection time — the same
-fail-soft posture as ``native.available()``.
+``shard_map`` is ``jax.shard_map`` (keywords ``mesh=``, ``in_specs=``,
+``out_specs=``, ``check_vma=``); ``force_cpu_devices`` is the
+``jax_num_cpu_devices`` config.  The code targets the one installation
+there is (jax 0.9): a name this jax does not offer is an ImportError at
+collection time, not a silent second path.  The module stays so the
+import sites and graftlint's traced-region discovery
+(``analysis/context.py``) keep one spelling.
 """
 
 from __future__ import annotations
 
-import os
+import jax
+from jax import shard_map  # noqa: F401 — re-exported
 
-try:                                    # jax >= 0.5
-    from jax import shard_map as _shard_map
-    _REP_KWARG = "check_vma"
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KWARG = "check_rep"
-
-
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` under either spelling of the replication-check
-    kwarg.  Call with keywords (``mesh=``, ``in_specs=``, ``out_specs=``,
-    ``check_vma=``) — positional use would silently bind differently
-    across versions."""
-    if _REP_KWARG != "check_vma" and "check_vma" in kwargs:
-        kwargs[_REP_KWARG] = kwargs.pop("check_vma")
-    return _shard_map(f, **kwargs)
+__all__ = ["shard_map", "force_cpu_devices"]
 
 
 def force_cpu_devices(n: int) -> None:
     """Ask for ``n`` virtual CPU devices (the local[N] test topology).
-
-    Newer jax exposes this as the ``jax_num_cpu_devices`` config; older
-    versions only honour the XLA host-platform flag, which must land in
-    the environment before the CPU backend is instantiated.  Call before
-    any ``jax.devices()``/array op.
-    """
-    import jax
-
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-        return
-    except AttributeError:
-        pass
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}").strip()
+    Call before any ``jax.devices()``/array op instantiates the CPU
+    backend."""
+    jax.config.update("jax_num_cpu_devices", n)

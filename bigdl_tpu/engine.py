@@ -186,11 +186,7 @@ class Engine:
 
     @staticmethod
     def _distributed_already_up() -> bool:
-        try:
-            return bool(jax.distributed.is_initialized())
-        except AttributeError:          # older jax: inspect global state
-            state = getattr(jax.distributed, "global_state", None)
-            return getattr(state, "coordinator_address", None) is not None
+        return bool(jax.distributed.is_initialized())
 
     @staticmethod
     def _env_says_multihost() -> Optional[str]:

@@ -28,6 +28,7 @@ def train_main(argv=None):
     from bigdl_tpu.engine import Engine
     from bigdl_tpu.nn import MSECriterion
     from bigdl_tpu.optim import Optimizer, SGD, Trigger
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("autoencoder-train")
@@ -40,6 +41,7 @@ def train_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     train = load_mnist(f"{args.folder}/train-images-idx3-ubyte",
                        f"{args.folder}/train-labels-idx1-ubyte")

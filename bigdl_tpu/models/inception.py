@@ -239,6 +239,7 @@ def train_main(argv=None):
     from bigdl_tpu.nn import ClassNLLCriterion
     from bigdl_tpu.optim import (Optimizer, Poly, SGD, Top1Accuracy,
                                  Top5Accuracy, Trigger)
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("inception-train")
@@ -263,6 +264,7 @@ def train_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     train_set = _imagenet_set(args.folder, args.batchSize, train=True,
                               total_size=args.trainSize)
@@ -313,6 +315,7 @@ def test_main(argv=None):
     from bigdl_tpu.engine import Engine
     from bigdl_tpu.optim import (LocalValidator, Top1Accuracy,
                                  Top5Accuracy)
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("inception-test")
@@ -327,6 +330,7 @@ def test_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     mk = Inception_v1 if args.net == "inception_v1" else Inception_v2
     model = mk(args.classNum)

@@ -50,8 +50,10 @@ def train_main(argv=None):
     p.add_argument("--state", default=None, help="state snapshot to resume")
     args = p.parse_args(argv)
 
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
     init_logging()
+    enable_compile_cache()
     Engine.init()
     train_mean, train_std = 0.13066047740239506, 0.3081078
 
@@ -100,6 +102,7 @@ def test_main(argv=None):
     from bigdl_tpu.engine import Engine
     from bigdl_tpu.optim import LocalValidator, Top1Accuracy
     from bigdl_tpu.utils.file import load_model_snapshot
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("lenet-test")
@@ -109,6 +112,7 @@ def test_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     val = load_mnist(f"{args.folder}/t10k-images-idx3-ubyte",
                      f"{args.folder}/t10k-labels-idx1-ubyte")

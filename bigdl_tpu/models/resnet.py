@@ -169,6 +169,7 @@ def train_main(argv=None):
     from bigdl_tpu.nn import CrossEntropyCriterion
     from bigdl_tpu.optim import (EpochDecay, Optimizer, SGD, Top1Accuracy,
                                  Trigger)
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("resnet-train")
@@ -190,6 +191,7 @@ def train_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     train_set = DataSet.array(load_cifar10(args.folder, train=True)) >> \
         BytesToBGRImg() >> BGRImgNormalizer(CIFAR10_TRAIN_MEAN, CIFAR10_TRAIN_STD) >> \
@@ -236,6 +238,7 @@ def test_main(argv=None):
                                            CIFAR10_TEST_STD)
     from bigdl_tpu.optim import LocalValidator, Top1Accuracy
     from bigdl_tpu.utils.file import load_model_snapshot
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("resnet-test")
@@ -248,6 +251,7 @@ def test_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     val_set = DataSet.array(load_cifar10(args.folder, train=False)) >> \
         BytesToBGRImg() >> BGRImgNormalizer(CIFAR10_TEST_MEAN, CIFAR10_TEST_STD) >> \

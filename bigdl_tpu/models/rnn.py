@@ -56,6 +56,7 @@ def train_main(argv=None):
     from bigdl_tpu.engine import Engine
     from bigdl_tpu.nn import ClassNLLCriterion, TimeDistributedCriterion
     from bigdl_tpu.optim import Loss, Optimizer, SGD, Trigger
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("rnn-train")
@@ -75,6 +76,7 @@ def train_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     dictionary_length = args.vocab + 1
     WordTokenizer(f"{args.folder}/input.txt", args.folder,
@@ -129,6 +131,7 @@ def test_main(argv=None):
     from bigdl_tpu.dataset.text import Dictionary, read_sentence
     from bigdl_tpu.engine import Engine
     from bigdl_tpu.utils.file import load_model_snapshot
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
     from bigdl_tpu.utils.random_generator import RNG
 
@@ -141,6 +144,7 @@ def test_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     vocab = Dictionary(args.folder)
     dictionary_length = args.vocab + 1

@@ -526,6 +526,7 @@ def train_main(argv=None):
     from bigdl_tpu.nn import ClassNLLCriterion, TimeDistributedCriterion
     from bigdl_tpu.optim import (Adam, Loss, Optimizer, SGD, Trigger,
                                  Warmup)
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("transformer-train")
@@ -551,6 +552,7 @@ def train_main(argv=None):
                 "analogous knob)")
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     dictionary_length = args.vocab + 1
     WordTokenizer(f"{args.folder}/input.txt", args.folder,
@@ -611,6 +613,7 @@ def generate_main(argv=None):
     from bigdl_tpu.dataset.text import Dictionary, read_sentence
     from bigdl_tpu.engine import Engine
     from bigdl_tpu.utils.file import load_model_snapshot
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("transformer-generate")
@@ -630,6 +633,7 @@ def generate_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     dictionary_length = args.vocab + 1
     vocab = Dictionary(args.folder)

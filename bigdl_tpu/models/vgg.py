@@ -112,6 +112,7 @@ def train_main(argv=None):
     from bigdl_tpu.nn import ClassNLLCriterion
     from bigdl_tpu.optim import (EpochStep, Optimizer, SGD, Top1Accuracy,
                                  Trigger)
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("vgg-train")
@@ -125,6 +126,7 @@ def train_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     train_set = _cifar_set(args.folder, args.batchSize, train=True)
     val_set = _cifar_set(args.folder, args.batchSize, train=False)
@@ -159,6 +161,7 @@ def test_main(argv=None):
     from bigdl_tpu.engine import Engine
     from bigdl_tpu.optim import LocalValidator, Top1Accuracy
     from bigdl_tpu.utils.file import load_model_snapshot
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
     from bigdl_tpu.utils.log import init_logging
 
     p = argparse.ArgumentParser("vgg-test")
@@ -168,6 +171,7 @@ def test_main(argv=None):
     args = p.parse_args(argv)
 
     init_logging()
+    enable_compile_cache()
     Engine.init()
     val_set = _cifar_set(args.folder, args.batchSize, train=False)
     model = VggForCifar10(10)

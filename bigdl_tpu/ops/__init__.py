@@ -57,13 +57,12 @@ __all__ = [
 
 def pallas_enabled() -> bool:
     """True when the compiled Pallas kernels should be used (TPU backend,
-    not disabled via ``BIGDL_TPU_DISABLE_PALLAS=1``)."""
+    not disabled via ``BIGDL_TPU_DISABLE_PALLAS=1``).  A backend that
+    cannot initialise raises here: a busy or missing chip must not turn
+    every kernel into its jnp reference unseen."""
     if os.environ.get("BIGDL_TPU_DISABLE_PALLAS", "0") == "1":
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 from bigdl_tpu.ops.attention import (  # noqa: E402

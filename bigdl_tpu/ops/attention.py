@@ -788,26 +788,34 @@ def _paged_kernel(pages_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
     b = pl.program_id(0)
     l = pl.program_id(2)
     is_trash = pages_ref[b, l] == trash
-    k_scr[pl.ds(l * ps, ps), :] = jnp.where(is_trash, 0, k_ref[0, 0])
-    v_scr[pl.ds(l * ps, ps), :] = jnp.where(is_trash, 0, v_ref[0, 0])
+    row = pl.multiple_of(l * ps, ps)
+    k_scr[pl.ds(row, ps), :] = jnp.where(is_trash, 0, k_ref[0, 0])
+    v_scr[pl.ds(row, ps), :] = jnp.where(is_trash, 0, v_ref[0, 0])
 
     @pl.when(l == lp - 1)
     def _compute():
         q = q_ref[0, 0]                              # (S, D)
         kk = k_scr[...]                              # (L, D) cache dtype
         vv = v_scr[...]
-        # OPERATION-FOR-OPERATION the reference gather path's math,
-        # including its dtype promotion: jnp.einsum promotes mixed
-        # operands exactly as the reference einsum does (bf16 x bf16
-        # scores stay bf16 there — an eager f32 promotion here would
-        # break the bit-parity gate on bf16 caches), then the same
-        # -inf validity mask, f32 softmax and cache-dtype weighted sum
-        s = jnp.einsum("sd,ld->sl", q, kk) * scale
-        pos = pos_ref[0]                             # (S,)
+        # the reference gather path's math, including its dtype
+        # promotion: scores round to the promoted operand dtype exactly
+        # where the reference einsum does (bf16 x bf16 scores are bf16
+        # there), then the same -inf validity mask, f32 softmax and
+        # cache-dtype weighted sum.  The MXU accumulates in f32 (Mosaic
+        # refuses a narrower accumulator: "Expected matmul acc to be
+        # 32-bit"), which is also what XLA's bf16 dot does before it
+        # rounds — so the rounding point, not the accumulator, is what
+        # the parity gate pins.
+        s = jax.lax.dot_general(
+            q, kk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        s = s.astype(jnp.result_type(q.dtype, kk.dtype)) * scale
         lidx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(lidx <= pos[:, None], s, -jnp.inf)
+        s = jnp.where(lidx <= pos_ref[0], s, -jnp.inf)   # pos (S, 1)
         w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-        o_ref[0, 0] = jnp.einsum("sl,ld->sd", w.astype(vv.dtype), vv)
+        o_ref[0, 0] = jnp.dot(
+            w.astype(vv.dtype), vv,
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, pages, positions, scale):
@@ -836,7 +844,11 @@ def paged_attention(q, k_pool, v_pool, pages, positions, scale):
         in_specs=[
             pl.BlockSpec((1, 1, s, d),
                          lambda bi, hi, li, pg: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, s), lambda bi, hi, li, pg: (bi, 0)),
+            # positions ride as a (B, S, 1) column so every block's last
+            # two dims are the array's own (a (1, S) block over (B, S)
+            # is neither (8, 128)-aligned nor full-width once B > 1) and
+            # the kernel needs no lane-to-sublane relayout
+            pl.BlockSpec((1, s, 1), lambda bi, hi, li, pg: (bi, 0, 0)),
             pl.BlockSpec((1, 1, ps, d),
                          lambda bi, hi, li, pg: (pg[bi, li],
                                                  hi // group, 0, 0)),
@@ -855,4 +867,4 @@ def paged_attention(q, k_pool, v_pool, pages, positions, scale):
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), k_pool.dtype),
         interpret=_interpret(),
     )(jnp.asarray(pages, jnp.int32), q,
-      jnp.asarray(positions, jnp.int32), k_pool, v_pool)
+      jnp.asarray(positions, jnp.int32)[:, :, None], k_pool, v_pool)

@@ -101,21 +101,27 @@ def tune_dir() -> str:
 def platform() -> str:
     """Store partition key: winners measured on one platform must never
     be served to another (a v5e tile layout means nothing on CPU
-    interpret timings and vice versa)."""
-    try:
-        import jax
-        backend = jax.default_backend()
-        if backend == "tpu":
-            kind = jax.devices()[0].device_kind
-            return "tpu-" + str(kind).strip().lower().replace(" ", "-")
-        return str(backend)
-    except Exception:
-        return "unknown"
+    interpret timings and vice versa).  A backend that cannot
+    initialise raises — there is no store to serve without one."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "tpu":
+        kind = jax.devices()[0].device_kind
+        return "tpu-" + str(kind).strip().lower().replace(" ", "-")
+    return str(backend)
 
 
 def _store_path(directory: Optional[str] = None) -> str:
     return os.path.join(directory or tune_dir(),
                         f"tune-{platform()}.json")
+
+
+def store_summary() -> Tuple[str, int]:
+    """(path, live entry count) of the store :func:`lookup` reads on
+    this platform — what a bring-up check prints so that a warm store
+    cannot change tiles unseen."""
+    path = _store_path()
+    return path, len(_load_entries(path) or {})
 
 
 def _load_entries(path: str) -> Optional[dict]:

@@ -382,17 +382,21 @@ def abstract_step_args(layout, optim, model_state, mesh,
 def audit_distri_step(model, criterion, optim, mesh, config, batch_shape,
                       compress: Optional[str] = "bf16",
                       rs_mode: str = "a2a",
-                      compiler_options: Optional[dict] = None) -> dict:
+                      compiler_options: Optional[dict] = None,
+                      compute_dtype=None) -> dict:
     """AOT-compile the full distributed train step on ``mesh`` (real
     devices or a deviceless topology) and audit its HLO.  Returns the
     ``audit_hlo_text`` result plus the analytic ``expected`` traffic and
     the ``cross_check`` verdicts.  ``compiler_options`` are forwarded to
-    the XLA compile (e.g. the latency-hiding-scheduler experiment)."""
+    the XLA compile (e.g. the latency-hiding-scheduler experiment);
+    ``compute_dtype`` is the step's own (pass what the trainer runs to
+    audit the trainer's exact program)."""
     from bigdl_tpu.parallel.allreduce import make_distri_train_step
 
     step, layout, _ = make_distri_train_step(
         model, criterion, optim, mesh, config, compress=compress,
-        params_template=model.params, rs_mode=rs_mode)
+        params_template=model.params, rs_mode=rs_mode,
+        compute_dtype=compute_dtype)
     args = abstract_step_args(layout, optim, model.state, mesh,
                               batch_shape)
     lowered = step.lower(*args)

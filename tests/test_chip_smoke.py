@@ -1,0 +1,134 @@
+"""CPU rehearsal of ``chip_smoke.py``: the same phase functions at toy
+sizes on the virtual CPU mesh (kernels under the Pallas interpreter),
+``main()`` refusing a ``cpu`` backend, and the compile-cache helper's
+placement contract."""
+
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+import chip_smoke
+from bigdl_tpu.utils import compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# tier-1 runs under a wall-clock limit: every size here is the smallest
+# that still walks the phase's code
+TOY = dict(input_shape=(8,), classes=4)
+
+
+@pytest.fixture
+def interpret_mode(monkeypatch):
+    monkeypatch.setenv("BIGDL_TPU_PALLAS_INTERPRET", "1")
+
+
+def _toy_model():
+    import bigdl_tpu.nn as nn
+    return nn.Sequential().add(nn.Linear(8, 4)).add(nn.LogSoftMax())
+
+
+def test_phase_device_reports_cache_store_and_native():
+    line = chip_smoke.phase_device("/some/cache")
+    assert "backend=cpu" in line and "pallas=off" in line
+    assert "compile_cache=/some/cache" in line
+    assert "tune-cpu.json entries=" in line
+    assert "native=" in line
+
+
+def test_phase_train_toy():
+    line = chip_smoke.phase_train(_toy_model, batch=8, steps=3, **TOY)
+    assert "steps=3" in line and "params_on=['cpu']" in line
+
+
+def test_phase_train_fails_on_nonfinite_loss():
+    from bigdl_tpu.resilience.fault_injector import FaultInjector
+    FaultInjector.install(FaultInjector().add("grad.nan", step=1))
+    try:
+        with pytest.raises(chip_smoke.SmokeFailure, match="finite losses"):
+            chip_smoke.phase_train(_toy_model, batch=8, steps=3, **TOY)
+    finally:
+        FaultInjector.clear()
+
+
+def test_phase_serve_toy(interpret_mode):
+    line = chip_smoke.phase_serve(
+        vocab=64, embed=32, heads=4, layers=1, max_len=48, buckets=(16, 32),
+        slots=2, prompt_lens=(20, 20, 20), shared_prefix=16, max_new=4,
+        compiled=False)
+    assert "requests=3" in line and "failed=0 shed=0" in line
+    assert "paged_kernel=interpreted" in line
+    assert "prefix_hit_rate=0.00" not in line       # the shared page hit
+    # f32-exact toy model: the scheduler's greedy path IS generate's
+    assert "token_match=3/3" in line
+
+
+def test_interpreted_kernel_does_not_pass_for_compiled(interpret_mode):
+    assert not chip_smoke._paged_kernel_compiled()
+
+
+def test_phase_kernels_toy(interpret_mode):
+    line = chip_smoke.phase_kernels(
+        t_fused=16, t_stream=16, heads=1, head_dim=64, slots=2,
+        max_len=48, page_size=16, prefill=16, matmuls=((2, 128, 128),),
+        conv=(1, 8, 6, 16, 3), fp16_n=1000, compiled=False)
+    assert line.startswith("kernels=11 interpreted")
+
+
+def test_phase_kernels_refuses_the_reference_path():
+    """Without Pallas dispatch the phase would compare the references
+    with themselves: it must fail instead."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="Pallas dispatch"):
+        chip_smoke.phase_kernels()
+
+
+def test_phase_multichip_toy_and_skip():
+    from bigdl_tpu.engine import Engine
+    assert chip_smoke.phase_multichip(min_devices=99) == \
+        f"skipped ({len(jax.devices())} device)"
+    Engine.reset()
+    try:
+        line = chip_smoke.phase_multichip(_toy_model, per_chip_batch=2,
+                                          steps=2, **TOY)
+    finally:
+        Engine.reset()
+    n = len(jax.devices())
+    assert f"devices={n} global_batch={2 * n}" in line
+    assert f"wshard/batch over {n} devices" in line
+
+
+def test_main_exits_nonzero_on_cpu(capsys):
+    assert jax.default_backend() == "cpu"
+    assert chip_smoke.main() != 0
+    out, err = capsys.readouterr()
+    assert '"ok"' not in out
+    assert "no TPU" in err
+
+
+# -- the compile-cache helper -------------------------------------------------
+
+def test_cache_helper_sets_nothing_when_placed_from_outside(monkeypatch):
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/placed/from/outside")
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a, **k: updates.append(a))
+    assert compile_cache.enable_compile_cache() == "/placed/from/outside"
+    assert updates == []
+
+
+def test_cache_helper_default_is_fixed_under_the_checkout(monkeypatch):
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    want = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert compile_cache.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    # a second process, another working directory, no package import:
+    # the path comes from the file's location and nothing else
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import runpy, sys; print(runpy.run_path(sys.argv[1])"
+         "['default_cache_dir']())", compile_cache.__file__],
+        cwd="/", capture_output=True, text=True, timeout=60, check=True)
+    assert out.stdout.strip() == want

@@ -13,6 +13,7 @@ import logging
 import math
 import os
 import struct
+import time
 
 import jax
 import numpy as np
@@ -644,10 +645,15 @@ def test_trace_export_cli_on_synthetic_ledger(tmp_path, capsys):
 
 class _ObsAugment:
     """Module-level (spawn-picklable) pass-through augment chain: its
-    only job is making the ingest workers emit ingest.augment spans."""
+    only job is making the ingest workers emit ingest.augment spans.
+    Each sample costs 20 ms so that the run outlasts the spawn jitter
+    between the two workers (up to ~0.5 s): with a free augment the
+    first worker up can serve the whole run before the second binds,
+    and the >= 3 pids the stitch test asserts become a coin flip."""
 
     def __call__(self, it):
         for s in it:
+            time.sleep(0.02)
             yield s
 
     def clone_transformer(self):

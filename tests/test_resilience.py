@@ -123,7 +123,10 @@ def test_fire_and_should_respect_step_and_count():
 def test_watchdog_fires_on_hung_step():
     with pytest.raises(WatchdogTimeout, match="watchdog"):
         with Watchdog(0.2, label="hung step"):
-            time.sleep(10)
+            # short sleeps: the watchdog's interrupt lands between
+            # bytecodes, and one long C-level sleep would sit it out
+            for _ in range(1000):
+                time.sleep(0.01)
 
 
 def test_watchdog_disarmed_and_fast_path():
