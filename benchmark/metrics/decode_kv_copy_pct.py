@@ -1,0 +1,12 @@
+"""Share of the device time inside the decode-chunk runs spent in copy
+and layout operations whose scope path holds ``attn.paged`` or
+``kv.write``: the relayout of the page pool around the cache's write and
+its paged read."""
+from benchmark import scope_events
+
+UNIT, LAYER, MOVES = "%", "model", "serve_tokens_per_s"
+
+
+def read(run):
+    return scope_events.copy_share_pct(
+        run, run.cell.config["programs"]["decode"], scope_events.KV_SCOPES)

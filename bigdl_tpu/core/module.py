@@ -416,6 +416,24 @@ class Container(Module):
         self.modules.append(module)
         return self
 
+    def child_scope(self, i: int):
+        """``jax.named_scope`` of child ``i``: the name it was given
+        (``set_name``), else its class and its index here — never the
+        default name, whose counter follows the order in which the
+        process built its modules.  Every operation the child traces then
+        carries the path of the containers above it in its ``op_name``
+        (metadata only: the compiled program is the same)."""
+        m = self.modules[i]
+        cls = type(m).__name__
+        default = m.name.startswith(cls + "_") \
+            and m.name[len(cls) + 1:].isdigit()
+        return jax.named_scope(f"{cls}_{i}" if default else m.name)
+
+    def child_apply(self, i: int, params, state, input, **kwargs):
+        """Child ``i``'s ``apply`` under its scope."""
+        with self.child_scope(i):
+            return self.modules[i].apply(params, state, input, **kwargs)
+
     def init(self, rng: jax.Array):
         params, state = [], []
         for i, m in enumerate(self.modules):

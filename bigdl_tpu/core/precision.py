@@ -54,3 +54,27 @@ def mixed_forward(model, params, model_state, data, *,
                             cast_tree(data, compute_dtype),
                             training=training, rng=rng)
     return cast_tree(y, jnp.float32), cast_like(new_ms, model_state)
+
+
+def training_loss(model, criterion, params, model_state, data, labels, rng,
+                  compute_dtype=None):
+    """The loss every trainer differentiates: the model's forward (under
+    the mixed-precision policy when ``compute_dtype`` is given), the
+    criterion and the modules' auxiliary losses.  Returns ``(loss,
+    new_model_state)``.  The ``forward`` and ``loss`` scopes name the two
+    parts in the device trace; their gradients carry the same names
+    inside ``transpose(jvp(...))``."""
+    import jax
+
+    from bigdl_tpu.core.module import collect_aux_losses
+    with jax.named_scope("forward"):
+        if compute_dtype is not None:
+            y, new_ms = mixed_forward(model, params, model_state, data,
+                                      compute_dtype=compute_dtype,
+                                      training=True, rng=rng)
+        else:
+            y, new_ms = model.apply(params, model_state, data,
+                                    training=True, rng=rng)
+    with jax.named_scope("loss"):
+        return criterion.apply(y, labels) + collect_aux_losses(new_ms), \
+            new_ms

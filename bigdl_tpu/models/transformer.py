@@ -91,50 +91,66 @@ class TransformerBlock(Module):
 
     def apply(self, params, state, input, *, training=False, rng=None,
               pos_offset=0, key_padding_mask=None):
-        h, _ = self.ln1.apply(params["ln1"], state["ln1"], input)
-        # training must reach the attention layer: it selects the
-        # fwd+bwd kernel dispatch vs the measured fwd-only (eval) policy
-        a, _ = self.attn.apply(params["attn"], state["attn"], h,
-                               training=training, pos_offset=pos_offset,
-                               key_padding_mask=key_padding_mask)
-        if self.dropout is not None and training:
-            a, _ = self.dropout.apply((), (), a, training=True,
-                                      rng=child_rng(rng, 0))
-        x = input + a
-        h, _ = self.ln2.apply(params["ln2"], state["ln2"], x)
-        new_state = state
-        if self.moe is None:
-            h, _ = self.fc1.apply(params["fc1"], state["fc1"], h)
-            h = jax.nn.gelu(h)
-            h, _ = self.fc2.apply(params["fc2"], state["fc2"], h)
-        else:
-            h, moe_state = self.moe.apply(params["moe"], state["moe"], h,
-                                          training=training)
-            # thread the routing stats (aux load-balance loss, drop rate)
-            # so trainers can collect them from the state tree
-            new_state = dict(state)
-            new_state["moe"] = moe_state
-        if self.dropout is not None and training:
-            h, _ = self.dropout.apply((), (), h, training=True,
-                                      rng=child_rng(rng, 1))
-        return x + h, new_state
+        with jax.named_scope("attn"):
+            h, _ = self.ln1.apply(params["ln1"], state["ln1"], input)
+            # training must reach the attention layer: it selects the
+            # fwd+bwd kernel dispatch vs the measured fwd-only (eval)
+            # policy
+            a, _ = self.attn.apply(params["attn"], state["attn"], h,
+                                   training=training,
+                                   pos_offset=pos_offset,
+                                   key_padding_mask=key_padding_mask)
+            if self.dropout is not None and training:
+                a, _ = self.dropout.apply((), (), a, training=True,
+                                          rng=child_rng(rng, 0))
+            x = input + a
+        with jax.named_scope("mlp"):
+            h, _ = self.ln2.apply(params["ln2"], state["ln2"], x)
+            new_state = state
+            if self.moe is None:
+                h, _ = self.fc1.apply(params["fc1"], state["fc1"], h)
+                h = jax.nn.gelu(h)
+                h, _ = self.fc2.apply(params["fc2"], state["fc2"], h)
+            else:
+                h, moe_state = self.moe.apply(params["moe"], state["moe"],
+                                              h, training=training)
+                # thread the routing stats (aux load-balance loss, drop
+                # rate) so trainers can collect them from the state tree
+                new_state = dict(state)
+                new_state["moe"] = moe_state
+            if self.dropout is not None and training:
+                h, _ = self.dropout.apply((), (), h, training=True,
+                                          rng=child_rng(rng, 1))
+            return x + h, new_state
+
+    def _decode_block(self, params, state, x_t, attend):
+        """What the three incremental variants share: ``attend(attn
+        params, normed x)`` -> ``(attention output, cache')`` through
+        whichever cache layout, then the FFN/MoE as in eval.  The
+        ``attn`` and ``mlp`` scopes name the two halves in the device
+        trace."""
+        with jax.named_scope("attn"):
+            h, _ = self.ln1.apply(params["ln1"], state["ln1"], x_t)
+            a, cache = attend(params["attn"], h)
+            x = x_t + a
+        with jax.named_scope("mlp"):
+            h, _ = self.ln2.apply(params["ln2"], state["ln2"], x)
+            if self.moe is None:
+                h, _ = self.fc1.apply(params["fc1"], state["fc1"], h)
+                h = jax.nn.gelu(h)
+                h, _ = self.fc2.apply(params["fc2"], state["fc2"], h)
+            else:
+                h, _ = self.moe.apply(params["moe"], state["moe"], h,
+                                      training=False)
+            return x + h, cache
 
     def decode_step(self, params, state, cache, x_t, pos):
         """Incremental block application for tokens at [pos, pos+S) —
         attention through the KV cache, FFN/MoE as in eval.  Returns
         (y (B, S, E), cache')."""
-        h, _ = self.ln1.apply(params["ln1"], state["ln1"], x_t)
-        a, cache = self.attn.apply_decode(params["attn"], h, cache, pos)
-        x = x_t + a
-        h, _ = self.ln2.apply(params["ln2"], state["ln2"], x)
-        if self.moe is None:
-            h, _ = self.fc1.apply(params["fc1"], state["fc1"], h)
-            h = jax.nn.gelu(h)
-            h, _ = self.fc2.apply(params["fc2"], state["fc2"], h)
-        else:
-            h, _ = self.moe.apply(params["moe"], state["moe"], h,
-                                  training=False)
-        return x + h, cache
+        return self._decode_block(
+            params, state, x_t,
+            lambda p, h: self.attn.apply_decode(p, h, cache, pos))
 
     def decode_step_pages(self, params, state, cache, x_t, pages, pos,
                           active):
@@ -142,38 +158,20 @@ class TransformerBlock(Module):
         indirection through ``pages`` (B, Lp) into a shared page pool —
         the per-decode-step unit of the PAGED continuous-batching
         scheduler."""
-        h, _ = self.ln1.apply(params["ln1"], state["ln1"], x_t)
-        a, cache = self.attn.apply_decode_pages(params["attn"], h, cache,
-                                                pages, pos, active)
-        x = x_t + a
-        h, _ = self.ln2.apply(params["ln2"], state["ln2"], x)
-        if self.moe is None:
-            h, _ = self.fc1.apply(params["fc1"], state["fc1"], h)
-            h = jax.nn.gelu(h)
-            h, _ = self.fc2.apply(params["fc2"], state["fc2"], h)
-        else:
-            h, _ = self.moe.apply(params["moe"], state["moe"], h,
-                                  training=False)
-        return x + h, cache
+        return self._decode_block(
+            params, state, x_t,
+            lambda p, h: self.attn.apply_decode_pages(p, h, cache, pages,
+                                                      pos, active))
 
     def decode_step_slots(self, params, state, cache, x_t, pos, active):
         """Slot-addressable :meth:`decode_step`: ``pos`` (B,) is each
         cache slot's own depth and ``active`` (B,) gates its cache
         write — the per-decode-step unit of the continuous-batching
         scheduler (``serving/scheduler/continuous.py``)."""
-        h, _ = self.ln1.apply(params["ln1"], state["ln1"], x_t)
-        a, cache = self.attn.apply_decode_slots(params["attn"], h, cache,
-                                                pos, active)
-        x = x_t + a
-        h, _ = self.ln2.apply(params["ln2"], state["ln2"], x)
-        if self.moe is None:
-            h, _ = self.fc1.apply(params["fc1"], state["fc1"], h)
-            h = jax.nn.gelu(h)
-            h, _ = self.fc2.apply(params["fc2"], state["fc2"], h)
-        else:
-            h, _ = self.moe.apply(params["moe"], state["moe"], h,
-                                  training=False)
-        return x + h, cache
+        return self._decode_block(
+            params, state, x_t,
+            lambda p, h: self.attn.apply_decode_slots(p, h, cache, pos,
+                                                      active))
 
 
 class TransformerLM(Module):
@@ -265,13 +263,15 @@ class TransformerLM(Module):
             else:
                 assert t <= self.max_len, \
                     f"shard length {t} exceeds max_len {self.max_len}"
-            x = _embed_rows(params["tok"], ids) + \
-                jax.lax.dynamic_slice_in_dim(
-                    params["pos"], pos_offset, t, axis=0)[None]
+            with jax.named_scope("embed"):
+                x = _embed_rows(params["tok"], ids) + \
+                    jax.lax.dynamic_slice_in_dim(
+                        params["pos"], pos_offset, t, axis=0)[None]
         else:
             # rope: positions enter through the attention q/k rotation
             # (relative, unbounded — no table, no max_len constraint)
-            x = _embed_rows(params["tok"], ids)
+            with jax.named_scope("embed"):
+                x = _embed_rows(params["tok"], ids)
         new_blocks = list(state["blocks"])
         for i, blk in enumerate(self.blocks):
 
@@ -283,14 +283,39 @@ class TransformerLM(Module):
                 # recompute this block's activations in the backward pass
                 # instead of keeping them live across the whole stack
                 block_call = jax.checkpoint(block_call)
-            x, new_blocks[i] = block_call(
-                params["blocks"][i], state["blocks"][i], x,
-                child_rng(rng, i), pos_offset, key_padding_mask)
-        x, _ = self.ln_f.apply(params["ln_f"], state["ln_f"], x)
-        logits = _tied_logits(x, params["tok"])          # weight tying
+            with jax.named_scope(f"block_{i}"):
+                x, new_blocks[i] = block_call(
+                    params["blocks"][i], state["blocks"][i], x,
+                    child_rng(rng, i), pos_offset, key_padding_mask)
         new_state = dict(state)
         new_state["blocks"] = new_blocks
-        return jax.nn.log_softmax(logits, axis=-1), new_state
+        return self._head(params, state, x), new_state
+
+    def _embed(self, params, ids, pos):
+        """Token rows of ``ids`` (B, S) plus, for learned positions, the
+        table's rows at ``[pos_b, pos_b + S)`` per row, gathered CLIPPED:
+        an out-of-table position (a
+        right-pad garbage token, or a speculative verify row past a
+        finishing slot's limit) must yield a garbage-but-FINITE
+        embedding.  jnp.take's default out-of-bounds mode fills NaN, and
+        a NaN hidden state written to the pool's trash page would poison
+        every OTHER slot's attention through 0 * NaN in the masked
+        softmax-weighted sum."""
+        with jax.named_scope("embed"):
+            x = _embed_rows(params["tok"], ids)
+            if self.position == "learned":
+                positions = jnp.asarray(pos)[:, None] \
+                    + jnp.arange(ids.shape[1])
+                x = x + jnp.take(jnp.asarray(params["pos"]), positions,
+                                 axis=0, mode="clip")
+            return x
+
+    def _head(self, params, state, x):
+        """Final norm, the weight-tied logits and their log-softmax."""
+        with jax.named_scope("logits"):
+            x, _ = self.ln_f.apply(params["ln_f"], state["ln_f"], x)
+            return jax.nn.log_softmax(_tied_logits(x, params["tok"]),
+                                      axis=-1)
 
     # -- autoregressive inference (KV cache) ----------------------------
 
@@ -324,19 +349,20 @@ class TransformerLM(Module):
         # lifts the table so traced ids (the lax.scan carry in
         # generate) can index it — int8-packed tables gather + matmul
         # through their per-row scales
-        x = _embed_rows(params["tok"], ids)
-        if self.position == "learned":
-            # dynamic_slice CLAMPS an overrun silently; generate()
-            # bounds pos statically, direct callers must too
-            x = x + jax.lax.dynamic_slice_in_dim(
-                params["pos"], jnp.asarray(pos), s, axis=0)[None]
+        with jax.named_scope("embed"):
+            x = _embed_rows(params["tok"], ids)
+            if self.position == "learned":
+                # dynamic_slice CLAMPS an overrun silently; generate()
+                # bounds pos statically, direct callers must too
+                x = x + jax.lax.dynamic_slice_in_dim(
+                    params["pos"], jnp.asarray(pos), s, axis=0)[None]
         new_cache = list(cache)
         for i, blk in enumerate(self.blocks):
-            x, new_cache[i] = blk.decode_step(
-                params["blocks"][i], state["blocks"][i], cache[i], x, pos)
-        x, _ = self.ln_f.apply(params["ln_f"], state["ln_f"], x)
-        return jax.nn.log_softmax(_tied_logits(x, params["tok"]),
-                                  axis=-1), new_cache
+            with jax.named_scope(f"block_{i}"):
+                x, new_cache[i] = blk.decode_step(
+                    params["blocks"][i], state["blocks"][i], cache[i], x,
+                    pos)
+        return self._head(params, state, x), new_cache
 
     def decode_slots(self, params, state, tokens, cache, pos, active):
         """Slot-addressable :meth:`decode`: every batch row is an
@@ -360,23 +386,16 @@ class TransformerLM(Module):
         are untouched (per-row writes never cross rows)."""
         ids = jnp.asarray(tokens, jnp.int32) - 1
         b, s = ids.shape
-        x = _embed_rows(params["tok"], ids)
-        if self.position == "learned":
-            # per-row gather replaces decode()'s dynamic_slice: each
-            # slot reads the table at its own depth.  mode="clip": an
-            # out-of-range position yields a garbage-but-finite row
-            # (the default fills NaN), matching dynamic_slice's clamp
-            positions = jnp.asarray(pos)[:, None] + jnp.arange(s)
-            x = x + jnp.take(jnp.asarray(params["pos"]), positions,
-                             axis=0, mode="clip")
+        # per-row gather replaces decode()'s dynamic_slice: each slot
+        # reads the position table at its own depth
+        x = self._embed(params, ids, pos)
         new_cache = list(cache)
         for i, blk in enumerate(self.blocks):
-            x, new_cache[i] = blk.decode_step_slots(
-                params["blocks"][i], state["blocks"][i], cache[i], x,
-                pos, active)
-        x, _ = self.ln_f.apply(params["ln_f"], state["ln_f"], x)
-        return jax.nn.log_softmax(_tied_logits(x, params["tok"]),
-                                  axis=-1), new_cache
+            with jax.named_scope(f"block_{i}"):
+                x, new_cache[i] = blk.decode_step_slots(
+                    params["blocks"][i], state["blocks"][i], cache[i], x,
+                    pos, active)
+        return self._head(params, state, x), new_cache
 
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32):
@@ -406,26 +425,14 @@ class TransformerLM(Module):
         (typed ``SlotCapacityError``) and deactivates rows in-graph."""
         ids = jnp.asarray(tokens, jnp.int32) - 1
         b, s = ids.shape
-        x = _embed_rows(params["tok"], ids)
-        if self.position == "learned":
-            # per-row gather, CLIPPED: an out-of-table position (a
-            # right-pad garbage token, or a speculative verify row past
-            # a finishing slot's limit) must yield a garbage-but-FINITE
-            # embedding.  jnp.take's default out-of-bounds mode fills
-            # NaN, and a NaN hidden state written to the pool's trash
-            # page would poison every OTHER slot's attention through
-            # 0 * NaN in the masked softmax-weighted sum
-            positions = jnp.asarray(pos)[:, None] + jnp.arange(s)
-            x = x + jnp.take(jnp.asarray(params["pos"]), positions,
-                             axis=0, mode="clip")
+        x = self._embed(params, ids, pos)
         new_cache = list(cache)
         for i, blk in enumerate(self.blocks):
-            x, new_cache[i] = blk.decode_step_pages(
-                params["blocks"][i], state["blocks"][i], cache[i], x,
-                pages, pos, active)
-        x, _ = self.ln_f.apply(params["ln_f"], state["ln_f"], x)
-        return jax.nn.log_softmax(_tied_logits(x, params["tok"]),
-                                  axis=-1), new_cache
+            with jax.named_scope(f"block_{i}"):
+                x, new_cache[i] = blk.decode_step_pages(
+                    params["blocks"][i], state["blocks"][i], cache[i], x,
+                    pages, pos, active)
+        return self._head(params, state, x), new_cache
 
     def generate(self, params, state, prompt, max_new: int,
                  temperature: float = 0.0, rng=None,

@@ -163,10 +163,11 @@ class MultiHeadAttention(Module):
             q = apply_rope(q, positions, self.rope_theta)
             k = apply_rope(k, positions, self.rope_theta)
         dt = cache["k"].dtype
-        ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(dt),
-                                          (0, 0, jnp.asarray(pos), 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(dt),
-                                          (0, 0, jnp.asarray(pos), 0))
+        with jax.named_scope("kv.write"):
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], k.astype(dt), (0, 0, jnp.asarray(pos), 0))
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], v.astype(dt), (0, 0, jnp.asarray(pos), 0))
         from bigdl_tpu.ops.attention import expand_kv_heads
         kk, vv = expand_kv_heads(q, ck, cv)         # (B, H, L, D)
         scale = 1.0 / math.sqrt(self.head_dim)
@@ -233,8 +234,9 @@ class MultiHeadAttention(Module):
         write = jax.vmap(_write_row)
         act = jnp.asarray(active)
         pos_v = jnp.asarray(pos)
-        ck = write(cache["k"], k.astype(dt), pos_v, act)
-        cv = write(cache["v"], v.astype(dt), pos_v, act)
+        with jax.named_scope("kv.write"):
+            ck = write(cache["k"], k.astype(dt), pos_v, act)
+            cv = write(cache["v"], v.astype(dt), pos_v, act)
         from bigdl_tpu.ops.attention import expand_kv_heads
         kk, vv = expand_kv_heads(q, ck, cv)         # (B, H, L, D)
         scale = 1.0 / math.sqrt(self.head_dim)
@@ -320,8 +322,11 @@ class MultiHeadAttention(Module):
             return c.at[phys.reshape(-1), :, offs.reshape(-1), :] \
                     .set(flat)
 
-        ck = _scatter(cache["k"], k)
-        cv = _scatter(cache["v"], v)
+        # the write and the read are scoped apart, so that a trace says
+        # which of the two owns a relayout of the pool
+        with jax.named_scope("kv.write"):
+            ck = _scatter(cache["k"], k)
+            cv = _scatter(cache["v"], v)
         scale = 1.0 / math.sqrt(self.head_dim)
         from bigdl_tpu.ops.attention import (paged_attention,
                                              paged_attention_enabled)
@@ -332,19 +337,21 @@ class MultiHeadAttention(Module):
             # view below never exists in HBM.  Same math operation for
             # operation (trash zeroing, validity mask, f32 softmax):
             # bit-parity with this gather path is regression-gated.
-            o = paged_attention(q, ck, cv, pages, positions, scale)
+            with jax.named_scope("attn.paged"):
+                o = paged_attention(q, ck, cv, pages, positions, scale)
             y = _proj(self._merge(o), params["wo"],
                       params["bo"] if self.with_bias else None)
             return y, {"k": ck, "v": cv}
         # read: gather the row's pages into a contiguous (B, H, L, D)
         # view (L = Lp * ps) — the jnp fallback path (non-Pallas
         # backends) and the kernel's parity oracle
-        kk = ck[pages].transpose(0, 2, 1, 3, 4) \
-                      .reshape(b, self.num_kv_heads, lp * ps,
-                               self.head_dim)
-        vv = cv[pages].transpose(0, 2, 1, 3, 4) \
-                      .reshape(b, self.num_kv_heads, lp * ps,
-                               self.head_dim)
+        with jax.named_scope("attn.paged"):
+            kk = ck[pages].transpose(0, 2, 1, 3, 4) \
+                          .reshape(b, self.num_kv_heads, lp * ps,
+                                   self.head_dim)
+            vv = cv[pages].transpose(0, 2, 1, 3, 4) \
+                          .reshape(b, self.num_kv_heads, lp * ps,
+                                   self.head_dim)
         # zero trash-mapped positions in the gathered view: the -inf
         # validity mask hides them from the softmax, but the weighted
         # sum still multiplies their V by 0 — and 0 * NaN is NaN, so a

@@ -26,10 +26,10 @@ class Sequential(Container):
     def apply(self, params, state, input, *, training=False, rng=None):
         x = input
         new_state = list(state)
-        for i, m in enumerate(self.modules):
-            x, new_state[i] = m.apply(params[i], state[i], x,
-                                      training=training,
-                                      rng=child_rng(rng, i))
+        for i in range(len(self.modules)):
+            x, new_state[i] = self.child_apply(
+                i, params[i], state[i], x, training=training,
+                rng=child_rng(rng, i))
         return x, new_state
 
 
@@ -44,10 +44,10 @@ class Concat(Container):
 
     def apply(self, params, state, input, *, training=False, rng=None):
         outs, new_state = [], list(state)
-        for i, m in enumerate(self.modules):
-            y, new_state[i] = m.apply(params[i], state[i], input,
-                                      training=training,
-                                      rng=child_rng(rng, i))
+        for i in range(len(self.modules)):
+            y, new_state[i] = self.child_apply(
+                i, params[i], state[i], input, training=training,
+                rng=child_rng(rng, i))
             outs.append(y)
         return jnp.concatenate(outs, axis=self.dimension - 1), new_state
 
@@ -57,10 +57,10 @@ class ConcatTable(Container):
 
     def apply(self, params, state, input, *, training=False, rng=None):
         outs, new_state = [], list(state)
-        for i, m in enumerate(self.modules):
-            y, new_state[i] = m.apply(params[i], state[i], input,
-                                      training=training,
-                                      rng=child_rng(rng, i))
+        for i in range(len(self.modules)):
+            y, new_state[i] = self.child_apply(
+                i, params[i], state[i], input, training=training,
+                rng=child_rng(rng, i))
             outs.append(y)
         return outs, new_state
 
@@ -70,10 +70,10 @@ class ParallelTable(Container):
 
     def apply(self, params, state, input, *, training=False, rng=None):
         outs, new_state = [], list(state)
-        for i, m in enumerate(self.modules):
-            y, new_state[i] = m.apply(params[i], state[i], input[i],
-                                      training=training,
-                                      rng=child_rng(rng, i))
+        for i in range(len(self.modules)):
+            y, new_state[i] = self.child_apply(
+                i, params[i], state[i], input[i], training=training,
+                rng=child_rng(rng, i))
             outs.append(y)
         return outs, new_state
 
@@ -93,12 +93,11 @@ class MapTable(Container):
         return [p], [s]
 
     def apply(self, params, state, input, *, training=False, rng=None):
-        m = self.modules[0]
         outs = []
         s = state[0]
         for i, x in enumerate(input):
-            y, s = m.apply(params[0], s, x, training=training,
-                           rng=child_rng(rng, i))
+            y, s = self.child_apply(0, params[0], s, x, training=training,
+                                    rng=child_rng(rng, i))
             outs.append(y)
         return outs, [s]
 
@@ -244,7 +243,7 @@ class Bottle(Container):
         lead = input.shape[:input.ndim - self.n_input_dim + 1]
         rest = input.shape[input.ndim - self.n_input_dim + 1:]
         squashed = jnp.reshape(input, (-1,) + rest)
-        y, s0 = self.modules[0].apply(params[0], state[0], squashed,
-                                      training=training, rng=rng)
+        y, s0 = self.child_apply(0, params[0], state[0], squashed,
+                                 training=training, rng=rng)
         y = jnp.reshape(y, lead + y.shape[1:])
         return y, [s0]

@@ -188,6 +188,6 @@ class TimeDistributed(Container):
     def apply(self, params, state, input, *, training=False, rng=None):
         b, t = input.shape[0], input.shape[1]
         flat = jnp.reshape(input, (b * t,) + input.shape[2:])
-        y, s0 = self.modules[0].apply(params[0], state[0], flat,
-                                      training=training, rng=rng)
+        y, s0 = self.child_apply(0, params[0], state[0], flat,
+                                 training=training, rng=rng)
         return jnp.reshape(y, (b, t) + y.shape[1:]), [s0]

@@ -289,6 +289,19 @@ def emit(type_: str, **fields) -> None:
         led.emit(rec)
 
 
+def emit_clock() -> None:
+    """One ``clock`` record holding the monotonic and the wall clock read
+    together, in nanoseconds: every record is stamped on both, but as
+    floats taken at different instants; with this pair a ledger can be
+    laid on any trace that carries either clock.  Emitted beside every
+    ``run.start``."""
+    led = get_ledger()
+    if led is not None:
+        mono_ns, wall_ns = time.monotonic_ns(), time.time_ns()
+        led.emit({"type": "clock", "mono_ns": mono_ns, "wall_ns": wall_ns,
+                  "pid": os.getpid()})
+
+
 def flush() -> None:
     led = get_ledger()
     if led is not None:

@@ -27,6 +27,26 @@ from bigdl_tpu.observability import ledger
 
 _tls = threading.local()
 _ids = itertools.count(1)
+_annotation = None      # jax.profiler.TraceAnnotation, looked up once
+
+
+def _trace_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` of the span's name, so
+    that a profile taken while the ledger is on shows the program's spans
+    above the device's rows, on the profile's own clock.  None where jax
+    is not importable (the ledger stays usable without it)."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:
+            _annotation = False
+    if not _annotation:
+        return None
+    ann = _annotation(name)
+    ann.__enter__()
+    return ann
 
 
 def _stack():
@@ -93,7 +113,7 @@ class SpanHandle:
     block leaked."""
 
     __slots__ = ("_led", "name", "attrs", "sid", "_rec", "_t0", "_done",
-                 "_excluded")
+                 "_excluded", "_ann")
 
     def __init__(self, led, name: str, attrs: dict):
         self._led = led
@@ -117,6 +137,7 @@ class SpanHandle:
                 self._rec["link_pid"] = remote[0]
         if attrs:
             self._rec["attrs"] = attrs
+        self._ann = _trace_annotation(name)
         self._t0 = time.perf_counter()
         self._done = False
         self._excluded = 0.0
@@ -158,6 +179,8 @@ class SpanHandle:
             del stack[stack.index(self.sid):]
         self._rec["dur_s"] = max(
             0.0, time.perf_counter() - self._t0 - self._excluded)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if error:
             self._rec["error"] = error
         self._led.emit(self._rec)

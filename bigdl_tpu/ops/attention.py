@@ -198,6 +198,7 @@ def _fused_forward(q, k, v, causal, scale, block_q=None):
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=_interpret(),
+        name="flash_fwd_fused",
     )(qf, kf, vf)
     return o.reshape(b, h, t, d)
 
@@ -351,6 +352,7 @@ def _streaming_forward(q, k, v, causal, scale, with_lse=False,
                         pltpu.VMEM((block_q, 128), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_fwd_stream",
     )(*operands)
     o = outs[0].reshape(b, h, t, d)
     if with_lse:
@@ -522,6 +524,7 @@ def _flash_streaming_bwd(q, k, v, o, lse, do, causal, scale, bias=None,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(*dq_operands)
 
     # dk/dv grid: KV row outer, then every (q-head-in-group, Q block)
@@ -556,6 +559,7 @@ def _flash_streaming_bwd(q, k, v, o, lse, do, causal, scale, bias=None,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(*dkv_operands)
 
     return (dq.reshape(b, h, t, d), dk.reshape(b, hk, tk, d),
@@ -866,5 +870,6 @@ def paged_attention(q, k_pool, v_pool, pages, positions, scale):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), k_pool.dtype),
         interpret=_interpret(),
+        name="paged_attention",
     )(jnp.asarray(pages, jnp.int32), q,
       jnp.asarray(positions, jnp.int32)[:, :, None], k_pool, v_pool)

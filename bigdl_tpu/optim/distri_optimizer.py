@@ -44,6 +44,7 @@ from bigdl_tpu.observability import ledger as run_ledger
 from bigdl_tpu.observability import tracer
 from bigdl_tpu.optim.local_optimizer import (LocalOptimizer,
                                              _base_dataset,
+                                             _host_nbytes,
                                              _sync_shuffles)
 from bigdl_tpu.parallel import mesh as mesh_mod
 from bigdl_tpu.parallel.allreduce import (make_distri_eval_fn,
@@ -787,7 +788,8 @@ class DistriOptimizer(LocalOptimizer):
                     f"data-axis size {n} (the reference enforces batch % "
                     f"nodeNumber == 0 the same way)")
             t0 = time.time()
-            with tracer.span("h2d", records=bs):
+            with tracer.span("h2d", records=bs,
+                             bytes=_host_nbytes(data, labels)):
                 if nproc > 1:
                     # true multi-host: each process contributes ONLY its
                     # local rows; the global array is assembled without
@@ -830,13 +832,15 @@ class DistriOptimizer(LocalOptimizer):
                     # full_like) is step work, not an inter-span hole in
                     # the coverage accounting
                     data = jnp.full_like(data, jnp.nan)  # NaN fwd -> grads
-                wshard, opt_shard, model_state, loss = step(
-                    wshard, opt_shard, model_state, data, labels, sub,
-                    jnp.asarray(stepno, jnp.int32), clr)
+                with tracer.span("train.dispatch"):
+                    wshard, opt_shard, model_state, loss = step(
+                        wshard, opt_shard, model_state, data, labels, sub,
+                        jnp.asarray(stepno, jnp.int32), clr)
                 # blocks: whole fused step (compute + comm) — the hang
                 # point the watchdog guards (a wedged host stalls every
                 # other host's collective exactly here)
-                loss = float(loss)
+                with tracer.span("train.sync"):
+                    loss = float(loss)
             compute_ns = (time.time() - t1) * 1e9
             dt = time.time() - t0   # full iteration, for throughput
 
@@ -1062,7 +1066,8 @@ class DistriOptimizer(LocalOptimizer):
                     f"global batch size {bs} must be a multiple of the "
                     f"dp shard count {n} (data x fsdp axes)")
             t0 = time.time()
-            with tracer.span("h2d", records=bs):
+            with tracer.span("h2d", records=bs,
+                             bytes=_host_nbytes(batch.data, batch.labels)):
                 data = jax.device_put(np.asarray(batch.data),
                                       data_sharding)
                 labels = jax.device_put(np.asarray(batch.labels),
@@ -1089,10 +1094,12 @@ class DistriOptimizer(LocalOptimizer):
                              label=f"train step {stepno} (spec, n={n})"):
                 if FaultInjector.should("grad.nan", stepno):
                     data = jnp.full_like(data, jnp.nan)
-                params, opt_state, model_state, loss = step(
-                    params, opt_state, model_state, data, labels, sub,
-                    jnp.asarray(stepno, jnp.int32), clr)
-                loss = float(loss)
+                with tracer.span("train.dispatch"):
+                    params, opt_state, model_state, loss = step(
+                        params, opt_state, model_state, data, labels, sub,
+                        jnp.asarray(stepno, jnp.int32), clr)
+                with tracer.span("train.sync"):
+                    loss = float(loss)
             compute_ns = (time.time() - t1) * 1e9
             dt = time.time() - t0
 

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bigdl_tpu.core.precision import training_loss
 from bigdl_tpu.observability import costs
 from bigdl_tpu.observability import ledger as run_ledger
 from bigdl_tpu.observability import tracer
@@ -286,36 +287,31 @@ class LocalOptimizer:
         def step(params, opt_state, model_state, data, labels, rng,
                  stepno, clr):
             def loss_fn(p):
-                if mixed:
-                    from bigdl_tpu.core.precision import mixed_forward
-                    y, new_ms = mixed_forward(model, p, model_state, data,
-                                              training=True, rng=rng)
-                else:
-                    y, new_ms = model.apply(p, model_state, data,
-                                            training=True, rng=rng)
-                from bigdl_tpu.core.module import collect_aux_losses
-                return (criterion.apply(y, labels) +
-                        collect_aux_losses(new_ms), new_ms)
+                return training_loss(
+                    model, criterion, p, model_state, data, labels, rng,
+                    compute_dtype=jnp.bfloat16 if mixed else None)
             (loss, new_ms), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             cfg = config.clone()
             cfg["clr"] = clr
-            new_params, new_opt = optim.update(grads, params, opt_state,
-                                               cfg, stepno)
+            with jax.named_scope("update"):
+                new_params, new_opt = optim.update(grads, params,
+                                                   opt_state, cfg, stepno)
             if guard:
                 # skip-and-keep-weights: a non-finite loss/gradient step
                 # must not poison the parameters OR the optimizer state
                 # (a single NaN in a momentum buffer corrupts every later
                 # step).  NaN loss is the driver's skip signal.
-                ok = jnp.isfinite(loss)
-                for g in jax.tree_util.tree_leaves(grads):
-                    ok &= jnp.all(jnp.isfinite(g))
-                sel = lambda new, old: jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(ok, a, b), new, old)
-                new_params = sel(new_params, params)
-                new_opt = sel(new_opt, opt_state)
-                new_ms = sel(new_ms, model_state)
-                loss = jnp.where(ok, loss, jnp.nan)
+                with jax.named_scope("guard"):
+                    ok = jnp.isfinite(loss)
+                    for g in jax.tree_util.tree_leaves(grads):
+                        ok &= jnp.all(jnp.isfinite(g))
+                    sel = lambda new, old: jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(ok, a, b), new, old)
+                    new_params = sel(new_params, params)
+                    new_opt = sel(new_opt, opt_state)
+                    new_ms = sel(new_ms, model_state)
+                    loss = jnp.where(ok, loss, jnp.nan)
             return new_params, new_opt, new_ms, loss
 
         return step
@@ -400,6 +396,7 @@ class LocalOptimizer:
             return
         tracer.install_compile_hook()
         tracer.reset_stack()     # a prior failed run must not parent us
+        run_ledger.emit_clock()
         run_ledger.emit(
             "run.start", kind=type(self).__name__, pid=os.getpid(),
             thread=threading.get_ident(),
@@ -526,7 +523,8 @@ class LocalOptimizer:
             # view, and the span records that H2D was absorbed by the
             # ingest ring (run-report shows ingest.h2d instead)
             with tracer.span("h2d",
-                             staged=isinstance(batch.data, jax.Array)):
+                             staged=isinstance(batch.data, jax.Array),
+                             bytes=_host_nbytes(batch.data, batch.labels)):
                 if self._data_sharding is not None and \
                         not isinstance(batch.data, jax.Array):
                     data = self._put_batch(batch.data)
@@ -561,10 +559,15 @@ class LocalOptimizer:
                     # full_like) is step work, not an inter-span hole in
                     # the coverage accounting
                     data = jnp.full_like(data, jnp.nan)  # NaN fwd -> grads
-                params, opt_state, model_state, loss = step(
-                    params, opt_state, model_state, data, labels, sub,
-                    jnp.asarray(stepno, jnp.int32), clr)
-                loss = float(loss)    # host sync: the hang point guarded
+                # the call returns once the program is enqueued; the
+                # wait for it (and for the batch h2d only enqueued) is
+                # the sync's
+                with tracer.span("train.dispatch"):
+                    params, opt_state, model_state, loss = step(
+                        params, opt_state, model_state, data, labels, sub,
+                        jnp.asarray(stepno, jnp.int32), clr)
+                with tracer.span("train.sync"):
+                    loss = float(loss)    # the hang point guarded
             dt = time.time() - t0
             # everything after the step itself — metrics/ledger/summary
             # bookkeeping, logging, epoch rollover (shuffle + fresh
@@ -661,6 +664,14 @@ class LocalOptimizer:
             File.save({"state": dict(self.state), "opt_state": opt_state,
                        "rng": np.asarray(self._rng)},
                       f"{self.checkpoint_path}/state{suffix}", True)
+
+
+def _host_nbytes(data, labels) -> int:
+    """Bytes ``h2d`` has to copy: those of the batch's arrays still on the
+    host (0 for a batch the ingest ring staged on the device)."""
+    return sum(int(getattr(a, "nbytes", 0))
+               for a in jax.tree_util.tree_leaves((data, labels))
+               if not isinstance(a, jax.Array))
 
 
 def _evaluate(model, dataset, methods):

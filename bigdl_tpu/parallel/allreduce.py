@@ -146,8 +146,8 @@ class AllReduceParameter:
         """The aggregate-gradient collective on a full padded flat vector
         -> this node's summed shard, in the master dtype (no count
         division — callers own that)."""
-        if self.rs_mode == "a2a":
-            with jax.named_scope("aggregate_gradient"):
+        with jax.named_scope("aggregate_gradient"):
+            if self.rs_mode == "a2a":
                 x = gflat.reshape(self.n, self.shard_size)
                 if self.compress == "bf16":
                     x = x.astype(jnp.bfloat16)
@@ -157,11 +157,11 @@ class AllReduceParameter:
                 y = lax.all_to_all(x, self.axis, split_axis=0,
                                    concat_axis=0)
                 return jnp.sum(y.astype(self.dtype), axis=0)
-        if self.compress == "bf16":
-            gflat = gflat.astype(jnp.bfloat16)
-        gshard = lax.psum_scatter(gflat, self.axis, scatter_dimension=0,
-                                  tiled=True)
-        return gshard.astype(self.dtype)
+            if self.compress == "bf16":
+                gflat = gflat.astype(jnp.bfloat16)
+            gshard = lax.psum_scatter(gflat, self.axis,
+                                      scatter_dimension=0, tiled=True)
+            return gshard.astype(self.dtype)
 
     def reduce_scatter_gradients(self, grads_pytree, count) -> jnp.ndarray:
         """putGradients + aggregrateGradientPartition: local full gradient
@@ -278,17 +278,9 @@ def make_distri_train_step(model, criterion, optim, mesh: Mesh,
         params = layout.all_gather_weights(wshard[0])
         # (2) local forward/backward on this node's batch shard
         def loss_fn(p):
-            if compute_dtype is not None:
-                from bigdl_tpu.core.precision import mixed_forward
-                y, new_ms = mixed_forward(model, p, model_state, data,
-                                          compute_dtype=compute_dtype,
-                                          training=True, rng=rng)
-            else:
-                y, new_ms = model.apply(p, model_state, data,
-                                        training=True, rng=rng)
-            from bigdl_tpu.core.module import collect_aux_losses
-            return (criterion.apply(y, labels) +
-                    collect_aux_losses(new_ms), new_ms)
+            from bigdl_tpu.core.precision import training_loss
+            return training_loss(model, criterion, p, model_state, data,
+                                 labels, rng, compute_dtype=compute_dtype)
         (loss, new_ms), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         # (3) reduce-scatter: own the summed gradient slice (mean over nodes)
@@ -297,27 +289,31 @@ def make_distri_train_step(model, criterion, optim, mesh: Mesh,
             # poison-before-pmean: NaN propagates through the mean, so
             # the existing loss reduction doubles as the cross-shard
             # skip consensus (see make_distri_train_step docstring)
-            bad = ~(jnp.isfinite(loss) & jnp.all(jnp.isfinite(gshard)))
-            loss = jnp.where(bad, jnp.nan, loss)
+            with jax.named_scope("guard"):
+                bad = ~(jnp.isfinite(loss)
+                        & jnp.all(jnp.isfinite(gshard)))
+                loss = jnp.where(bad, jnp.nan, loss)
         # (4) sharded optimizer update on the owned slice (ZeRO-1)
         cfg = config.clone()
         cfg["clr"] = clr
         opt_in = jax.tree_util.tree_map(lambda t: t[0], opt_shard)
-        new_wshard, new_opt = optim.update(gshard, wshard[0], opt_in,
-                                           cfg, stepno)
+        with jax.named_scope("update"):
+            new_wshard, new_opt = optim.update(gshard, wshard[0], opt_in,
+                                               cfg, stepno)
         # (5) losses/state reductions for the driver
         loss = lax.pmean(loss, axis)
         new_ms = jax.tree_util.tree_map(
             lambda t: lax.pmean(t, axis), new_ms)
         if guard_nonfinite:
-            ok = jnp.isfinite(loss)       # identical on every node
-            new_wshard = jnp.where(ok, new_wshard, wshard[0])
-            new_opt = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(ok, new, old[0]),
-                new_opt, opt_shard)
-            new_ms = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(ok, new, old),
-                new_ms, model_state)
+            with jax.named_scope("guard"):
+                ok = jnp.isfinite(loss)       # identical on every node
+                new_wshard = jnp.where(ok, new_wshard, wshard[0])
+                new_opt = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(ok, new, old[0]),
+                    new_opt, opt_shard)
+                new_ms = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(ok, new, old),
+                    new_ms, model_state)
         return (new_wshard[None], jax.tree_util.tree_map(
             lambda t: t[None], new_opt), new_ms, loss)
 

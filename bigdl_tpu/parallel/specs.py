@@ -311,33 +311,27 @@ def make_spec_train_step(model, criterion, optim, mesh, config,
     def _step(params, opt_state, model_state, data, labels, rng,
               stepno, clr):
         def loss_fn(p):
-            if compute_dtype is not None:
-                from bigdl_tpu.core.precision import mixed_forward
-                y, new_ms = mixed_forward(model, p, model_state, data,
-                                          compute_dtype=compute_dtype,
-                                          training=True, rng=rng)
-            else:
-                y, new_ms = model.apply(p, model_state, data,
-                                        training=True, rng=rng)
-            from bigdl_tpu.core.module import collect_aux_losses
-            return (criterion.apply(y, labels) +
-                    collect_aux_losses(new_ms), new_ms)
+            from bigdl_tpu.core.precision import training_loss
+            return training_loss(model, criterion, p, model_state, data,
+                                 labels, rng, compute_dtype=compute_dtype)
         (loss, new_ms), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         cfg = config.clone()
         cfg["clr"] = clr
-        new_params, new_opt = optim.update(grads, params, opt_state,
-                                           cfg, stepno)
+        with jax.named_scope("update"):
+            new_params, new_opt = optim.update(grads, params, opt_state,
+                                               cfg, stepno)
         if guard_nonfinite:
-            ok = jnp.isfinite(loss)
-            for g in jax.tree_util.tree_leaves(grads):
-                ok &= jnp.all(jnp.isfinite(g))
-            sel = lambda new, old: jax.tree_util.tree_map(
-                lambda a, b: jnp.where(ok, a, b), new, old)
-            new_params = sel(new_params, params)
-            new_opt = sel(new_opt, opt_state)
-            new_ms = sel(new_ms, model_state)
-            loss = jnp.where(ok, loss, jnp.nan)
+            with jax.named_scope("guard"):
+                ok = jnp.isfinite(loss)
+                for g in jax.tree_util.tree_leaves(grads):
+                    ok &= jnp.all(jnp.isfinite(g))
+                sel = lambda new, old: jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(ok, a, b), new, old)
+                new_params = sel(new_params, params)
+                new_opt = sel(new_opt, opt_state)
+                new_ms = sel(new_ms, model_state)
+                loss = jnp.where(ok, loss, jnp.nan)
         return new_params, new_opt, new_ms, loss
 
     # same donation policy as the flat trainer: params/opt_state buffers

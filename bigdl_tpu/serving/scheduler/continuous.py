@@ -82,13 +82,68 @@ logger = logging.getLogger("bigdl_tpu.serving")
 _rids = itertools.count(1)
 
 
+class Timeline:
+    """One request's lifecycle on ``time.monotonic()``, always kept (the
+    ledger may be off) and readable by the client as ``future.timeline``
+    once the future resolved; the scheduler thread is its only writer.
+
+    ``t_submit`` — ``submit()`` accepted it; ``t_admit`` — the scheduler
+    took it out of the queue and began to place it (a held-back request
+    keeps its first); ``t_first`` — the host holds its first token;
+    ``t_last`` — the newest delivery of tokens to it.  A delivery is the
+    prefill's first token or a decode chunk that emitted for it:
+    ``n_chunks`` counts them, ``gaps_s`` lists the ``n_chunks - 1``
+    intervals between consecutive ones (prefills of other requests
+    included) and ``max_gap_s`` is their largest.  A stamp the request
+    never reached stays None."""
+
+    __slots__ = ("t_submit", "t_admit", "t_first", "t_last", "n_chunks",
+                 "max_gap_s", "gaps_s")
+
+    def __init__(self):
+        self.t_submit = time.monotonic()
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
+        self.t_last: Optional[float] = None
+        self.n_chunks = 0
+        self.max_gap_s = 0.0
+        self.gaps_s: List[float] = []
+
+    def delivered(self, now: float) -> Optional[float]:
+        """Note a delivery of tokens at ``now``; returns the gap since
+        the one before it (None for the first)."""
+        gap = None
+        if self.t_last is None:
+            self.t_first = now
+        else:
+            gap = now - self.t_last
+            self.gaps_s.append(gap)
+            if gap > self.max_gap_s:
+                self.max_gap_s = gap
+        self.t_last = now
+        self.n_chunks += 1
+        return gap
+
+    def fields(self) -> dict:
+        """What a ``serve.request`` record carries of it."""
+        out = {"t_submit": self.t_submit, "n_chunks": self.n_chunks,
+               "max_gap_s": self.max_gap_s}
+        if self.t_admit is not None:
+            out["queue_s"] = self.t_admit - self.t_submit
+        if self.t_first is not None:
+            out["ttft_s"] = self.t_first - self.t_submit
+            out["gaps_s"] = list(self.gaps_s)
+        return out
+
+
 class GenRequest:
     """One admitted generation request: a 1-based prompt, a token
     budget, a future resolving to the generated 1-based ids
-    (``np.ndarray``, length ``max_new`` — shorter only on ``eos_id``)."""
+    (``np.ndarray``, length ``max_new`` — shorter only on ``eos_id``);
+    the future carries the request's :class:`Timeline`."""
 
     __slots__ = ("rid", "prompt", "max_new", "future", "deadline",
-                 "t_submit", "slot", "tokens", "counted", "session")
+                 "timeline", "slot", "tokens", "counted", "session")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  session: Optional[str] = None):
@@ -96,13 +151,17 @@ class GenRequest:
         self.prompt = prompt
         self.max_new = int(max_new)
         self.future: Future = Future()
+        self.timeline = self.future.timeline = Timeline()
         self.deadline = None            # AdmissionQueue duck contract
-        self.t_submit = time.monotonic()
         self.slot: Optional[int] = None
         self.tokens: List[int] = []
         self.counted = False            # prefix census: count once even
                                         # if held back and re-placed
         self.session = session          # multi-turn session id (r20)
+
+    @property
+    def t_submit(self) -> float:
+        return self.timeline.t_submit
 
 
 class Session:
@@ -542,10 +601,12 @@ class ContinuousGenerator:
         cache_dtype = self._cache_dtype
 
         def pick(logp, key):
-            if temperature <= 0:
-                return jnp.argmax(logp, axis=-1).astype(jnp.int32) + 1
-            return jax.random.categorical(
-                key, logp / temperature, axis=-1).astype(jnp.int32) + 1
+            with jax.named_scope("sample"):
+                if temperature <= 0:
+                    return jnp.argmax(logp, axis=-1).astype(jnp.int32) + 1
+                return jax.random.categorical(
+                    key, logp / temperature,
+                    axis=-1).astype(jnp.int32) + 1
 
         if self._paged:
             def prefill(params, state, tokens, ts, cache, pages, start,
@@ -1101,6 +1162,7 @@ class ContinuousGenerator:
     def _loop(self) -> None:
         if run_ledger.enabled():
             tracer.install_compile_hook()
+            run_ledger.emit_clock()
             run_ledger.emit("run.start", kind="ContinuousGenerator",
                             pid=os.getpid(),
                             thread=threading.get_ident(),
@@ -1395,6 +1457,11 @@ class ContinuousGenerator:
         typed instead of holding back, so the loop can never wedge on a
         request the pool will never satisfy (belt-and-braces: the
         submit-time pool check already rejects those)."""
+        tl = req.timeline
+        if tl.t_admit is None:          # a held-back request keeps its first
+            tl.t_admit = time.monotonic()
+            self.metrics.observe("serve.gen.queue_wait_s",
+                                 tl.t_admit - tl.t_submit)
         if not self._paged:
             self._place_row(req)
             return True
@@ -1489,10 +1556,7 @@ class ContinuousGenerator:
                 prefix.release(slot_keys)
             self._session_abort(req)
             self.metrics.incr("serve.gen.cancelled")
-            run_ledger.emit("serve.request", rid=req.rid,
-                            status="cancelled",
-                            dur_s=time.monotonic() - req.t_submit,
-                            **self._tags)
+            self._emit_request(req, "cancelled")
             return True
         slot = self.slots.alloc()
         assert slot is not None, "placed with no free slot"
@@ -1543,6 +1607,7 @@ class ContinuousGenerator:
                 # the host fetch stays in scope: an async dispatch
                 # failure surfaces here, after the cache was donated
                 first = int(np.asarray(first))
+                self._first_token(req)
         except Exception as e:
             self._release_partial(req, slot, priv, slot_keys)
             self._prefill_failed(req, e, consumed_cache=True)
@@ -1659,10 +1724,7 @@ class ContinuousGenerator:
             alloc.free(got)
             self._session_abort(req)
             self.metrics.incr("serve.gen.cancelled")
-            run_ledger.emit("serve.request", rid=req.rid,
-                            status="cancelled",
-                            dur_s=time.monotonic() - req.t_submit,
-                            **self._tags)
+            self._emit_request(req, "cancelled")
             return True
 
         resumed, new_priv = got[:resume_pages], got[resume_pages:]
@@ -1720,6 +1782,7 @@ class ContinuousGenerator:
                     self.params, self.state, suffix_dev, ts,
                     self._cache, table_dev, kv_start, key)
                 first = int(np.asarray(first))
+                self._first_token(req)
         except Exception as e:
             self.slots.release(slot)
             alloc.free(new_priv)
@@ -1746,10 +1809,7 @@ class ContinuousGenerator:
 
         if not req.future.set_running_or_notify_cancel():
             self.metrics.incr("serve.gen.cancelled")
-            run_ledger.emit("serve.request", rid=req.rid,
-                            status="cancelled",
-                            dur_s=time.monotonic() - req.t_submit,
-                            **self._tags)
+            self._emit_request(req, "cancelled")
             return
         slot = self.slots.alloc()
         assert slot is not None, "placed with no free slot"
@@ -1774,11 +1834,38 @@ class ContinuousGenerator:
                     self.params, self.state, prompt_dev, tp,
                     self._cache, slot, key)
                 first = int(np.asarray(first))
+                self._first_token(req)
         except Exception as e:
             self.slots.release(slot)
             self._prefill_failed(req, e, consumed_cache=True)
             return
         self._commit_placed(req, slot, tp, first, bucket)
+
+    def _first_token(self, req: GenRequest) -> None:
+        """The host holds the request's first token: stamp it."""
+        tl = req.timeline
+        tl.delivered(time.monotonic())
+        self.metrics.observe("serve.gen.ttft_s", tl.t_first - tl.t_submit)
+
+    def _delivered(self, req: GenRequest, now: float) -> None:
+        """A decode chunk that ended at ``now`` emitted for ``req``."""
+        gap = req.timeline.delivered(now)
+        if gap is not None:
+            self.metrics.observe("serve.gen.chunk_gap_s", gap)
+
+    def _decode_attrs(self) -> dict:
+        """What a ``serve.decode`` span says of the work it covers
+        (computed only while the ledger is on): ``ctx_tokens``, the sum
+        of the active rows' positions, and ``pages_mapped``, the pages
+        their table rows map."""
+        if not run_ledger.enabled():
+            return {}
+        act = self._active
+        out = {"ctx_tokens": int(self._pos[act].sum())}
+        if self._paged:
+            out["pages_mapped"] = int(
+                (self._page_table[act] != self._alloc.trash).sum())
+        return out
 
     def _commit_placed(self, req: GenRequest, slot: int, tp: int,
                        first: int, bucket: int) -> None:
@@ -1820,9 +1907,7 @@ class ContinuousGenerator:
             req.future.set_exception(exc)
         except Exception:                # client cancelled mid-flight
             pass
-        run_ledger.emit("serve.request", rid=req.rid, status="failed",
-                        tokens=0, dur_s=time.monotonic() - req.t_submit,
-                        **self._tags)
+        self._emit_request(req, "failed", tokens=0)
 
     def _prefill_failed(self, req: GenRequest, e: Exception,
                         consumed_cache: bool) -> None:
@@ -1842,10 +1927,7 @@ class ContinuousGenerator:
                 f"prefill failed: {type(e).__name__}: {e}"))
         except Exception:            # client cancelled mid-flight
             pass
-        run_ledger.emit("serve.request", rid=req.rid,
-                        status="failed", tokens=0,
-                        dur_s=time.monotonic() - req.t_submit,
-                        **self._tags)
+        self._emit_request(req, "failed", tokens=0)
 
     # -- decode --------------------------------------------------------------
 
@@ -1862,7 +1944,8 @@ class ContinuousGenerator:
         n_active = int(self._active.sum())
         occ = n_active / self.slots.num_slots
         with tracer.span("serve.decode", chunk=self._chunks,
-                         active=n_active, steps=self.steps_per_sync):
+                         active=n_active, steps=self.steps_per_sync,
+                         **self._decode_attrs()):
             if self._greedy_keys is not None:
                 keys = self._greedy_keys
             else:
@@ -1892,12 +1975,16 @@ class ContinuousGenerator:
             new_active = np.asarray(active)
             toks = np.asarray(toks)              # (steps, slots)
             emitted = np.asarray(emitted)
+        now = time.monotonic()      # the chunk's tokens reached the host
         chunk_tokens = int(emitted.sum())
         self._account_chunk(occ, n_active, chunk_tokens,
                             self.steps_per_sync)
+        got = emitted.any(axis=0)
         for j, req in enumerate(self._requests):
             if req is None:
                 continue
+            if got[j]:
+                self._delivered(req, now)
             for t in range(toks.shape[0]):
                 if emitted[t, j]:
                     req.tokens.append(int(toks[t, j]))
@@ -1918,7 +2005,8 @@ class ContinuousGenerator:
         occ = n_active / self.slots.num_slots
         k = self.spec_k
         with tracer.span("serve.decode", chunk=self._chunks,
-                         active=n_active, steps=1, spec_k=k):
+                         active=n_active, steps=1, spec_k=k,
+                         **self._decode_attrs()):
             drafts, greedy, self._cache, self._dcache = self._spec_fn(
                 self.params, self.state, self._draft_params,
                 self._draft_state, jnp.asarray(self._tokens),
@@ -1927,11 +2015,13 @@ class ContinuousGenerator:
                 jnp.asarray(self._active))
             drafts = np.asarray(drafts)          # (slots, k)
             greedy = np.asarray(greedy)          # (slots, k + 1)
+        now = time.monotonic()      # the round's tokens reached the host
         chunk_tokens = 0
         proposed = accepted = 0
         for j, req in enumerate(self._requests):
             if req is None or not self._active[j]:
                 continue
+            self._delivered(req, now)   # an active row emits every round
             n = 0
             while n < k and drafts[j, n] == greedy[j, n]:
                 n += 1
@@ -2069,7 +2159,6 @@ class ContinuousGenerator:
             self._slot_priv[slot] = []
             self._slot_shared[slot] = 0
             self._page_table[slot, :] = self._alloc.trash
-        dur = time.monotonic() - req.t_submit
         if status == "ok":
             out = np.asarray(req.tokens[:req.max_new], np.int32)
             try:
@@ -2086,9 +2175,20 @@ class ContinuousGenerator:
             except Exception:
                 status = "cancelled"
             self.metrics.incr("serve.gen.failed")
-        run_ledger.emit("serve.request", rid=req.rid, status=status,
-                        dur_s=dur, tokens=len(req.tokens), slot=slot,
-                        **self._tags)
+        self._emit_request(req, status, tokens=len(req.tokens), slot=slot)
+
+    def _emit_request(self, req: GenRequest, status: str, **fields) -> None:
+        """The one ``serve.request`` record of a request, whatever became
+        of it: how long it took and its timeline."""
+        led = run_ledger.get_ledger()
+        if led is None:
+            return
+        rec = {"type": "serve.request", "rid": req.rid, "status": status,
+               "dur_s": time.monotonic() - req.t_submit}
+        rec.update(req.timeline.fields())
+        rec.update(fields)
+        rec.update(self._tags)
+        led.emit(rec)
 
     def _run_end(self, wall_s: float) -> None:
         led = run_ledger.get_ledger()
@@ -2122,6 +2222,7 @@ class ContinuousGenerator:
         local, _, _ = self.metrics.snapshot()
         out = {
             "counters": {name: v for name, (v, _p) in local.items()},
+            "histograms": self.metrics.hist_snapshot(),
             "queue_depth": self.queue.depth,
             "slots": self.slots.num_slots,
             "active": int(self._active.sum()),

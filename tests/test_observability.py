@@ -8,6 +8,7 @@ keys) from which ``run-report`` reconstructs the per-phase breakdown
 matching ``Metrics`` — for BOTH trainers.
 """
 
+import glob
 import json
 import logging
 import math
@@ -646,10 +647,9 @@ def test_trace_export_cli_on_synthetic_ledger(tmp_path, capsys):
 class _ObsAugment:
     """Module-level (spawn-picklable) pass-through augment chain: its
     only job is making the ingest workers emit ingest.augment spans.
-    Each sample costs 20 ms so that the run outlasts the spawn jitter
-    between the two workers (up to ~0.5 s): with a free augment the
-    first worker up can serve the whole run before the second binds,
-    and the >= 3 pids the stitch test asserts become a coin flip."""
+    Each sample costs 20 ms, which paces the run (a batch of 8 is
+    0.16 s of one worker) while the stitch test waits for the second
+    worker to come up and serve a chunk."""
 
     def __call__(self, it):
         for s in it:
@@ -661,6 +661,19 @@ class _ObsAugment:
 
     def reseed(self, seed):
         pass
+
+
+def _workers_with_spans(run_dir) -> int:
+    """Ingest-worker ledger files that already hold an augment span (the
+    workers' writer threads drain every 0.25 s, so a span reaches the
+    file while the run goes on)."""
+    n = 0
+    for path in glob.glob(os.path.join(run_dir, "events-*.jsonl")):
+        if path.endswith(f"events-{os.getpid()}.jsonl"):
+            continue
+        with open(path, encoding="utf-8") as f:
+            n += '"ingest.augment"' in f.read()
+    return n
 
 
 def test_trace_export_stitches_two_worker_training_run(tmp_path):
@@ -686,8 +699,21 @@ def test_trace_export_stitches_two_worker_training_run(tmp_path):
         ds = ShardedDataSet(samples, augment=_ObsAugment(),
                             batcher=SampleToBatch(8), workers=2, chunk=6)
         model = LeNet5(10).build(seed=1)
+        # ten iterations, and on until BOTH workers have served a chunk:
+        # the executor spawns them one by one and a spawn takes seconds
+        # on a loaded machine (six test workers share this one's cores),
+        # so the first one up can serve any fixed number of iterations
+        # alone and the second then binds its ledger without ever
+        # writing a span.  Bounded: with one worker the augment's 20 ms
+        # a sample make 300 iterations about a minute.
+        class BothWorkersServed(Trigger):
+            def __call__(self, state):
+                return state["neval"] >= 300 or (
+                    state["neval"] >= 10
+                    and _workers_with_spans(run_dir) >= 2)
+
         opt = LocalOptimizer(model, nn.ClassNLLCriterion(), ds,
-                             Trigger.max_iteration(10))
+                             BothWorkersServed())
         opt.set_optim_method(SGD(learning_rate=0.01))
         opt.optimize()
         run_ledger.flush()
