@@ -763,15 +763,19 @@ def fused_attention(q, k, v, causal: bool = False, scale=None,
 # per-row KV view in HBM before attending — (B, Hkv, Lp*ps, D) written
 # out and read back every decode step.  This kernel removes that round
 # trip: the host page table rides in as a SCALAR-PREFETCH operand, each
-# grid step DMAs one physical pool page straight into a VMEM scratch
-# row (the index map does the gather — the view never exists in HBM),
-# and the last page's step computes the same masked softmax attention
-# the jnp reference runs on the materialised view.  Math is kept
-# OPERATION-FOR-OPERATION identical to `nn.MultiHeadAttention
-# .apply_decode_pages`'s gather path (zero trash pages, f32 scores,
-# -inf validity mask, f32 softmax, cache-dtype weighted sum), so the
-# outputs are bit-parity-gated against `decode_pages` in tests and the
-# bench-serve ablation.
+# grid step DMAs one physical pool page — EVERY KV head of it, the page
+# being contiguous in the pool — straight into its slot of a VMEM
+# scratch (the index map does the gather — the view never exists in
+# HBM), and the last step computes the same masked softmax attention
+# the jnp reference runs on the materialised view.  The walk stops at
+# the row's last visible page: a second scalar-prefetch operand carries
+# it, the index map clamps the table slot to it (the steps beyond name
+# the block already resident, so the pipeline moves nothing) and their
+# scratch slots are zero-filled.  Math is kept OPERATION-FOR-OPERATION
+# identical to `nn.MultiHeadAttention.apply_decode_pages`'s gather path
+# (zero trash pages, f32 scores, -inf validity mask, f32 softmax,
+# cache-dtype weighted sum), so the outputs are bit-parity-gated against
+# `decode_pages` in tests and the bench-serve ablation.
 
 def paged_attention_enabled() -> bool:
     """Dispatch gate for the paged-attention kernel: on wherever the
@@ -783,24 +787,100 @@ def paged_attention_enabled() -> bool:
     return _use_pallas()
 
 
-def _paged_kernel(pages_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
-                  k_scr, v_scr, *, lp, ps, trash, scale):
-    # grid (B, H, Lp): pages stream into scratch; compute fires on the
-    # row's last page.  k_ref/v_ref blocks were already gathered BY THE
-    # INDEX MAP (pages_ref[b, l] picked the pool row), so the kernel
-    # only zeroes trash pages — the reference's tmask — and attends.
-    b = pl.program_id(0)
-    l = pl.program_id(2)
-    is_trash = pages_ref[b, l] == trash
-    row = pl.multiple_of(l * ps, ps)
-    k_scr[pl.ds(row, ps), :] = jnp.where(is_trash, 0, k_ref[0, 0])
-    v_scr[pl.ds(row, ps), :] = jnp.where(is_trash, 0, v_ref[0, 0])
+# up to this many bytes of f32 scores, the heads of a step are scored in
+# ONE batched dot_general (a decode step, a speculative verify); above
+# it (a prefill bucket) a loop runs head by head
+_PAGED_BATCHED_SCORES = 4 * 1024 * 1024
+# VMEM, as (what the head-group rule plans a step for, what the call
+# declares): the compiler's default scoped limit of 16 MiB is under the
+# K and V scratch of one GPT-2 XL row, and what the kernel does not
+# declare the compiler uses to keep the pool's relayouts on chip.  A
+# decode call serves every slot and each head group would multiply the
+# steps of every row (5 groups: the kernel 4 times slower), so it plans
+# for all heads in one block; a prefill call serves one row, where the
+# split costs a few hundred cheap steps, so it stays lean (the cache
+# write of a GPT-2 XL prefill: 25.3 ms under 48 MiB, 20.0 under 24).
+# Measured on a v5e (PERF.md, PR 25), where a decode step took 39.8 ms
+# under 48 MiB and 46.0-48.3 under 24, 32 and 96: the compiler's
+# placement of the pool's copies makes most of that difference.
+_PAGED_VMEM_DECODE = (40 * 1024 * 1024, 48 * 1024 * 1024)
+_PAGED_VMEM_PREFILL = (20 * 1024 * 1024, 24 * 1024 * 1024)
 
-    @pl.when(l == lp - 1)
-    def _compute():
-        q = q_ref[0, 0]                              # (S, D)
-        kk = k_scr[...]                              # (L, D) cache dtype
-        vv = v_scr[...]
+
+def _paged_scores_bytes(queries, length):
+    """The f32 scores of one head: ``queries`` rows (to a sublane
+    tile) of ``length`` keys."""
+    return -(-queries // 8) * 8 * length * 4
+
+
+def _paged_vmem_bytes(heads, group, queries, length, d, ps, itemsize,
+                      batched):
+    """VMEM of one grid step holding ``heads`` KV heads: the K and V
+    scratch, the double-buffered query, output and page blocks, and the
+    f32 scores (of every head where they are ``batched``) with their
+    softmax temporaries — in tiles as Mosaic lays them out (minor dim
+    to 128 lanes, rows to a 32-byte sublane pack)."""
+    lanes = -(-d // 128) * 128
+    sub = 8 * max(1, 4 // itemsize)
+    q_rows = group * (-(-queries // sub) * sub)
+    ps_rows = -(-ps // sub) * sub
+    blocks = heads * lanes * itemsize * (2 * length + 4 * q_rows
+                                         + 4 * ps_rows)
+    scores = _paged_scores_bytes(queries, length) * (heads if batched else 1)
+    return blocks + 4 * scores
+
+
+def _paged_plan(hkv, group, queries, length, d, ps, itemsize):
+    """(head groups, batched, VMEM to declare) of a call: the KV heads
+    are split over the grid into the fewest groups (a divisor of
+    ``hkv``) whose step fits what its kind of call plans for — a
+    function of the shapes and the dtype's size only."""
+    # the scores of all KV heads small enough for one batched
+    # dot_general: a decode step, not a prefill bucket
+    few = hkv * _paged_scores_bytes(queries, length) <= _PAGED_BATCHED_SCORES
+    budget, limit = _PAGED_VMEM_DECODE if few else _PAGED_VMEM_PREFILL
+    for groups in range(1, hkv + 1):
+        if hkv % groups == 0 and _paged_vmem_bytes(
+                hkv // groups, group, queries, length, d, ps, itemsize,
+                few) <= budget:
+            break
+    # a lone head has no batch axis to score over
+    return groups, few and groups < hkv, limit
+
+
+def _paged_kernel(pages_ref, last_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
+                  k_scr, v_scr, *, lp, ps, trash, scale, batched):
+    # grid (B, head groups, Lp), walked in order: a row's pages stream
+    # into scratch, one page of every KV head of the group a step;
+    # compute fires on the row's last step.  k_ref/v_ref blocks were
+    # already gathered BY THE INDEX MAP (pages_ref[b, min(l, last)]
+    # picked the pool row), so the kernel only zeroes what must read as
+    # zero — trash pages, the reference's tmask, and the slots past the
+    # row's last visible page, whose block the pipeline did not fetch:
+    # the weights there are exactly 0 after the -inf mask, but 0 x NaN
+    # is NaN, so they may not keep what an earlier row left — and
+    # attends.  The scratch past the walk of whoever filled it before
+    # (the same row's previous head group, else the previous row; the
+    # whole of it on the first step of all) is zero already.
+    b, g, l = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    last = last_ref[b]
+    filled = jnp.where(g > 0, last, jnp.where(
+        b > 0, last_ref[jnp.maximum(b - 1, 0)], lp - 1))
+    live = jnp.logical_and(l <= last, pages_ref[b, l] != trash)
+    row = pl.multiple_of(l * ps, ps)
+
+    @pl.when(live)
+    def _copy():
+        k_scr[:, pl.ds(row, ps), :] = k_ref[0]
+        v_scr[:, pl.ds(row, ps), :] = v_ref[0]
+
+    @pl.when(jnp.logical_and(jnp.logical_not(live),
+                             l <= jnp.maximum(last, filled)))
+    def _zero():
+        k_scr[:, pl.ds(row, ps), :] = jnp.zeros_like(k_ref[0])
+        v_scr[:, pl.ds(row, ps), :] = jnp.zeros_like(v_ref[0])
+
+    def attend(q, kk, vv, dims_qk, dims_pv):
         # the reference gather path's math, including its dtype
         # promotion: scores round to the promoted operand dtype exactly
         # where the reference einsum does (bf16 x bf16 scores are bf16
@@ -810,16 +890,39 @@ def _paged_kernel(pages_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
         # 32-bit"), which is also what XLA's bf16 dot does before it
         # rounds — so the rounding point, not the accumulator, is what
         # the parity gate pins.
-        s = jax.lax.dot_general(
-            q, kk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        s = jax.lax.dot_general(q, kk, dims_qk,
+                                preferred_element_type=jnp.float32)
         s = s.astype(jnp.result_type(q.dtype, kk.dtype)) * scale
-        lidx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        lidx = jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
         s = jnp.where(lidx <= pos_ref[0], s, -jnp.inf)   # pos (S, 1)
         w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-        o_ref[0, 0] = jnp.dot(
-            w.astype(vv.dtype), vv,
+        return jax.lax.dot_general(
+            w.astype(vv.dtype), vv, dims_pv,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    @pl.when(l == lp - 1)
+    def _compute():
+        # q_ref (1, Hg, group, S, D): query head h reads KV head
+        # h // group, so the group's heads are the third axis and each
+        # takes the math of the reference on its own (S, D) queries
+        for gq in range(q_ref.shape[2]):
+            if batched:
+                # few queries a head (a decode step): all heads at once
+                o_ref[0, :, gq] = attend(
+                    q_ref[0, :, gq], k_scr[...], v_scr[...],
+                    (((2,), (2,)), ((0,), (0,))),
+                    (((2,), (1,)), ((0,), (0,))))
+            else:
+                # a prefill bucket: the (S, L) f32 scores of one head
+                # at a time
+                def head(j, carry):
+                    o_ref[0, j, gq] = attend(
+                        q_ref[0, j, gq], k_scr[j], v_scr[j],
+                        (((1,), (1,)), ((), ())),
+                        (((1,), (0,)), ((), ())))
+                    return carry
+
+                jax.lax.fori_loop(0, k_scr.shape[0], head, 0)
 
 
 def paged_attention(q, k_pool, v_pool, pages, positions, scale):
@@ -828,8 +931,12 @@ def paged_attention(q, k_pool, v_pool, pages, positions, scale):
     (P+1, Hkv, ps, D) whose LAST page is the write-redirect trash page,
     ``pages`` (B, Lp) int32 host page table, ``positions`` (B, S) — key
     slot ``l`` visible to row token ``s`` iff ``l <= positions[b, s]``
-    (the decode validity predicate).  GQA shares KV pages via the index
-    map (kv head = h // group), like the training kernels.  Returns
+    (the decode validity predicate).  A grid step moves one pool page
+    with every KV head of its group (all of them where the row fits
+    VMEM: ``_paged_plan``), and none for the table slots past
+    the row's last visible key.  GQA shares a KV head among the
+    ``group`` query heads ``[j*group, (j+1)*group)`` inside the kernel
+    (kv head = h // group, as ``expand_kv_heads``).  Returns
     (B, H, S, D) in the cache dtype — bit-parity with the
     ``apply_decode_pages`` gather path is the acceptance gate."""
     from jax.experimental.pallas import tpu as pltpu
@@ -840,36 +947,48 @@ def paged_attention(q, k_pool, v_pool, pages, positions, scale):
     trash = k_pool.shape[0] - 1
     lp = pages.shape[1]
     length = lp * ps
+    groups, batched, vmem_limit = _paged_plan(
+        hkv, group, s, length, d, ps, jnp.dtype(k_pool.dtype).itemsize)
+    hg = hkv // groups
+    positions = jnp.asarray(positions, jnp.int32)
+    # the last logical page any query of the row can see
+    last = jnp.clip(jnp.max(positions, axis=1) // ps, 0, lp - 1)
     kern = functools.partial(_paged_kernel, lp=lp, ps=ps, trash=trash,
-                             scale=float(scale))
+                             scale=float(scale), batched=batched)
+
+    def q_block(bi, gi, li, pg, la):
+        return bi, gi, 0, 0, 0
+
+    def page_block(bi, gi, li, pg, la):
+        return pg[bi, jnp.minimum(li, la[bi])], gi, 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, h, lp),
+        num_scalar_prefetch=2,
+        grid=(b, groups, lp),
         in_specs=[
-            pl.BlockSpec((1, 1, s, d),
-                         lambda bi, hi, li, pg: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, hg, group, s, d), q_block),
             # positions ride as a (B, S, 1) column so every block's last
             # two dims are the array's own (a (1, S) block over (B, S)
             # is neither (8, 128)-aligned nor full-width once B > 1) and
             # the kernel needs no lane-to-sublane relayout
-            pl.BlockSpec((1, s, 1), lambda bi, hi, li, pg: (bi, 0, 0)),
-            pl.BlockSpec((1, 1, ps, d),
-                         lambda bi, hi, li, pg: (pg[bi, li],
-                                                 hi // group, 0, 0)),
-            pl.BlockSpec((1, 1, ps, d),
-                         lambda bi, hi, li, pg: (pg[bi, li],
-                                                 hi // group, 0, 0)),
+            pl.BlockSpec((1, s, 1), lambda bi, gi, li, pg, la: (bi, 0, 0)),
+            pl.BlockSpec((1, hg, ps, d), page_block),
+            pl.BlockSpec((1, hg, ps, d), page_block),
         ],
-        out_specs=pl.BlockSpec((1, 1, s, d),
-                               lambda bi, hi, li, pg: (bi, hi, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((length, d), k_pool.dtype),
-                        pltpu.VMEM((length, d), v_pool.dtype)],
+        out_specs=pl.BlockSpec((1, hg, group, s, d), q_block),
+        scratch_shapes=[pltpu.VMEM((hg, length, d), k_pool.dtype),
+                        pltpu.VMEM((hg, length, d), v_pool.dtype)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), k_pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), k_pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # in order: a step relies on what the one before it left
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=vmem_limit),
         interpret=_interpret(),
         name="paged_attention",
-    )(jnp.asarray(pages, jnp.int32), q,
-      jnp.asarray(positions, jnp.int32)[:, :, None], k_pool, v_pool)
+    )(jnp.asarray(pages, jnp.int32), last, q.reshape(b, hkv, group, s, d),
+      positions[:, :, None], k_pool, v_pool)
+    return out.reshape(b, h, s, d)

@@ -579,6 +579,8 @@ class ContinuousGenerator:
         self._token_occupancy_sum = 0.0
         self._spec_proposed = 0
         self._spec_accepted = 0
+        self._pages_walked = 0
+        self._pages_table = 0
 
         self._build_programs()
         if warmup:
@@ -1593,7 +1595,8 @@ class ContinuousGenerator:
             return True
         try:
             with tracer.span("serve.prefill", slot=slot, bucket=bucket,
-                             tp=tp, shared_tokens=start, rid=req.rid):
+                             tp=tp, shared_tokens=start, rid=req.rid,
+                             **self._walk_attrs(start + bucket - 1)):
                 first, self._cache = self._prefill_fn(
                     self.params, self.state, suffix_dev, ts,
                     self._cache, table_dev, start, key)
@@ -1777,7 +1780,8 @@ class ContinuousGenerator:
         try:
             with tracer.span("serve.prefill", slot=slot, bucket=bucket,
                              tp=tp, shared_tokens=kv_start,
-                             rid=req.rid, sid=sess.sid):
+                             rid=req.rid, sid=sess.sid,
+                             **self._walk_attrs(kv_start + bucket - 1)):
                 first, self._cache = self._prefill_fn(
                     self.params, self.state, suffix_dev, ts,
                     self._cache, table_dev, kv_start, key)
@@ -1853,11 +1857,13 @@ class ContinuousGenerator:
         if gap is not None:
             self.metrics.observe("serve.gen.chunk_gap_s", gap)
 
-    def _decode_attrs(self) -> dict:
+    def _decode_attrs(self, queries: int = 1) -> dict:
         """What a ``serve.decode`` span says of the work it covers
         (computed only while the ledger is on): ``ctx_tokens``, the sum
-        of the active rows' positions, and ``pages_mapped``, the pages
-        their table rows map."""
+        of the active rows' positions, ``pages_mapped``, the pages their
+        table rows map, and what the paged kernel walks of those tables
+        at the chunk's first step (``queries`` kernel rows a slot: the
+        speculative verify expands each slot into ``k + 1``)."""
         if not run_ledger.enabled():
             return {}
         act = self._active
@@ -1865,7 +1871,29 @@ class ContinuousGenerator:
         if self._paged:
             out["pages_mapped"] = int(
                 (self._page_table[act] != self._alloc.trash).sum())
+            out.update(self._walk_attrs(
+                self._pos[act][:, None] + np.arange(queries)))
         return out
+
+    def _walk_attrs(self, last_visible) -> dict:
+        """``pages_walked`` and ``pages_table`` of one call of the paged
+        kernel (only while the ledger is on): of the ``Lp`` table slots
+        of each kernel row, those up to the page of its last visible key
+        (``last_visible``, one position a row) are walked, the rest move
+        no data.  Their running ratio is the gauge ``serve.paged walk
+        share``."""
+        if not (self._paged_kernel and run_ledger.enabled()):
+            return {}
+        last = np.asarray(last_visible).reshape(-1) // self._alloc.page_size
+        walked = int((np.clip(last, 0, self._lp - 1) + 1).sum())
+        table = int(last.size * self._lp)
+        self._pages_walked += walked
+        self._pages_table += table
+        if self._pages_table:
+            self.metrics.set("serve.paged walk share",
+                             self._pages_walked / self._pages_table,
+                             unit="scalar")
+        return {"pages_walked": walked, "pages_table": table}
 
     def _commit_placed(self, req: GenRequest, slot: int, tp: int,
                        first: int, bucket: int) -> None:
@@ -2006,7 +2034,7 @@ class ContinuousGenerator:
         k = self.spec_k
         with tracer.span("serve.decode", chunk=self._chunks,
                          active=n_active, steps=1, spec_k=k,
-                         **self._decode_attrs()):
+                         **self._decode_attrs(queries=k + 1)):
             drafts, greedy, self._cache, self._dcache = self._spec_fn(
                 self.params, self.state, self._draft_params,
                 self._draft_state, jnp.asarray(self._tokens),

@@ -314,10 +314,15 @@ def _attention_rows(t_fused, t_stream, heads, head_dim):
     return rows
 
 
-def _paged_rows(slots, heads, head_dim, max_len, page_size, prefill):
+def _paged_rows(slots, heads, wide_heads, head_dim, max_len, page_size,
+                prefill):
     """``paged_attention`` against the gather path of
-    ``apply_decode_pages`` for a decode step (S=1, every slot) and a
-    prefill bucket (one row), bf16 cache, tables part trash."""
+    ``apply_decode_pages``, bf16 cache, tables part trash: a decode step
+    (S=1, every slot) and a prefill bucket (one row) at the serve
+    model's heads; and at ``wide_heads`` (GPT-2 XL's 25: every KV head
+    of a page in one block) a decode step whose slots hold one to three
+    pages, so that nearly all of the walk is skipped, and one whose
+    slots are full to the last position, so that none is."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -326,27 +331,35 @@ def _paged_rows(slots, heads, head_dim, max_len, page_size, prefill):
     from bigdl_tpu.nn.attention import MultiHeadAttention
     from bigdl_tpu.ops import attention as ops_attention
 
-    embed = heads * head_dim
-    attn = MultiHeadAttention(embed, heads)
-    params = jax.jit(lambda k: cast_tree(attn.init_params(k), jnp.bfloat16))(
-        jax.random.PRNGKey(0))
     lp = max_len // page_size
     rs = np.random.RandomState(2)
+    few = min(3 * page_size, max_len) - 1
+    layers = {}
+    for h in (heads, wide_heads):
+        attn = MultiHeadAttention(h * head_dim, h)
+        layers[h] = attn, jax.jit(lambda k: cast_tree(
+            attn.init_params(k), jnp.bfloat16))(jax.random.PRNGKey(0))
     rows = []
-    for name, b, s in (("decode", slots, 1), ("prefill", 1, prefill)):
+    # row i holds pos[i] cached tokens before its s queries
+    for name, b, s, h, lo, hi in (
+            ("decode", slots, 1, heads, page_size, max_len - 1),
+            ("prefill", 1, prefill, heads, page_size, max_len - prefill),
+            ("decode.short", slots, 1, wide_heads, 0, few),
+            ("decode.full", slots, 1, wide_heads, max_len - 1, max_len)):
+        attn, params = layers[h]
         num_pages = b * lp
-        ck, cv = [jax.random.normal(k, (num_pages + 1, heads, page_size,
+        ck, cv = [jax.random.normal(k, (num_pages + 1, h, page_size,
                                         head_dim), jnp.bfloat16)
                   for k in jax.random.split(jax.random.PRNGKey(s), 2)]
-        # row i holds pos[i] cached tokens; the rest of its table is trash
-        pos = rs.randint(page_size, max_len - s, b).astype(np.int32)
+        pos = rs.randint(lo, hi, b).astype(np.int32)
+        # the rest of a row's table is trash
         pages = np.full((b, lp), num_pages, np.int32)
         perm = rs.permutation(num_pages)
         for i in range(b):
             used = -(-(int(pos[i]) + s) // page_size)
             pages[i, :used] = perm[i * lp:i * lp + used]
-        x = jax.random.normal(jax.random.PRNGKey(7), (b, s, embed),
-                              jnp.bfloat16)
+        x = jax.random.normal(jax.random.PRNGKey(7),
+                              (b, s, h * head_dim), jnp.bfloat16)
 
         def run():
             return jax.jit(lambda p, x, k, v: attn.apply_decode_pages(
@@ -362,8 +375,8 @@ def _paged_rows(slots, heads, head_dim, max_len, page_size, prefill):
         with mock.patch.object(ops_attention, "paged_attention_enabled",
                                return_value=False):
             gather = run()
-        rows.append((f"paged_attention.{name} B={b} S={s} L={max_len}",
-                     _rel_err(kernel, gather), TOL_BF16))
+        rows.append((f"paged_attention.{name} B={b} H={h} S={s} "
+                     f"L={max_len}", _rel_err(kernel, gather), TOL_BF16))
     return rows
 
 
@@ -446,8 +459,9 @@ def _fp16_rows(n):
              0.0 if bool(jax.jit(codec)(a, b)) else 1.0, 0.0)]
 
 
-def phase_kernels(t_fused=1024, t_stream=4096, heads=8, head_dim=64,
-                  slots=8, max_len=1024, page_size=16, prefill=128,
+def phase_kernels(t_fused=1024, t_stream=4096, heads=8, wide_heads=25,
+                  head_dim=64, slots=8, max_len=1024, page_size=16,
+                  prefill=128,
                   matmuls=((8, 512, 2048), (128, 2048, 512),
                            (8, 512, 32000)),
                   conv=(8, 192, 28, 128, 3), fp16_n=7_000_000,
@@ -455,7 +469,8 @@ def phase_kernels(t_fused=1024, t_stream=4096, heads=8, head_dim=64,
     """Defaults are the shapes phases 2-3 and the quantised rungs run:
     attention at the serve model's head dim over its cache length (fused)
     and past the fused kernel's VMEM cut (streaming); the paged kernel
-    for one decode step of every slot and one prefill bucket; the packed
+    for one decode step of every slot and one prefill bucket, and at
+    GPT-2 XL's 25 heads for nearly empty and for full slots; the packed
     matmuls of a decode step (ffn up, logits) and a prefill bucket (ffn
     down) at (M, K, N); an Inception 3x3 conv as (N, C, HW, O, k)."""
     from bigdl_tpu.ops import attention
@@ -465,7 +480,8 @@ def phase_kernels(t_fused=1024, t_stream=4096, heads=8, head_dim=64,
           f"kernels compiled={not attention._interpret()}, "
           f"expected {compiled}")
     rows = (_attention_rows(t_fused, t_stream, heads, head_dim)
-            + _paged_rows(slots, heads, head_dim, max_len, page_size, prefill)
+            + _paged_rows(slots, heads, wide_heads, head_dim, max_len,
+                          page_size, prefill)
             + _quant_rows(matmuls, conv)
             + _fp16_rows(fp16_n))
     for name, err, tol in rows:

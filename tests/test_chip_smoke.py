@@ -71,10 +71,13 @@ def test_interpreted_kernel_does_not_pass_for_compiled(interpret_mode):
 
 def test_phase_kernels_toy(interpret_mode):
     line = chip_smoke.phase_kernels(
-        t_fused=16, t_stream=16, heads=1, head_dim=64, slots=2,
-        max_len=48, page_size=16, prefill=16, matmuls=((2, 128, 128),),
-        conv=(1, 8, 6, 16, 3), fp16_n=1000, compiled=False)
-    assert line.startswith("kernels=11 interpreted")
+        t_fused=16, t_stream=16, heads=1, wide_heads=3, head_dim=64,
+        slots=2, max_len=64, page_size=16, prefill=16,
+        matmuls=((2, 128, 128),), conv=(1, 8, 6, 16, 3), fp16_n=1000,
+        compiled=False)
+    # the paged kernel's four rows: a decode step, a prefill bucket,
+    # nearly empty slots and full ones
+    assert line.startswith("kernels=13 interpreted")
 
 
 def test_phase_kernels_refuses_the_reference_path():

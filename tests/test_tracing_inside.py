@@ -217,6 +217,49 @@ def test_decode_spans_carry_context_and_pages(serve_records):
     assert prefills and all("rid" in r["attrs"] for r in prefills)
 
 
+def test_walk_counters_equal_a_replay_of_the_positions(tmp_path,
+                                                       monkeypatch):
+    """``pages_walked`` / ``pages_table`` on the spans of the calls that
+    hold the paged kernel equal numpy's count over the positions the
+    slots had when the call was made, and ``stats()`` shows their
+    running ratio."""
+    monkeypatch.setenv("BIGDL_TPU_PALLAS_INTERPRET", "1")
+    ps, lp = 4, 64 // 4
+    run_ledger.set_run_dir(str(tmp_path))
+    try:
+        g = _gen(paged_kernel=True)
+        seen = []                   # positions of the active rows, a chunk
+        chunk = g._plain_chunk
+        monkeypatch.setattr(g, "_plain_chunk", lambda: (
+            seen.append(g._pos[g._active].copy()), chunk())[1])
+        futs = [g.submit(list(range(3, 3 + n)), new)
+                for n, new in ((5, 9), (14, 4), (2, 7))]
+        for f in futs:
+            f.result(120)
+        share = g.stats()["counters"]["serve.paged walk share"]
+        g.drain(timeout=30)
+        run_ledger.flush()
+    finally:
+        run_ledger.set_run_dir(None)
+    records, bad = load_ledger(str(tmp_path))
+    assert bad == 0
+    spans = [r for r in records if r.get("type") == "span"]
+    decodes = [r["attrs"] for r in spans if r["name"] == "serve.decode"]
+    assert len(decodes) == len(seen) >= 3
+    for a, pos in zip(decodes, seen):
+        assert a["pages_walked"] == int((pos // ps + 1).sum())
+        assert a["pages_table"] == pos.size * lp == a["active"] * lp
+    prefills = [r["attrs"] for r in spans if r["name"] == "serve.prefill"]
+    assert len(prefills) == 3
+    for a in prefills:
+        # a prefill's queries run to the end of its bucket
+        last = a["shared_tokens"] + a["bucket"] - 1
+        assert a["pages_walked"] == last // ps + 1 and a["pages_table"] == lp
+    walked = sum(a["pages_walked"] for a in decodes + prefills)
+    table = sum(a["pages_table"] for a in decodes + prefills)
+    assert share == pytest.approx(walked / table) and 0 < share < 0.5
+
+
 def test_run_start_comes_with_a_clock_record(serve_records):
     clocks = [r for r in serve_records if r.get("type") == "clock"]
     assert len(clocks) == 1

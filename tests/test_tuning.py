@@ -493,6 +493,132 @@ class TestPagedAttention:
         assert np.isfinite(outs["1"]).all()
         assert np.array_equal(outs["1"], outs["0"])
 
+    @staticmethod
+    def _gather(q, kp, vp, pages, pos, scale):
+        """The jnp gather path of ``apply_decode_pages``: the oracle."""
+        from bigdl_tpu.ops.attention import expand_kv_heads
+        b, hkv, ps, d = q.shape[0], kp.shape[1], kp.shape[2], q.shape[3]
+        lp, trash = pages.shape[1], kp.shape[0] - 1
+        kk = kp[pages].transpose(0, 2, 1, 3, 4).reshape(b, hkv,
+                                                        lp * ps, d)
+        vv = vp[pages].transpose(0, 2, 1, 3, 4).reshape(b, hkv,
+                                                        lp * ps, d)
+        tmask = jnp.repeat(pages == trash, ps, axis=1)[:, None, :, None]
+        kk = jnp.where(tmask, 0, kk)
+        vv = jnp.where(tmask, 0, vv)
+        kk, vv = expand_kv_heads(q, kk, vv)
+        scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
+        valid = (jnp.arange(lp * ps)[None, None, :] <= pos[:, :, None])
+        scores = jnp.where(valid[:, None], scores, -jnp.inf)
+        wts = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        return jnp.einsum("bhsl,bhld->bhsd", wts.astype(vv.dtype), vv)
+
+    # first query position of each row, in the grid's order; tables of
+    # 4 pages of 16.  A row's table maps the pages its queries reach.
+    WALKS = {
+        # the shortest on its first page only, the longest filling its
+        # table, in one call
+        "mixed_contexts": dict(ctx=[3, 59, 20]),
+        # a trash slot INSIDE the walk (an unmapped hole before the
+        # row's last visible page)
+        "trash_inside_walk": dict(ctx=[40, 59], hole={0: 1}),
+        # a short row after a long one: its scratch past page 0 holds
+        # the long row's (finite) keys unless the kernel zeroes it
+        "stale_finite": dict(ctx=[59, 2, 35, 1]),
+        # the same with NaN where the long row itself cannot see: its
+        # own output is NaN on both paths (0 x NaN), the short rows
+        # after it must stay finite
+        "stale_nan": dict(ctx=[50, 2, 1], nan_after=0),
+    }
+
+    # a lone query is scored for all heads of a step in one batched
+    # dot_general (the shape rule); the head-by-head form is a prefill
+    # bucket's, and XLA's CPU backend rounds a one-row product taken
+    # from a slice apart from the oracle's batched einsum, so only the
+    # chip's tolerance (chip_smoke) can gate that pairing
+    @pytest.mark.parametrize("s,variant", [
+        (1, "one_group"), (1, "head_groups"), (5, "one_group"),
+        (5, "head_groups"), (5, "lone_heads"), (5, "head_loop")])
+    @pytest.mark.parametrize("case", sorted(WALKS))
+    def test_walk_bit_parity_vs_gather(self, interpret_mode, monkeypatch,
+                                       case, s, variant):
+        """The bounded walk against the gather path, ``np.array_equal``:
+        GQA group 2 over an ODD number of KV heads, pages of 16, width
+        64, a decode step and a 5-token verify; every KV head in one
+        block, the heads split into two groups (a group's scratch then
+        follows its own row's, not the previous row's), one head a
+        group, and scored head by head."""
+        from bigdl_tpu.ops import attention as A
+        hkv, d, ps, lp = 3, 64, 16, 4
+        groups = 1
+        limit = A._PAGED_VMEM_DECODE[1]
+        if variant == "head_groups":
+            hkv, groups = 6, 2
+            monkeypatch.setattr(A, "_PAGED_VMEM_DECODE", (
+                A._paged_vmem_bytes(3, 2, s, lp * ps, d, ps, 4, True),
+                limit))
+        elif variant == "lone_heads":
+            groups = 3
+            monkeypatch.setattr(A, "_PAGED_VMEM_DECODE", (0, limit))
+        elif variant == "head_loop":
+            monkeypatch.setattr(A, "_PAGED_BATCHED_SCORES", 0)
+        h = 2 * hkv
+        assert A._paged_plan(hkv, 2, s, lp * ps, d, ps, 4)[:2] == (
+            groups, variant in ("one_group", "head_groups"))
+        spec = self.WALKS[case]
+        ctx = np.asarray(spec["ctx"])
+        b = len(ctx)
+        rng = np.random.RandomState(3)
+        p = b * lp
+        q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
+        kp, vp = self._pools(rng, p, hkv, ps, d)
+        pos = ctx[:, None] + np.arange(s)[None]
+        pages = np.full((b, lp), p, np.int32)
+        for i in range(b):
+            used = min(lp, pos[i, -1] // ps + 1)
+            pages[i, :used] = np.arange(i * lp, i * lp + used)
+        for i, slot in spec.get("hole", {}).items():
+            pages[i, slot] = p
+        if "nan_after" in spec:
+            i = spec["nan_after"]
+            page, off = divmod(int(pos[i, -1]) + 1, ps)
+            assert 0 < off and page == pos[i, -1] // ps
+            vp = vp.at[pages[i, page], :, off:].set(jnp.nan)
+        pages, pos = jnp.asarray(pages), jnp.asarray(pos, jnp.int32)
+        scale = 1.0 / np.sqrt(d)
+        want = np.asarray(self._gather(q, kp, vp, pages, pos, scale))
+        got = np.asarray(A.paged_attention(q, kp, vp, pages, pos, scale))
+        clean = [i for i in range(b) if i != spec.get("nan_after")]
+        assert np.isfinite(got[clean]).all()
+        assert np.isnan(got).any() == ("nan_after" in spec)
+        assert np.array_equal(want, got, equal_nan=True)
+
+    @pytest.mark.parametrize("s", [1, 256, 768])
+    def test_grid_has_no_head_axis(self, s):
+        """Structural guard at GPT-2 XL's shapes: a grid step holds a
+        page of every KV head of its group, so the grid is rows x head
+        groups x table slots and no axis has the heads' extent."""
+        from bigdl_tpu.ops import attention as A
+        b, h, d, ps, lp = (10 if s == 1 else 1), 25, 64, 16, 64
+        pool = jax.ShapeDtypeStruct((b * lp + 1, h, ps, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: A.paged_attention(*a, 0.125))(
+            jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16), pool, pool,
+            jax.ShapeDtypeStruct((b, lp), jnp.int32),
+            jax.ShapeDtypeStruct((b, s), jnp.int32))
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        grid = tuple(calls[0].params["grid_mapping"].grid)
+        groups, batched, limit = A._paged_plan(h, 1, s, lp * ps, d, ps, 2)
+        # a decode step holds every head and scores them at once; a
+        # prefill bucket splits them to stay lean
+        assert (groups, batched) == ((1, True) if s == 1 else (5, False))
+        assert limit == (A._PAGED_VMEM_DECODE if s == 1
+                         else A._PAGED_VMEM_PREFILL)[1]
+        assert grid == (b, groups, lp)
+        assert int(np.prod(grid)) <= b * lp * groups and h not in grid
+
     def test_decode_pages_kernel_on_off_bit_equal(self, interpret_mode,
                                                   monkeypatch):
         """The integration gate: TransformerLM.decode_pages (GQA +
