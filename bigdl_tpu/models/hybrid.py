@@ -1,0 +1,291 @@
+"""A decoder-only language model built from a LAYER PATTERN: each layer
+names its sequence mixer and its feed-forward part, and the model's
+serving state follows from the mixers it holds.
+
+* mixers: ``"kda"`` (:class:`~bigdl_tpu.nn.DeltaAttention`: delta-rule
+  linear attention, a fixed float32 state per sequence) and ``"mla"``
+  (:class:`~bigdl_tpu.nn.LatentAttention`: softmax attention over a paged
+  pool of latents);
+* feed-forward parts: ``"dense"`` (:class:`~bigdl_tpu.nn.GatedMLP`) and
+  ``"experts"`` (sigmoid group-limited routing over ALL the layer's
+  experts, the grouped product over the ``experts_held`` this chip holds
+  from ``expert_offset`` on, plus a shared expert added once);
+* RMSNorm before each part, no biases, an untied head, no position
+  table; ``vocab_size`` is the number of embedding and head rows HELD
+  (a chip of a vocabulary-parallel deployment holds a slice).
+
+It speaks ``ContinuousGenerator``'s interface (``init``, ``max_len``,
+``vocab_size``, ``init_paged_cache``, ``decode_pages``) and declares what
+the generator cannot see from outside: ``recurrent_state`` (state is
+kept per SLOT, beside or instead of pages, and a prefill starts at 0) and
+``decode_counters`` (small integers ``decode_pages`` returns beside the
+cache, and how a chunk of steps reduces each).
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+
+import bigdl_tpu.nn as nn
+from bigdl_tpu.core.module import Module, child_rng
+from bigdl_tpu.models.transformer import _embed_rows
+from bigdl_tpu.parallel.expert import (held_experts_apply,
+                                       sigmoid_group_route)
+
+_F32 = jnp.float32
+MIXERS = ("kda", "mla")
+ROUTER_GAIN = 4.0           # standard deviation of a fresh router's logits
+EXPERT_GAIN = 0.1           # a routed expert's output, of unit gain
+FFNS = ("dense", "experts")
+
+
+class HybridLM(Module):
+    """See the module's docstring.  ``layers`` is the pattern, one
+    ``(mixer, ffn)`` pair a layer; every width is an argument, so that a
+    configuration file states the published ones."""
+
+    #: how a chunk of decode steps reduces what ``decode_pages`` counts
+    decode_counters = {"expert_pairs": "sum", "experts_hit": "sum",
+                       "expert_pairs_max": "max"}
+
+    def __init__(self, vocab_size: int, max_len: int = 4096,
+                 embed_dim: int = 2560, num_heads: int = 32,
+                 num_layers: int = 2,
+                 layers: Sequence[Sequence[str]] = (("kda", "dense"),
+                                                    ("mla", "experts")),
+                 head_dim: int = 128, ffn_dim: int = 6144,
+                 expert_dim: int = 768, num_experts: int = 512,
+                 experts_per_token: int = 8, n_group: int = 8,
+                 topk_group: int = 4, routed_scale: float = 2.5,
+                 experts_held: Optional[int] = None, expert_offset: int = 0,
+                 latent_dim: int = 512, rope_dim: int = 64,
+                 nope_dim: int = 128, v_dim: int = 128,
+                 rope_theta: float = 10000.0, conv_taps: int = 4,
+                 decay_floor: float = -5.0, norm_eps: float = 1e-6):
+        super().__init__()
+        layers = [tuple(l) for l in layers]
+        if len(layers) != num_layers:
+            raise ValueError(f"num_layers {num_layers} but the pattern "
+                             f"holds {len(layers)} layers")
+        for mixer, ffn in layers:
+            if mixer not in MIXERS or ffn not in FFNS:
+                raise ValueError(f"layer ({mixer!r}, {ffn!r}): mixers are "
+                                 f"{MIXERS}, feed-forward parts {FFNS}")
+        self.vocab_size = vocab_size
+        self.max_len = max_len
+        self.embed_dim = embed_dim
+        self.num_heads = num_heads
+        self.num_layers = num_layers
+        self.layers = layers
+        self.expert_dim = expert_dim
+        self.num_experts = num_experts
+        self.experts_per_token = experts_per_token
+        self.n_group = n_group
+        self.topk_group = topk_group
+        self.routed_scale = float(routed_scale)
+        self.experts_held = num_experts if experts_held is None \
+            else int(experts_held)
+        self.expert_offset = int(expert_offset)
+        if not 0 <= self.expert_offset \
+                <= num_experts - self.experts_held:
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset} + "
+                f"{self.experts_held}) are not a share of {num_experts}")
+        self.norm = nn.RMSNorm(embed_dim, norm_eps)
+        self.mixers = [
+            nn.DeltaAttention(embed_dim, num_heads, head_dim, conv_taps,
+                              decay_floor, norm_eps) if m == "kda"
+            else nn.LatentAttention(embed_dim, num_heads, latent_dim,
+                                    rope_dim, nope_dim, v_dim, rope_theta,
+                                    norm_eps)
+            for m, _ in layers]
+        self.dense = nn.GatedMLP(embed_dim, ffn_dim)
+        self.shared = nn.GatedMLP(embed_dim, expert_dim)
+
+    #: what the generator is told, for EVERY pattern: the serving tree is
+    #: ``{"pages", "slots"}``, addressed by slot beside the page table, and
+    #: a prefill is the prompt whole from position 0.  A ``kda`` layer needs
+    #: that for its state; an ``mla`` layer because a longer input attends
+    #: over its own tokens only (``LatentAttention``'s contract), so a
+    #: pattern without ``kda`` layers must not be handed a shared prefix
+    #: or a verify pass either (its ``slots`` entries are empty: 0 bytes).
+    recurrent_state = True
+
+    # -- parameters ------------------------------------------------------------
+
+    def _init_experts(self, rng):
+        kr, kg, kd, ks = jax.random.split(rng, 4)
+        e, f, g = self.embed_dim, self.expert_dim, self.experts_held
+        # Two scales are chosen, not unit gain (the configuration's
+        # ``assumed.init`` says why at length).  The router's logits have
+        # a standard deviation of ROUTER_GAIN: a router that has made up
+        # its mind, whose best scores lie where bfloat16 has a hundred
+        # values left and float32 millions.  A routed expert's output
+        # projection is EXPERT_GAIN of unit gain, so that ONE expert
+        # exchanged for its runner-up (which a hidden state rounded to
+        # bfloat16 does to a token in ten a layer, in any implementation)
+        # moves the logits less than the rounding itself does.
+        return {
+            "router": jax.random.normal(kr, (self.num_experts, e))
+            * ROUTER_GAIN * e ** -0.5,
+            "bias": jnp.zeros((self.num_experts,), _F32),
+            # (in, out) per expert, as the grouped product reads them;
+            # gate and up side by side
+            "experts": {
+                "w_gate_up": jax.random.normal(kg, (g, e, 2 * f))
+                * e ** -0.5,
+                "w_down": jax.random.normal(kd, (g, f, e))
+                * EXPERT_GAIN * f ** -0.5},
+            "shared": self.shared.init_params(ks),
+        }
+
+    def init_params(self, rng):
+        e = self.embed_dim
+        blocks = []
+        for i, ((_, ffn), mixer) in enumerate(zip(self.layers,
+                                                  self.mixers)):
+            k = child_rng(rng, i)
+            blocks.append({
+                "norm1": self.norm.init_params(None),
+                "mixer": mixer.init_params(child_rng(k, 0)),
+                "norm2": self.norm.init_params(None),
+                "ffn": self.dense.init_params(child_rng(k, 1))
+                if ffn == "dense" else self._init_experts(child_rng(k, 1)),
+            })
+        kt, kh = jax.random.split(child_rng(rng, self.num_layers))
+        return {"tok": jax.random.normal(kt, (self.vocab_size, e)),
+                "blocks": blocks,
+                "norm_f": self.norm.init_params(None),
+                "head": jax.random.normal(kh, (self.vocab_size, e))
+                * e ** -0.5}
+
+    # -- serving state ---------------------------------------------------------
+
+    def init_paged_cache(self, num_pages: int, page_size: int,
+                         dtype=jnp.float32, num_slots: int = 1):
+        """``{"pages": [...], "slots": [...]}``, a list entry a layer:
+        the latent pool of an ``mla`` layer (``{}`` for a layer without
+        one) and the per-slot state of a ``kda`` layer (likewise)."""
+        return {
+            "pages": [m.init_paged_cache(num_pages, page_size, dtype)
+                      if kind == "mla" else {}
+                      for (kind, _), m in zip(self.layers, self.mixers)],
+            "slots": [m.init_slot_state(num_slots, dtype)
+                      if kind == "kda" else {}
+                      for (kind, _), m in zip(self.layers, self.mixers)]}
+
+    # -- the layers --------------------------------------------------------------
+
+    def _route(self, p, x):
+        """(ids, gates) (T, k) of ``x`` (T, E) over ALL the layer's
+        experts: router product, sigmoid and gates in float32."""
+        with jax.default_matmul_precision("highest"):
+            scores = jax.nn.sigmoid(
+                x.astype(_F32) @ p["router"].astype(_F32).T)
+        return sigmoid_group_route(
+            scores, p["bias"].astype(_F32), self.experts_per_token,
+            self.n_group, self.topk_group, self.routed_scale)
+
+    def _experts(self, p, x, valid):
+        """The expert layer on ``x`` (T, E): (y, counters)."""
+        with jax.named_scope("router"):
+            ids, gates = self._route(p, x)
+        with jax.named_scope("experts"):
+            y, counters = held_experts_apply(
+                x, ids, gates, valid, p["experts"]["w_gate_up"],
+                p["experts"]["w_down"], self.expert_offset)
+        with jax.named_scope("shared"):
+            y = y + self.shared.apply(p["shared"], {}, x)[0]
+        return y, counters
+
+    def _forward(self, params, ids, cache, pages, pos, active, slots,
+                 lengths):
+        b, s = ids.shape
+        with jax.named_scope("embed"):
+            x = _embed_rows(params["tok"], ids)
+        n = jnp.full((b,), s, jnp.int32) if lengths is None \
+            else jnp.asarray(lengths, jnp.int32)
+        valid = (jnp.asarray(active)[:, None]
+                 & (jnp.arange(s)[None] < n[:, None])).reshape(-1)
+        zero = jnp.zeros((), jnp.int32)
+        counts = {"expert_pairs": zero, "experts_hit": zero,
+                  "expert_pairs_max": zero}
+        new_pages, new_slots = list(cache["pages"]), list(cache["slots"])
+        for i, ((kind, ffn), mixer) in enumerate(zip(self.layers,
+                                                     self.mixers)):
+            p = params["blocks"][i]
+            with jax.named_scope(f"block_{i}"):
+                h = self.norm.apply(p["norm1"], {}, x)[0]
+                with jax.named_scope(kind):
+                    if kind == "mla":
+                        y, new_pages[i] = mixer.apply_decode_pages(
+                            p["mixer"], h, cache["pages"][i], pages, pos,
+                            active)
+                    else:
+                        st = cache["slots"][i]
+                        rows = st if slots is None else \
+                            jax.tree_util.tree_map(lambda a: a[slots], st)
+                        y, rows = mixer.apply_slots(p["mixer"], h, rows,
+                                                    pos, active, lengths)
+                        new_slots[i] = rows if slots is None else \
+                            jax.tree_util.tree_map(
+                                lambda a, r: a.at[slots].set(r), st, rows)
+                x = x + y
+                h = self.norm.apply(p["norm2"], {}, x)[0]
+                if ffn == "dense":
+                    with jax.named_scope("mlp"):
+                        x = x + self.dense.apply(p["ffn"], {}, h)[0]
+                else:
+                    with jax.named_scope("moe"):
+                        y, c = self._experts(
+                            p["ffn"], h.reshape(b * s, -1), valid)
+                        x = x + y.reshape(b, s, -1)
+                    counts = {
+                        "expert_pairs": counts["expert_pairs"] + c["pairs"],
+                        "experts_hit": counts["experts_hit"] + c["hit"],
+                        "expert_pairs_max": jnp.maximum(
+                            counts["expert_pairs_max"], c["max"])}
+        with jax.named_scope("logits"):
+            if lengths is not None:
+                # a prefill wants the logits after its last real token
+                x = jnp.take_along_axis(x, (n - 1)[:, None, None], axis=1)
+            x = self.norm.apply(params["norm_f"], {}, x)[0]
+            # the product accumulates in float32 anyway: rounding the
+            # logits to the weights' dtype first would put the largest of
+            # a position on a grid of 0.016 of their standard deviation
+            logp = jax.nn.log_softmax(
+                jnp.dot(x, jnp.asarray(params["head"]).T,
+                        preferred_element_type=_F32), axis=-1)
+        return logp, {"pages": new_pages, "slots": new_slots}, counts
+
+    def decode_pages(self, params, state, tokens, cache, pages, pos, active,
+                     slots=None, lengths=None):
+        """``TransformerLM.decode_pages``'s contract with two additions a
+        model with per-slot state needs.  ``slots`` (B,) int32 names the
+        slot of each row (None: row ``b`` is slot ``b``, the decode
+        chunk's layout); ``lengths`` (B,) the real tokens of each row of
+        a right-padded prefill: tokens past them touch neither the
+        recurrent state nor the counters, and the log-probs come back
+        for the last real token only, ``(B, 1, vocab)``.  An input longer
+        than one token is a prefill from position 0 (``LatentAttention``'s
+        contract).  Returns (log-probs, cache', counters): the counters
+        are ``decode_counters``'s keys, int32 scalars, the expert layers'
+        ``expert_pairs`` and ``experts_hit`` summed and
+        ``expert_pairs_max`` their largest."""
+        del state
+        ids = jnp.asarray(tokens, jnp.int32) - 1
+        return self._forward(params, ids, cache, pages, pos, active, slots,
+                             lengths)
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        """Log-probs (B, T, vocab) of whole sequences from empty state."""
+        ids = jnp.asarray(input, jnp.int32) - 1
+        b = ids.shape[0]
+        cache = self.init_paged_cache(0, 1, params["tok"].dtype, b)
+        logp, _, _ = self._forward(
+            params, ids, cache, jnp.zeros((b, 1), jnp.int32),
+            jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool), None, None)
+        return logp, state

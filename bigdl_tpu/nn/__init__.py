@@ -7,7 +7,8 @@ reference maps 1:1 onto this package.
 
 from bigdl_tpu.core.module import (Container, Criterion, Module,
                                    flatten_params, unflatten_params)
-from bigdl_tpu.nn.attention import MultiHeadAttention
+from bigdl_tpu.nn.attention import LatentAttention, MultiHeadAttention
+from bigdl_tpu.nn.linear_attention import DeltaAttention
 from bigdl_tpu.parallel.expert import MixtureOfExperts
 from bigdl_tpu.nn.activation import (ELU, Abs, Clamp, Exp, GradientReversal,
                                      HardShrink, HardTanh, LeakyReLU, Log,
@@ -46,9 +47,9 @@ from bigdl_tpu.nn.distance import (MM, MV, Cosine, CosineDistance, DotProduct,
                                    Euclidean, L1Penalty, PairwiseDistance)
 from bigdl_tpu.nn.dropout import Dropout, LookupTable
 from bigdl_tpu.nn.linear import (Add, AddConstant, Bilinear, CAdd, CMul,
-                                 Linear, Mul, MulConstant, Scale)
+                                 GatedMLP, Linear, Mul, MulConstant, Scale)
 from bigdl_tpu.nn.normalization import (BatchNormalization, LayerNorm,
-                                        Normalize,
+                                        Normalize, RMSNorm,
                                         SpatialBatchNormalization,
                                         SpatialContrastiveNormalization,
                                         SpatialCrossMapLRN,

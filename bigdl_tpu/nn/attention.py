@@ -418,3 +418,206 @@ class MultiHeadAttention(Module):
         y = _proj(self._merge(o), params["wo"],
                   params["bo"] if self.with_bias else None)
         return y, state
+
+
+def apply_rope_interleaved(x, pos, theta: float = 10000.0):
+    """Rotary embedding over the last axis of ``x`` (..., T, D) by
+    INTERLEAVED pairs ``(x[2i], x[2i+1])`` (the DeepSeek convention;
+    :func:`apply_rope` pairs ``x[i]`` with ``x[i + D/2]``).  ``pos``
+    broadcasts against ``x``'s leading axes up to T."""
+    half = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.asarray(pos, jnp.float32)[..., None] * freqs
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _causal_attention(q, k, v, scale, block_q: int = 256):
+    """Exact causal attention over (B, H, T, ·) whose values may be
+    narrower than its keys, a block of query rows at a time (one (B, H,
+    block, T) score tile live, float32 softmax)."""
+    b, h, t, _ = q.shape
+    block = next(c for c in (block_q, 128, 64, 32, 16, 8, 4, 2, 1)
+                 if t % c == 0)
+
+    def rows(i):
+        qs = jax.lax.dynamic_slice_in_dim(q, i * block, block, axis=2)
+        s = jnp.einsum("bhqd,bhkd->bhqk", qs, k) * scale
+        seen = (i * block + jnp.arange(block))[:, None] \
+            >= jnp.arange(t)[None]
+        w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf)
+                           .astype(jnp.float32), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", w.astype(v.dtype), v)
+
+    out = jax.lax.map(rows, jnp.arange(t // block))      # (N, B, H, blk, dv)
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, h, t, v.shape[-1])
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) with a
+    full-rank query: per token ``q = Wq x`` (H x (nope | rope)),
+    ``[c~, k_r] = Wkva x`` (latent | rope), ``c = rmsnorm(c~)``, rope by
+    interleaved pairs on ``q_r`` and the ONE shared ``k_r``, ``[k_n, v] =
+    Wkvb c`` per head; scores ``(q_n . k_n + q_r . k_r) / sqrt(nope +
+    rope)``, causal softmax, ``Wo`` on the heads' weighted ``v``.
+
+    The cache holds the latent, not the heads: a page pool ``(P + 1, 1,
+    page_size, latent + rope)`` of ``[c, k_r]`` rows, read as K and, by
+    its first ``latent`` lanes, as V.  A decode step (``S == 1``) ABSORBS
+    ``Wkvb``: ``q' = q_n Wkvb^K`` (H x latent) scores the latents
+    directly and the weighted sum of latents goes through ``Wkvb^V`` — one
+    KV head of width ``latent + rope`` shared by all H query heads, which
+    the paged-attention kernel serves as H query rows of one head.  A
+    longer input is a prefill FROM POSITION 0 (the caller's contract):
+    it writes its latents and attends over its own tokens in the expanded
+    form, which costs a third of the absorbed form's operations."""
+
+    def __init__(self, embed_dim: int, num_heads: int, latent_dim: int = 512,
+                 rope_dim: int = 64, nope_dim: int = 128, v_dim: int = 128,
+                 rope_theta: float = 10000.0, eps: float = 1e-6):
+        super().__init__()
+        self.embed_dim = embed_dim
+        self.num_heads = num_heads
+        self.latent_dim = latent_dim
+        self.rope_dim = rope_dim
+        self.nope_dim = nope_dim
+        self.v_dim = v_dim
+        self.rope_theta = float(rope_theta)
+        self.eps = eps
+        self.scale = 1.0 / math.sqrt(nope_dim + rope_dim)
+
+    def init_params(self, rng):
+        ks = jax.random.split(rng, 4)
+        e, h, c = self.embed_dim, self.num_heads, self.latent_dim
+
+        def w(k, out, fan_in):
+            return jax.random.normal(k, (out, fan_in)) * fan_in ** -0.5
+
+        return {"wq": w(ks[0], h * (self.nope_dim + self.rope_dim), e),
+                "wkva": w(ks[1], c + self.rope_dim, e),
+                "kv_norm": {"weight": jnp.ones((c,), jnp.float32)},
+                "wkvb": w(ks[2], h * (self.nope_dim + self.v_dim), c),
+                "wo": w(ks[3], e, h * self.v_dim)}
+
+    def init_paged_cache(self, num_pages: int, page_size: int,
+                         dtype=jnp.float32):
+        """The latent page pool, under ``"k"``; its last page is the
+        write-redirect trash page, as in ``MultiHeadAttention``'s."""
+        return {"k": jnp.zeros((num_pages + 1, 1, page_size,
+                                self.latent_dim + self.rope_dim), dtype)}
+
+    def _project(self, params, x, positions):
+        """Per-head queries ``q_n`` (B, H, S, nope) and roped ``q_r``
+        (B, H, S, rope), the tokens' latents (B, S, latent + rope) and
+        ``Wkvb`` by head (H, nope + v, latent)."""
+        b, s, _ = x.shape
+        h, n, r = self.num_heads, self.nope_dim, self.rope_dim
+        q = _proj(x, params["wq"]).reshape(b, s, h, n + r)
+        q_r = apply_rope_interleaved(q[..., n:].transpose(0, 2, 1, 3),
+                                     positions[:, None], self.rope_theta)
+        wkvb = jnp.asarray(params["wkvb"]).reshape(h, n + self.v_dim,
+                                                   self.latent_dim)
+        return q[..., :n].transpose(0, 2, 1, 3), q_r, \
+            self._latents(params, x, positions), wkvb
+
+    def _latents(self, params, x, positions):
+        """``[c, k_r]`` (B, S, latent + rope) of the tokens of ``x``."""
+        c = self.latent_dim
+        kva = _proj(x, params["wkva"])
+        lat = kva[..., :c].astype(jnp.float32)
+        lat = lat * jax.lax.rsqrt(jnp.mean(lat * lat, axis=-1,
+                                           keepdims=True) + self.eps) \
+            * params["kv_norm"]["weight"].astype(jnp.float32)
+        k_r = apply_rope_interleaved(kva[..., c:], positions,
+                                     self.rope_theta)
+        return jnp.concatenate([lat.astype(x.dtype), k_r], axis=-1)
+
+    def apply_decode_pages(self, params, x_t, cache, pages, pos, active):
+        """``MultiHeadAttention.apply_decode_pages``'s contract on the
+        latent pool (writes of inactive rows and unmapped positions go to
+        the trash page).  Returns (y (B, S, E), cache')."""
+        b, s, _ = x_t.shape
+        h, c, r = self.num_heads, self.latent_dim, self.rope_dim
+        n, dv = self.nope_dim, self.v_dim
+        positions = jnp.asarray(pos)[:, None] + jnp.arange(s)    # (B, S)
+        pool = cache["k"]
+        ps, trash = pool.shape[2], pool.shape[0] - 1
+        pages = jnp.asarray(pages, jnp.int32)
+        lp = pages.shape[1]
+        with jax.named_scope("absorb"):
+            q_n, q_r, lat, wkvb = self._project(params, x_t, positions)
+        with jax.named_scope("kv.write"):
+            logical = positions // ps
+            phys = jnp.take_along_axis(pages, jnp.clip(logical, 0, lp - 1),
+                                       axis=1)
+            phys = jnp.where(logical >= lp, trash, phys)
+            phys = jnp.where(jnp.asarray(active)[:, None], phys, trash)
+            pool = pool.at[phys.reshape(-1), 0, (positions % ps).reshape(-1)
+                           ].set(lat.astype(pool.dtype).reshape(b * s, c + r))
+        if s > 1:
+            # prefill from position 0: expanded heads over the call's own
+            # tokens, causal
+            with jax.named_scope("attn.expand"):
+                o = self._expanded(q_n, q_r, lat, wkvb)
+        else:
+            with jax.named_scope("absorb"):
+                # q' = q_n Wkvb^K: the H heads become H query rows of the
+                # one latent head
+                qa = jnp.einsum("bhsn,hnc->bhsc", q_n, wkvb[:, :n])
+                qa = jnp.concatenate([qa.astype(x_t.dtype), q_r],
+                                     axis=-1)[:, :, 0][:, None]  # (B,1,H,c+r)
+                rows = jnp.broadcast_to(positions, (b, h))
+            from bigdl_tpu.ops.attention import (paged_attention,
+                                                 paged_attention_enabled)
+            with jax.named_scope("attn.paged"):
+                if paged_attention_enabled():
+                    ctx = paged_attention(qa, pool, pool, pages, rows,
+                                          self.scale)[..., :c]
+                else:
+                    ctx = self._gathered(qa, pool, pages, rows, trash)
+            with jax.named_scope("absorb"):
+                o = jnp.einsum("bhc,hdc->bhd", ctx[:, 0], wkvb[:, n:]
+                               )[:, :, None]                     # (B,H,1,dv)
+        y = _proj(o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+                  .astype(x_t.dtype), params["wo"])
+        return y, {"k": pool}
+
+    def _expanded(self, q_n, q_r, lat, wkvb):
+        """Causal attention of a call's tokens over themselves with
+        per-head keys and values expanded from their latents:
+        (B, H, S, v)."""
+        b, h, s, n = q_n.shape
+        c, r = self.latent_dim, self.rope_dim
+        kv = jnp.einsum("bsc,hdc->bhsd", lat[..., :c], wkvb)
+        k = jnp.concatenate(
+            [kv[..., :n], jnp.broadcast_to(lat[:, None, :, c:],
+                                           (b, h, s, r))], axis=-1)
+        return _causal_attention(jnp.concatenate([q_n, q_r], axis=-1), k,
+                                 kv[..., n:], self.scale)
+
+    def _gathered(self, q, pool, pages, rows, trash):
+        """The jnp form of the absorbed read, ``apply_decode_pages``'s
+        gather path on the one latent head: (B, 1, H, latent)."""
+        b, lp = pages.shape
+        ps, c = pool.shape[2], self.latent_dim
+        kk = pool[pages][:, :, 0].reshape(b, lp * ps, -1)        # (B, L, W)
+        kk = jnp.where(jnp.repeat(pages == trash, ps, axis=1)[..., None],
+                       0, kk)
+        s = jnp.einsum("bhd,bld->bhl", q[:, 0], kk) * self.scale
+        valid = jnp.arange(lp * ps)[None, None] <= rows[:, :, None]
+        w = jax.nn.softmax(jnp.where(valid, s, -jnp.inf)
+                           .astype(jnp.float32), axis=-1)
+        return jnp.einsum("bhl,blc->bhc", w.astype(kk.dtype),
+                          kk[..., :c])[:, None]
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        """A whole sequence from position 0, causal, expanded; no cache."""
+        b, s, _ = input.shape
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+        o = self._expanded(*self._project(params, input, positions))
+        y = _proj(o.transpose(0, 2, 1, 3).reshape(b, s, -1)
+                  .astype(input.dtype), params["wo"])
+        return y, state

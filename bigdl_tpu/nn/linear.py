@@ -187,3 +187,25 @@ class Scale(Module):
         y, _ = self.cmul.apply(params["cmul"], (), input)
         y, _ = self.cadd.apply(params["cadd"], (), y)
         return y, state
+
+
+class GatedMLP(Module):
+    """Bias-free SwiGLU feed-forward (Shazeer 2020): ``(silu(x Wg^T) *
+    (x Wu^T)) Wd^T``, weights ``(out, in)`` as :class:`Linear`'s."""
+
+    def __init__(self, embed_dim: int, hidden_dim: int):
+        super().__init__()
+        self.embed_dim = embed_dim
+        self.hidden_dim = hidden_dim
+
+    def init_params(self, rng):
+        kg, ku, kd = jax.random.split(rng, 3)
+        e, f = self.embed_dim, self.hidden_dim
+        return {"w_gate": jax.random.normal(kg, (f, e)) * e ** -0.5,
+                "w_up": jax.random.normal(ku, (f, e)) * e ** -0.5,
+                "w_down": jax.random.normal(kd, (e, f)) * f ** -0.5}
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        h = jax.nn.silu(quant.matmul_or_observe(input, params["w_gate"])) \
+            * quant.matmul_or_observe(input, params["w_up"])
+        return quant.matmul_or_observe(h, params["w_down"]), state

@@ -312,3 +312,27 @@ class LayerNorm(Module):
         if self.affine:
             y = y * params["weight"] + params["bias"]
         return y, state
+
+
+class RMSNorm(Module):
+    """Root-mean-square normalisation over the last dimension (Zhang &
+    Sennrich 2019): ``x / sqrt(mean(x^2) + eps) * weight``, no mean
+    subtraction and no bias.  Computed in float32 whatever the input's
+    dtype (the weight may arrive in bfloat16 from a served tree) and
+    returned in the input's."""
+
+    def __init__(self, normalized_size: int, eps: float = 1e-6):
+        super().__init__()
+        self.normalized_size = normalized_size
+        self.eps = eps
+
+    def init_params(self, rng):
+        del rng
+        return {"weight": jnp.ones((self.normalized_size,), jnp.float32)}
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        x = input.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                              + self.eps)
+        y = y * params["weight"].astype(jnp.float32)
+        return y.astype(input.dtype), state

@@ -94,10 +94,18 @@ def span(name: str, **attrs):
     None when the ledger is off).  An exception inside the block is
     recorded (``error`` field) and re-raised; the duration is recorded
     either way — failed phases are exactly the ones worth attributing."""
+    with open_span(name, **attrs) as h:
+        yield h.sid
+
+
+@contextlib.contextmanager
+def open_span(name: str, **attrs):
+    """:func:`span` that yields the HANDLE instead of the id, for a block
+    whose counts are known only once its work ran (``handle.set(...)``)."""
     h = begin_span(name, **attrs)
     error = None
     try:
-        yield h.sid
+        yield h
     except BaseException as e:
         error = type(e).__name__
         raise

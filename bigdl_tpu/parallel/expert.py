@@ -348,3 +348,100 @@ class MixtureOfExperts(Module):
         new_state = {"aux_loss": aux.astype(jnp.float32),
                      "drop_rate": drop.astype(jnp.float32)}
         return y.reshape(shape), new_state
+
+
+# -- routing without drops: the experts one chip HOLDS -------------------------
+#
+# A served expert layer of a model too large for one chip: the router
+# scores every expert of the layer, this chip holds the contiguous share
+# ``[expert_offset, expert_offset + experts_held)`` and computes what
+# those give for the tokens routed to them; what the absent experts would
+# add is left out (their chips add it), the gates are normalised over all
+# of a token's chosen experts wherever they live.  No capacity: the
+# token-expert pairs are sorted by expert and multiplied group by group
+# (``lax.ragged_dot``), so no token is dropped and an expert without
+# tokens costs nothing but its empty group.  A batch of few tokens (a
+# decode step) takes every held expert over every token instead, with a
+# gate of zero where the token was not routed there: the same sum, and a
+# time that does not follow which experts the batch happened to hit.
+
+#: at most this many tokens go through every held expert (the product
+#: then reads each held expert's weights once whatever the routing; the
+#: multiplications it adds stay under the time of that read up to about
+#: 240 tokens at these widths on a v5e)
+DENSE_TOKENS = 128
+
+def sigmoid_group_route(scores, bias, k: int, n_group: int, topk_group: int,
+                        scale: float = 1.0):
+    """Group-limited top-k over sigmoid scores (DeepSeek-V3 form).
+    ``scores`` (T, N) float32 in (0, 1); ``bias`` (N,) moves the
+    SELECTION only.  The ``n_group`` groups of ``N / n_group`` experts
+    are ranked by the sum of their two best biased scores, the best
+    ``topk_group`` kept, the best ``k`` experts chosen among those.
+    Returns (ids (T, k) int32, gates (T, k) float32): a token's gates are
+    its chosen experts' UNBIASED scores over their sum, times ``scale``."""
+    t, n = scores.shape
+    biased = scores + bias.astype(scores.dtype)
+    per = biased.reshape(t, n_group, n // n_group)
+    rank = jnp.sum(lax.top_k(per, 2)[0], axis=-1)            # (T, groups)
+    keep = lax.top_k(rank, topk_group)[1]
+    kept = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None], keep].set(True)
+    masked = jnp.where(jnp.repeat(kept, n // n_group, axis=1), biased,
+                       -jnp.inf)
+    ids = lax.top_k(masked, k)[1].astype(jnp.int32)
+    chosen = jnp.take_along_axis(scores, ids, axis=1)
+    gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
+    return ids, gates
+
+
+def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
+                       expert_offset: int = 0):
+    """What the experts held here add for ``x`` (T, E): ``ids``/``gates``
+    (T, k) from the router over ALL experts, ``valid`` (T,) masks padding
+    and inactive rows, ``w_gate_up`` (held, E, 2F) and ``w_down`` (held,
+    F, E) the held experts' SwiGLU weights (``silu(x Wg) * (x Wu)``
+    through ``Wd``).  Pairs whose expert is absent (or whose token is not
+    valid) sort behind every held group and contribute zero.
+
+    Returns (y (T, E) in ``x``'s dtype, counters): ``pairs`` routed to
+    held experts, ``hit`` held experts with at least one, ``max`` the
+    most loaded one's — int32 scalars."""
+    t, k = ids.shape
+    held, _, f2 = w_gate_up.shape
+    local = ids - expert_offset
+    here = (local >= 0) & (local < held) & valid[:, None]
+    key = jnp.where(here, local, held).reshape(-1)           # (T*k,)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    n_here = jnp.sum(sizes)
+    counters = {"pairs": n_here.astype(jnp.int32),
+                "hit": jnp.sum(sizes > 0).astype(jnp.int32),
+                "max": jnp.max(sizes).astype(jnp.int32)}
+    if t <= DENSE_TOKENS:
+        # (T, held): a held expert's gate for a token, zero where the
+        # token was not routed to it; the gates go in BEFORE the second
+        # product, which then contracts over (expert, F) at once
+        dense = jnp.zeros((t, held), jnp.float32).at[
+            jnp.arange(t)[:, None], jnp.clip(local, 0, held - 1)].add(
+            jnp.where(here, gates, 0.0))
+        h = jnp.einsum("te,gef->tgf", x, w_gate_up,
+                       preferred_element_type=jnp.float32)
+        h = jax.nn.silu(h[..., :f2 // 2]) * h[..., f2 // 2:] \
+            * dense[..., None]
+        y = jnp.dot(h.astype(x.dtype).reshape(t, -1),
+                    w_down.reshape(held * (f2 // 2), -1),
+                    preferred_element_type=jnp.float32)
+        return y.astype(x.dtype), counters
+    order = jnp.argsort(key, stable=True)
+    xs = x[order // k]                                       # sorted pairs
+    h = lax.ragged_dot(xs, w_gate_up, sizes,
+                       preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(h[:, :f2 // 2]) * h[:, f2 // 2:]).astype(x.dtype)
+    ys = lax.ragged_dot(h, w_down, sizes,
+                        preferred_element_type=jnp.float32)
+    # rows past the last held group belong to no group: whatever the
+    # grouped product left there is not a number anyone asked for
+    ys = jnp.where((jnp.arange(t * k) < n_here)[:, None], ys, 0.0)
+    y = ys[jnp.argsort(order)].reshape(t, k, -1)             # unsort
+    y = jnp.sum(y * jnp.where(here, gates, 0.0)[..., None], axis=1)
+    return y.astype(x.dtype), counters

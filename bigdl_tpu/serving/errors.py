@@ -99,6 +99,19 @@ class UnknownTenantError(ShedError):
     reason = "unknown_tenant"
 
 
+class RecurrentStateError(ShedError, ValueError):
+    """The model keeps a fixed-size recurrent state per slot (a linear-
+    attention or state-space layer) that pages do not carry, and the
+    feature asked for moves or shares PAGES only: a prefix-cache hit, a
+    speculative verify, a session kept between turns or parked to the
+    host would each resume from a state nobody saved.  Refused typed —
+    at construction for a draft model, at ``submit(session=...)`` and at
+    ``park()`` — instead of answering from the wrong state; snapshots of
+    the state at page boundaries would lift it (ROADMAP.md B-I 5)."""
+
+    reason = "recurrent_state"
+
+
 class InvalidRequestError(ServingError, ValueError):
     """The request's feature payload cannot be served (wrong shape /
     size for the compiled executable) — a client bug, rejected at

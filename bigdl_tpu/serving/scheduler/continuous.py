@@ -53,6 +53,21 @@ same argument covers speculative rejects: a rejected proposal's K/V
 sit at positions beyond the accepted frontier, invisible until the
 very chunk that overwrites them.  ``paged=False`` keeps the r8
 row-slot layout — the in-bench ablation baseline.
+
+A model may DECLARE a second kind of state (``model.recurrent_state``: a
+linear-attention or state-space layer keeps a fixed-size state per SLOT,
+which no page carries).  Everything that differs then follows from that
+declaration and not from an option: the cache is the model's own tree
+(``{"pages": ..., "slots": ...}``, donated whole), the prefill names its
+slot and always starts at position 0 (the model zeroes the slot's state
+there, in-graph), inactive rows leave their state bit for bit, and what
+moves or shares pages only — the prefix cache, a draft model's verify,
+sessions and their parking — is declined or refused typed
+(``RecurrentStateError``) instead of resuming from a state nobody saved.
+A model may also declare ``decode_counters``: small integers its
+``decode_pages`` returns beside the cache, reduced over a chunk's steps
+in-graph and carried to the host by the sync that is there, onto the
+``serve.decode`` and ``serve.prefill`` spans.
 """
 
 from __future__ import annotations
@@ -72,6 +87,7 @@ from bigdl_tpu.observability import tracer
 from bigdl_tpu.optim.metrics import Metrics
 from bigdl_tpu.serving.errors import (DrainingError, InvalidRequestError,
                                       MemoryBudgetError, QueueFullError,
+                                      RecurrentStateError,
                                       SlotCapacityError)
 from bigdl_tpu.serving.scheduler.buckets import BucketLadder
 from bigdl_tpu.serving.scheduler.paging import (HostOffloadTier,
@@ -276,6 +292,13 @@ class SlotManager:
         return self.num_slots - len(self._free)
 
 
+def _row_bytes(tree) -> int:
+    """Bytes of ONE row (page, slot) across every array of ``tree``."""
+    import jax
+    return int(sum(int(np.prod(a.shape[1:])) * np.dtype(a.dtype).itemsize
+                   for a in jax.tree_util.tree_leaves(tree)))
+
+
 class ContinuousGenerator:
     """Continuous-batching front for ``TransformerLM`` generation.
 
@@ -382,6 +405,20 @@ class ContinuousGenerator:
         self.params = params if params is not None else model.params
         self.state = state if state is not None else model.state
         self._tags = dict(ledger_tags or {})
+        # what the model declares (module doc): a per-slot recurrent
+        # state, and counters its decode_pages returns
+        self._recurrent = bool(getattr(model, "recurrent_state", False))
+        self._counted = dict(getattr(model, "decode_counters", None) or {})
+        if self._recurrent and not paged:
+            raise ValueError("a model with recurrent state is served "
+                             "paged (its state is addressed by slot "
+                             "beside the page table)")
+        if self._recurrent and draft_model is not None:
+            raise RecurrentStateError(
+                "speculative decoding rolls rejected proposals back by "
+                "position, which pages allow and a recurrent state does "
+                "not: the verify pass would leave the slot's state past "
+                "the accepted frontier")
         qmode = quant.normalize_mode(quantize)
         if qmode is not None:
             if qmode not in ("w8", "w8a8", "w4", "f8"):
@@ -452,6 +489,7 @@ class ContinuousGenerator:
 
         # -- paging ----------------------------------------------------------
         self._paged = bool(paged)
+        prefix_declined = False
         n = int(num_slots)
         if self._paged:
             ps = int(page_size)
@@ -461,7 +499,11 @@ class ContinuousGenerator:
             self._alloc = PageAllocator(int(num_pages), ps)
             if prefix_cache is None:
                 prefix_cache = True
-            self._prefix = PrefixCache(ps) if prefix_cache else None
+            # a shared page carries the prefix's keys, not the recurrent
+            # state after it: declined (counted below), not silently wrong
+            prefix_declined = bool(prefix_cache) and self._recurrent
+            self._prefix = PrefixCache(ps) \
+                if prefix_cache and not self._recurrent else None
             self._lp = lp
             self._page_table = np.full((n, lp), self._alloc.trash,
                                        np.int32)
@@ -538,6 +580,8 @@ class ContinuousGenerator:
             self._dcache = None
 
         self.metrics = Metrics()
+        if prefix_declined:
+            self.metrics.incr("serve.gen.prefix.declined")
         self._closed = False
         self._lock = threading.Lock()
         from bigdl_tpu.serving.queue import AdmissionQueue
@@ -559,19 +603,20 @@ class ContinuousGenerator:
         # use can exceed the pool
         self._budget = budgeter
         self._bt = budget_tenant or self._tags.get("tenant", "default")
+        self._state_bytes = 0       # recurrent state of ONE slot
         if self._paged:
-            self._cache = model.init_paged_cache(
-                self._alloc.num_pages, self._alloc.page_size,
-                self._cache_dtype)
-            # bytes of ONE page across every layer's k+v pool
-            self._page_bytes = int(sum(
-                int(np.prod(l[side].shape[1:]))
-                * np.dtype(l[side].dtype).itemsize
-                for l in self._cache for side in ("k", "v")))
+            self._cache = self._new_paged_cache()
+            # bytes of ONE page across every layer's pools
+            self._page_bytes = _row_bytes(
+                self._cache["pages"] if self._recurrent else self._cache)
+            if self._recurrent:
+                self._state_bytes = _row_bytes(self._cache["slots"])
         else:
             self._cache = model.init_cache(n, self.max_len,
                                            self._cache_dtype)
             self._page_bytes = 0
+        self._moe_pairs = 0
+        self._moe_hit = 0
         self._chunks = 0
         self._emitted = 0
         self._completed = 0
@@ -589,6 +634,15 @@ class ContinuousGenerator:
                                         name="bigdl-tpu-generate",
                                         daemon=True)
         self._worker.start()
+
+    def _new_paged_cache(self):
+        """The model's paged cache, with its per-slot state where it
+        declares one."""
+        extra = {"num_slots": self.slots.num_slots} \
+            if self._recurrent else {}
+        return self.model.init_paged_cache(
+            self._alloc.num_pages, self._alloc.page_size,
+            self._cache_dtype, **extra)
 
     # -- compiled programs ---------------------------------------------------
 
@@ -610,7 +664,34 @@ class ContinuousGenerator:
                     key, logp / temperature,
                     axis=-1).astype(jnp.int32) + 1
 
+        counted = self._counted
+        def decode_pages(*args, **kw):
+            # (log-probs, cache', counters): a model that declares no
+            # counters returns none, and an empty dict adds no output to
+            # the compiled program
+            out = model.decode_pages(*args, **kw)
+            return out if counted else (*out, {})
+
+        def reduce_counts(counts):
+            # over the chunk's steps, each as the model declares
+            return {k: (jnp.max if counted[k] == "max" else jnp.sum)(v)
+                    for k, v in counts.items()}
+
         if self._paged:
+            def prefill_slot(params, state, tokens, ts, cache, pages, slot,
+                             key):
+                # a model with recurrent state: the prompt WHOLE (nothing
+                # below it is shared or retained), from position 0, where
+                # the model zeroes the state of `slot` in-graph; `ts`
+                # keeps the right-padding out of the state and selects
+                # the one row of log-probs that is computed
+                lp, cache, counts = decode_pages(
+                    params, state, tokens, cache, pages,
+                    jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool),
+                    slots=jnp.asarray(slot, jnp.int32)[None],
+                    lengths=jnp.asarray(ts, jnp.int32)[None])
+                return pick(lp[:, 0], key)[0], cache, counts
+
             def prefill(params, state, tokens, ts, cache, pages, start,
                         key):
                 # tokens (1, Tb): the prompt SUFFIX beyond the shared
@@ -621,12 +702,12 @@ class ContinuousGenerator:
                 # prefix pages sit below `start` and are never indexed.
                 pos = jnp.asarray(start, jnp.int32)[None]
                 active = jnp.ones((1,), bool)
-                lp, cache = model.decode_pages(params, state, tokens,
-                                               cache, pages, pos, active)
+                lp, cache, counts = decode_pages(params, state, tokens,
+                                                 cache, pages, pos, active)
                 last = jax.lax.dynamic_slice_in_dim(lp, ts - 1, 1,
                                                     axis=1)[:, 0]
                 first = pick(last, key)[0]
-                return first, cache
+                return first, cache, counts
 
             def step_chunk_kernel(params, state, tokens, cache, pages,
                                   pos, active, limit, keys):
@@ -636,12 +717,16 @@ class ContinuousGenerator:
                 # pass, so there is no materialised view to hoist and
                 # the per-step writes scatter straight into the pool.
                 # Outputs are bit-parity-gated against the hoisted
-                # chunk below (bench-serve ablation + tests).
+                # chunk below (bench-serve ablation + tests).  A model
+                # with recurrent state always takes this form: its slot
+                # state is updated step by step beside the pool, and its
+                # attention layers gather through the table themselves
+                # where the kernel is off.
                 def one(carry, key):
                     tok, cache, pos, active = carry
-                    lp, cache = model.decode_pages(params, state,
-                                                   tok[:, None], cache,
-                                                   pages, pos, active)
+                    lp, cache, counts = decode_pages(params, state,
+                                                     tok[:, None], cache,
+                                                     pages, pos, active)
                     nxt = pick(lp[:, -1], key)
                     nxt = jnp.where(active, nxt, tok)
                     pos = jnp.where(active, pos + 1, pos)
@@ -649,11 +734,12 @@ class ContinuousGenerator:
                     active = jnp.logical_and(active, pos < limit)
                     if eos_id is not None:
                         active = jnp.logical_and(active, nxt != eos_id)
-                    return (nxt, cache, pos, active), (nxt, emitted)
+                    return (nxt, cache, pos, active), (nxt, emitted, counts)
 
-                (tok, cache, pos, active), (toks, emitted) = jax.lax.scan(
-                    one, (tokens, cache, pos, active), keys)
-                return tok, cache, pos, active, toks, emitted
+                (tok, cache, pos, active), (toks, emitted, counts) = \
+                    jax.lax.scan(one, (tokens, cache, pos, active), keys)
+                return tok, cache, pos, active, toks, emitted, \
+                    reduce_counts(counts)
 
             def step_chunk(params, state, tokens, cache, pages, pos,
                            active, limit, keys):
@@ -729,12 +815,14 @@ class ContinuousGenerator:
                 cache = [{"k": to_pool(l["k"], v["k"]),
                           "v": to_pool(l["v"], v["v"])}
                          for l, v in zip(cache, views)]
-                return tok, cache, pos, active, toks, emitted
+                return tok, cache, pos, active, toks, emitted, {}
 
             self._prefill_fn = jax.jit(
-                prefill, donate_argnums=(4,) if self._donate else ())
+                prefill_slot if self._recurrent else prefill,
+                donate_argnums=(4,) if self._donate else ())
             self._step_fn = jax.jit(
-                step_chunk_kernel if self._paged_kernel else step_chunk,
+                step_chunk_kernel if self._paged_kernel or self._recurrent
+                else step_chunk,
                 donate_argnums=(3,) if self._donate else ())
 
             if self._draft is not None:
@@ -837,7 +925,7 @@ class ContinuousGenerator:
                  "v": jax.lax.dynamic_update_slice(
                      big["v"], small["v"], (slot, 0, 0, 0))}
                 for big, small in zip(cache, lcache)]
-            return first, new_cache
+            return first, new_cache, {}
 
         def step_chunk(params, state, tokens, cache, pos, active, limit,
                        keys):
@@ -859,7 +947,7 @@ class ContinuousGenerator:
 
             (tok, cache, pos, active), (toks, emitted) = jax.lax.scan(
                 one, (tokens, cache, pos, active), keys)
-            return tok, cache, pos, active, toks, emitted
+            return tok, cache, pos, active, toks, emitted, {}
 
         # cache donation: the live cache enters each program exactly
         # once and is immediately rebound to the program's output, so
@@ -893,11 +981,14 @@ class ContinuousGenerator:
                 if self._paged:
                     trash_row = jnp.full((1, self._lp), self._alloc.trash,
                                          jnp.int32)
-                    first, new_cache = self._prefill_fn(
+                    # (the 0 is the shared depth, or slot 0 of a model
+                    # with recurrent state, whose first real tenant
+                    # starts from zero whatever this leaves there)
+                    first, new_cache, _ = self._prefill_fn(
                         self.params, self.state, dummy, 1, self._cache,
                         trash_row, 0, key)
                 else:
-                    first, new_cache = self._prefill_fn(
+                    first, new_cache, _ = self._prefill_fn(
                         self.params, self.state, dummy, 1, self._cache,
                         0, key)
                 if self._donate:
@@ -1018,7 +1109,7 @@ class ContinuousGenerator:
             self._shed(e)
         if self._budget is not None and self._paged:
             need = self._alloc.pages_for(p.size + max_new - 1) \
-                * self._page_bytes
+                * self._page_bytes + self._state_bytes
             try:
                 self._budget.require_possible(self._bt, need,
                                               what="request")
@@ -1045,6 +1136,11 @@ class ContinuousGenerator:
             self._shed(InvalidRequestError(
                 "sessions are not supported with speculative decoding "
                 "(the draft's row cache has no park/resume path)"))
+        if self._recurrent:
+            self._shed(RecurrentStateError(
+                "a session keeps its PAGES between turns; the slot's "
+                "recurrent state goes to the slot's next tenant, so the "
+                "next turn could not resume from it"))
         with self._lock:
             sess = self._sessions.get(sid)
             created = sess is None
@@ -1123,6 +1219,10 @@ class ContinuousGenerator:
         'park after the turn retires, or not at all').  Pressure also
         parks idle sessions automatically; this is the explicit
         client-driven variant."""
+        if self._recurrent:
+            self._shed(RecurrentStateError(
+                "parking moves a session's pages to the host; a model "
+                "with recurrent state keeps no session to park"))
         cmd = _Control("park", str(sid))
         try:
             self.queue.offer(cmd)
@@ -1182,6 +1282,7 @@ class ContinuousGenerator:
                             num_pages=(self._alloc.num_pages
                                        if self._paged else None),
                             prefix_cache=self._prefix is not None,
+                            recurrent_state=self._recurrent,
                             speculative=self._draft is not None,
                             spec_k=(self.spec_k if self._draft is not None
                                     else None),
@@ -1229,9 +1330,7 @@ class ContinuousGenerator:
         self._active[:] = False
         if self._donate:
             if self._paged:
-                self._cache = self.model.init_paged_cache(
-                    self._alloc.num_pages, self._alloc.page_size,
-                    self._cache_dtype)
+                self._cache = self._new_paged_cache()
                 # every retained session's KV died with the donated
                 # pool (parked copies too — their shared heads are
                 # gone, a resume could not be bit-faithful): close
@@ -1564,6 +1663,7 @@ class ContinuousGenerator:
         assert slot is not None, "placed with no free slot"
         self._budget_add("kv_pages", len(priv) * self._page_bytes,
                          rid=req.rid)
+        self._budget_add("slot_state", self._state_bytes, rid=req.rid)
 
         # build the slot's page table row: shared prefix pages first,
         # then the private pages, trash beyond the allocation
@@ -1594,12 +1694,17 @@ class ContinuousGenerator:
             self._prefill_failed(req, e, consumed_cache=False)
             return True
         try:
-            with tracer.span("serve.prefill", slot=slot, bucket=bucket,
-                             tp=tp, shared_tokens=start, rid=req.rid,
-                             **self._walk_attrs(start + bucket - 1)):
-                first, self._cache = self._prefill_fn(
+            with tracer.open_span(
+                    "serve.prefill", slot=slot, bucket=bucket, tp=tp,
+                    shared_tokens=start, rid=req.rid,
+                    **self._walk_attrs(start + bucket - 1)) as sp:
+                # a model with recurrent state is told its slot where an
+                # attention-only one is told its shared depth (0 here:
+                # nothing is shared below a recurrent state)
+                first, self._cache, counts = self._prefill_fn(
                     self.params, self.state, suffix_dev, ts,
-                    self._cache, table_dev, start, key)
+                    self._cache, table_dev,
+                    slot if self._recurrent else start, key)
                 if self._draft is not None:
                     fbucket = self.seq_ladder.pick(tp)
                     fpad = np.ones((1, fbucket), np.int32)
@@ -1609,8 +1714,11 @@ class ContinuousGenerator:
                         jnp.asarray(fpad), self._dcache, slot)
                 # the host fetch stays in scope: an async dispatch
                 # failure surfaces here, after the cache was donated
-                first = int(np.asarray(first))
+                first, counts = jax.device_get((first, counts))
+                first = int(first)
                 self._first_token(req)
+                if counts:
+                    sp.set(**self._counter_attrs(counts))
         except Exception as e:
             self._release_partial(req, slot, priv, slot_keys)
             self._prefill_failed(req, e, consumed_cache=True)
@@ -1782,7 +1890,7 @@ class ContinuousGenerator:
                              tp=tp, shared_tokens=kv_start,
                              rid=req.rid, sid=sess.sid,
                              **self._walk_attrs(kv_start + bucket - 1)):
-                first, self._cache = self._prefill_fn(
+                first, self._cache, _ = self._prefill_fn(
                     self.params, self.state, suffix_dev, ts,
                     self._cache, table_dev, kv_start, key)
                 first = int(np.asarray(first))
@@ -1834,7 +1942,7 @@ class ContinuousGenerator:
         try:
             with tracer.span("serve.prefill", slot=slot, bucket=bucket,
                              tp=tp, rid=req.rid):
-                first, self._cache = self._prefill_fn(
+                first, self._cache, _ = self._prefill_fn(
                     self.params, self.state, prompt_dev, tp,
                     self._cache, slot, key)
                 first = int(np.asarray(first))
@@ -1895,6 +2003,34 @@ class ContinuousGenerator:
                              unit="scalar")
         return {"pages_walked": walked, "pages_table": table}
 
+    def _state_attrs(self, emitted, pos) -> dict:
+        """What a ``serve.decode`` span says of the per-slot state and
+        the latent pool (a model with recurrent state, ledger on):
+        ``state_rows``, the row-steps that updated a state, and
+        ``latent_tokens``, the context tokens an attention layer read
+        over them (a row at position p reads p + 1), from the positions
+        the chunk started at (``pos`` is where it ended)."""
+        if not (self._recurrent and run_ledger.enabled()):
+            return {}
+        n = emitted.sum(axis=0).astype(np.int64)         # steps a row ran
+        ctx = n * (pos.astype(np.int64) - n + 1) + n * (n - 1) // 2
+        return {"state_rows": int(n.sum()), "latent_tokens": int(ctx.sum())}
+
+    def _counter_attrs(self, counts) -> dict:
+        """The model's counters of one program run as span attributes;
+        the expert layers' feed two running gauges: pairs per expert
+        that had any, and the most loaded expert's pairs over that."""
+        out = {k: int(v) for k, v in counts.items()}
+        if out.get("experts_hit"):
+            self._moe_pairs += out["expert_pairs"]
+            self._moe_hit += out["experts_hit"]
+            self.metrics.set("serve.moe pairs per hit expert",
+                             self._moe_pairs / self._moe_hit, unit="scalar")
+            self.metrics.set("serve.moe max over mean",
+                             out["expert_pairs_max"] * out["experts_hit"]
+                             / out["expert_pairs"], unit="scalar")
+        return out
+
     def _commit_placed(self, req: GenRequest, slot: int, tp: int,
                        first: int, bucket: int) -> None:
         req.slot = slot
@@ -1920,6 +2056,7 @@ class ContinuousGenerator:
         shrink capacity forever."""
         if slot is not None:
             self.slots.release(slot)
+            self._budget_sub("slot_state", self._state_bytes)
         if priv:
             self._alloc.free(priv)
             self._budget_sub("kv_pages", len(priv) * self._page_bytes)
@@ -1967,42 +2104,34 @@ class ContinuousGenerator:
 
     def _plain_chunk(self) -> None:
         import jax
-        import jax.numpy as jnp
 
         n_active = int(self._active.sum())
         occ = n_active / self.slots.num_slots
-        with tracer.span("serve.decode", chunk=self._chunks,
-                         active=n_active, steps=self.steps_per_sync,
-                         **self._decode_attrs()):
+        with tracer.open_span("serve.decode", chunk=self._chunks,
+                              active=n_active, steps=self.steps_per_sync,
+                              **self._decode_attrs()) as sp:
             if self._greedy_keys is not None:
                 keys = self._greedy_keys
             else:
                 self._rng, key = jax.random.split(self._rng)
                 keys = jax.random.split(key, self.steps_per_sync)
+            # the mirrors go up in ONE call and the results come back in
+            # one: each separate transfer is a round trip to the device
+            host = [self._tokens, self._pos, self._active, self._limit]
             if self._paged:
-                tok, self._cache, pos, active, toks, emitted = \
-                    self._step_fn(
-                        self.params, self.state,
-                        jnp.asarray(self._tokens), self._cache,
-                        jnp.asarray(self._page_table),
-                        jnp.asarray(self._pos),
-                        jnp.asarray(self._active),
-                        jnp.asarray(self._limit), keys)
-            else:
-                tok, self._cache, pos, active, toks, emitted = \
-                    self._step_fn(
-                        self.params, self.state,
-                        jnp.asarray(self._tokens), self._cache,
-                        jnp.asarray(self._pos),
-                        jnp.asarray(self._active),
-                        jnp.asarray(self._limit), keys)
-            # np.array (copy): asarray of a jax output is a read-only
+                host.append(self._page_table)
+            tokens, pos, active, limit, *table = jax.device_put(host)
+            tok, self._cache, pos, active, toks, emitted, counts = \
+                self._step_fn(self.params, self.state, tokens, self._cache,
+                              *table, pos, active, limit, keys)
+            tok, pos, new_active, toks, emitted, counts = jax.device_get(
+                (tok, pos, active, toks, emitted, counts))
+            # np.array (copy): what device_get returns may be a read-only
             # view, and _place mutates these mirrors on the next admit
             self._tokens = np.array(tok)
-            self._pos = np.array(pos)
-            new_active = np.asarray(active)
-            toks = np.asarray(toks)              # (steps, slots)
-            emitted = np.asarray(emitted)
+            self._pos = np.array(pos)        # toks, emitted: (steps, slots)
+            sp.set(**self._state_attrs(emitted, pos),
+                   **self._counter_attrs(counts))
         now = time.monotonic()      # the chunk's tokens reached the host
         chunk_tokens = int(emitted.sum())
         self._account_chunk(occ, n_active, chunk_tokens,
@@ -2138,6 +2267,7 @@ class ContinuousGenerator:
         self._active[slot] = False
         self.slots.release(slot)
         if self._paged:
+            self._budget_sub("slot_state", self._state_bytes)
             sess = (self._sessions.get(req.session)
                     if req.session is not None else None)
             if sess is not None and status == "ok":
@@ -2274,6 +2404,10 @@ class ContinuousGenerator:
                     self._token_occupancy_sum / self._chunks
                     if self._chunks else 0.0),
             }
+            if self._recurrent:
+                out["state"] = {
+                    "bytes_per_slot": self._state_bytes,
+                    "bytes": self.slots.num_slots * self._state_bytes}
             out["prefix"] = (self._prefix.stats()
                              if self._prefix is not None else None)
             with self._lock:

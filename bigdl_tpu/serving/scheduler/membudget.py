@@ -11,6 +11,9 @@ byte pressure becomes *policy* instead of a crash:
 * **charge classes** — each tenant's bytes are tracked per class:
   ``kv_pages`` (private KV pages held by live/resident sessions),
   ``prefix_pages`` (refcounted shared prefix-cache pages),
+  ``slot_state`` (the fixed-size recurrent state of a placed request
+  whose model keeps one per slot: charged whole at placement,
+  discharged at evict, whatever the sequence's length),
   ``params`` (packed/quantized parameter trees, bytes from
   ``quant.pack``'s ``param_bytes_by_dtype``), ``rung_executables``
   (warmed per-rung compiled programs, bytes from the r10 cost
@@ -52,10 +55,10 @@ from bigdl_tpu.serving.errors import MemoryBudgetError
 
 #: charge classes, in the order the census reports them.  Everything
 #: except ``host_offload`` counts against the device budget.
-CHARGE_CLASSES = ("kv_pages", "prefix_pages", "params",
+CHARGE_CLASSES = ("kv_pages", "prefix_pages", "slot_state", "params",
                   "rung_executables", "host_offload")
 
-DEVICE_CLASSES = ("kv_pages", "prefix_pages", "params",
+DEVICE_CLASSES = ("kv_pages", "prefix_pages", "slot_state", "params",
                   "rung_executables")
 
 

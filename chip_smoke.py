@@ -13,6 +13,11 @@ seed:
 3. **serve**     an 8-layer, 512-wide, vocab-32000 ``TransformerLM`` (bf16
                  params and cache) behind ``ContinuousGenerator`` defaults
                  (paged, kernel by the platform gate, prefix cache, warm-up)
+3b. **hybrid**   the layer-pattern model with recurrent state at the
+                 published widths of ``ling3_flash_vl``: a 512-token prefill
+                 and 1,024 decode steps, LOG-PROBS against the plain float32
+                 reference, the router against the reference's, and two
+                 controls (bf16 state, bf16 router) that have to fail
 4. **kernels**   every Pallas kernel a TPU backend switches on without an
                  opt-in variable, compiled and compared with its jnp
                  reference inside the tolerances below
@@ -31,6 +36,7 @@ from __future__ import annotations
 
 import json
 import math
+import os
 import sys
 import time
 import traceback
@@ -264,6 +270,225 @@ def phase_serve(vocab=32000, embed=512, heads=8, layers=8, max_len=1024,
                  f"(got {outs[i][j]}, reference {ref[i][j]}, reference "
                  f"top-2 logit margin {top2[1] - top2[0]:.4f})")
     return line
+
+
+# -- phase 3b: a model with recurrent state, on logits ---------------------------
+
+# The hybrid phase's tolerance on max |served log-prob - reference
+# log-prob| over the standard deviation of that position's reference
+# logits: (the MEDIAN over the positions, the MAXIMUM over them).  The
+# served path is bf16 weights and activations around a float32 recurrent
+# state and a float32 router; the reference is float32 throughout.  What
+# makes precision visible is the model's own initialisation
+# (``models/hybrid.py``, ``nn/linear_attention.py``; the configuration's
+# ``assumed.init``): decay rates that keep hundreds to thousands of
+# tokens in the state, so that a state rounded to bf16 after every update
+# drifts, and a routed expert whose exchange for its runner-up (what a
+# hidden state rounded to bf16 does to a token now and then, in any
+# implementation) moves the logits less than the rounding itself does.
+# 1,024 decode steps let the rounding add up (after 256 the control
+# still reads as the served path does).  Readings on a v5e (PR 27):
+# served (0.0761, 0.1462), the control that rounds the state to bf16
+# (0.2317, 0.3510); the tolerance lies between (the geometric means), and
+# the control has to FAIL it.
+HYBRID_TOLERANCE = (0.135, 0.225)
+# The router itself, on one random hidden state at the published width:
+# the same experts chosen as the reference chooses, gates within this of
+# the reference's.  The control (scores rounded to bf16 before selection
+# and gating, 2^-9 relative) has to fail it: a router of this gain puts a
+# token's best scores within 1e-4 of 1, where bf16 has no values left
+# between them, and on a v5e no token of 512 then chose the reference's
+# eight.
+ROUTER_TOLERANCE = 2e-5
+
+
+def _bf16(x):
+    """``x`` rounded to bf16's 8 exponent and 7 mantissa bits, in place of
+    a cast there and back, which XLA may drop (it is allowed excess
+    precision)."""
+    import jax
+    return jax.lax.reduce_precision(x, 8, 7)
+
+
+def _rounded(fn, index):
+    """``fn`` with output ``index`` rounded to bf16 (a control)."""
+    def wrapped(*args, **kw):
+        out = list(fn(*args, **kw))
+        out[index] = _bf16(out[index])
+        return tuple(out)
+    return wrapped
+
+
+def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
+                 overrides=None, reference_kw=None, prompt_len=512, steps=1024,
+                 slots=8, max_len=2048, buckets=(512,), compiled=True,
+                 tolerance=HYBRID_TOLERANCE,
+                 router_tolerance=ROUTER_TOLERANCE, dtype="bfloat16") -> str:
+    """Prefill of ``prompt_len`` tokens, then ``steps`` decode steps, the
+    way ``ContinuousGenerator``'s two programs call the model (slot-
+    addressed prefill from position 0 with its real length; whole-batch
+    decode steps with the other rows inactive), against the plain
+    reference's full forward over the same tokens, on LOG-PROBS; the
+    router against the reference's on one hidden state; the same request
+    through the generator itself; and the two controls."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bigdl_tpu.models import hybrid
+    from bigdl_tpu.nn import linear_attention
+    from bigdl_tpu.serving.scheduler import ContinuousGenerator
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           config), encoding="utf-8") as f:
+        cfg = json.load(f)
+    kwargs = dict(cfg["model"]["kwargs"], **(overrides or {}))
+    vocab = vocab or cfg["model"]["args"][0]
+    reference = importlib.import_module(cfg["reference"])
+    ref_kw = reference_kw or {}
+    model = hybrid.HybridLM(vocab, **kwargs)
+    def init(key):
+        params, _ = model.init(key)
+        return jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
+
+    params = jax.jit(init)(jax.random.PRNGKey(27))
+    rs = np.random.RandomState(27)
+    prompt = rs.randint(1, vocab + 1, prompt_len).astype(np.int32)
+    slot, ps = slots - 1, 16
+    lp_w = -(-max_len // ps)
+    need = -(-(prompt_len + steps) // ps)
+    table = np.full((slots, lp_w), slots * lp_w, np.int32)   # all trash
+    table[slot, :need] = np.arange(need)
+    bucket = min(b for b in buckets if b >= prompt_len)
+    padded = np.ones((1, bucket), np.int32)
+    padded[0, :prompt_len] = prompt
+
+    def served(params):
+        """(log-probs (steps + 1, vocab), greedy tokens) of the request."""
+        cache = model.init_paged_cache(slots * lp_w, ps, jnp.dtype(dtype),
+                                       num_slots=slots)
+        pages = jnp.asarray(table)
+
+        @jax.jit
+        def prefill(params, tokens, cache):
+            return model.decode_pages(
+                params, {}, tokens, cache, pages[slot][None],
+                jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool),
+                slots=jnp.asarray([slot]),
+                lengths=jnp.asarray([prompt_len]))[:2]
+
+        @jax.jit
+        def step(params, tok, cache, pos):
+            active = jnp.arange(slots) == slot
+            lp, cache, _ = model.decode_pages(
+                params, {}, jnp.where(active, tok, 1)[:, None], cache,
+                pages, jnp.where(active, pos, 0), active)
+            return lp[slot, 0], cache
+
+        lp, cache = prefill(params, jnp.asarray(padded), cache)
+        rows, toks = [lp[0, 0]], []
+        for i in range(steps):
+            toks.append(jnp.argmax(rows[-1]).astype(jnp.int32) + 1)
+            lp, cache = step(params, toks[-1], cache, prompt_len + i)
+            rows.append(lp)
+        toks.append(jnp.argmax(rows[-1]).astype(jnp.int32) + 1)
+        return np.asarray(jnp.stack(rows), np.float32), \
+            np.asarray(jnp.stack(toks))
+
+    def gap(params, logp, toks):
+        """(median, maximum) over the positions of the largest |served -
+        reference| log-prob over the std of the reference's logits, the
+        reference fed the served tokens."""
+        total = -(-(prompt_len + steps + 1) // 512) * 512
+        seq = np.ones(total, np.int32)
+        seq[:prompt_len] = prompt
+        seq[prompt_len:prompt_len + steps] = toks[:steps]
+        rows = np.arange(prompt_len - 1, prompt_len + steps)
+        logits = np.asarray(reference.logits_at(
+            params, seq, rows, heads=kwargs["num_heads"], **ref_kw),
+            np.float32)
+        want = np.asarray(jax.nn.log_softmax(logits, axis=-1))
+        per = np.abs(logp - want).max(axis=-1) / logits.std(axis=-1)
+        return float(np.median(per)), float(per.max())
+
+    def inside(reading, limit):
+        return reading[0] <= limit[0] and reading[1] <= limit[1]
+
+    # 1. the served path, and the control that keeps its state in bf16
+    logp, toks = served(params)
+    check(np.isfinite(logp).all(), "non-finite served log-probs")
+    tight = gap(params, logp, toks)
+    check(inside(tight, tolerance), f"served log-probs (median, max) "
+          f"{tight} std from the reference's (tolerance {tolerance})")
+    with mock.patch.object(linear_attention, "kda_step",
+                           _rounded(linear_attention.kda_step, 1)), \
+            mock.patch.object(linear_attention, "kda_chunked",
+                              _rounded(linear_attention.kda_chunked, 1)):
+        state_bf16 = gap(params, *served(params))
+    check(not inside(state_bf16, tolerance), f"the bf16-state control "
+          f"passed the tolerance {tolerance}: {state_bf16}")
+
+    # 2. the router against the reference's, one hidden state
+    layer = next(b["ffn"] for b in params["blocks"] if "router" in b["ffn"])
+    x = jax.random.normal(jax.random.PRNGKey(28),
+                          (prompt_len, model.embed_dim)).astype(dtype)
+    published = {**reference.PUBLISHED, **ref_kw}
+
+    def routed(route):
+        ids, gates = jax.jit(route)(layer, x)
+        with jax.default_matmul_precision("highest"):
+            scores = jax.nn.sigmoid(
+                x.astype(jnp.float32)
+                @ layer["router"].astype(jnp.float32).T)
+            want_ids, want_gates = reference.route(
+                scores, layer["bias"].astype(jnp.float32), published)
+        order, want_order = np.argsort(ids, -1), np.argsort(want_ids, -1)
+        same = np.take_along_axis(np.asarray(ids), order, -1) \
+            == np.take_along_axis(np.asarray(want_ids), want_order, -1)
+        off = np.abs(np.take_along_axis(np.asarray(gates), order, -1)
+                     - np.take_along_axis(np.asarray(want_gates),
+                                          want_order, -1))
+        rows = same.all(axis=-1)
+        # (no row left to compare gates on: every token chose otherwise)
+        return float(rows.mean()), \
+            float(off[rows].max()) if rows.any() else float("inf")
+
+    agree, gates_off = routed(model._route)
+    check(agree == 1.0 and gates_off <= router_tolerance,
+          f"router: {agree:.4f} of the tokens choose the reference's "
+          f"experts, gates off by {gates_off:.2e} (tolerance "
+          f"{router_tolerance})")
+    route = hybrid.sigmoid_group_route
+    with mock.patch.object(
+            hybrid, "sigmoid_group_route",
+            lambda scores, *a, **kw: route(_bf16(scores), *a, **kw)):
+        agree_bf16, gates_bf16 = routed(model._route)
+    check(agree_bf16 < 1.0 or gates_bf16 > router_tolerance,
+          f"the bf16-router control passed: {agree_bf16} {gates_bf16:.2e}")
+
+    # 3. the same request through the generator's own loop
+    gen = ContinuousGenerator(model, params, {}, num_slots=slots,
+                              max_len=max_len, seq_buckets=list(buckets),
+                              cache_dtype=jnp.dtype(dtype))
+    try:
+        out = np.asarray(gen.submit(prompt, steps + 1).result(timeout=900))
+    finally:
+        gen.drain(timeout=60)
+    st = gen.stats()
+    check(st["counters"].get("serve.gen.failed", 0) == 0
+          and out.shape == (steps + 1,), f"generator: {st['counters']}")
+    check(_paged_kernel_compiled() == compiled,
+          f"paged kernel compiled={_paged_kernel_compiled()}")
+    fmt = "({:.4f}, {:.4f})".format
+    return (f"prompt={prompt_len} steps={steps} gap_median_max="
+            f"{fmt(*tight)} (tolerance {tolerance}) bf16_state="
+            f"{fmt(*state_bf16)} "
+            f"router_agree={agree:.4f} gates_off={gates_off:.2e} "
+            f"(tolerance {router_tolerance}) bf16_router=({agree_bf16:.4f}, "
+            f"{gates_bf16:.2e}) state_bytes_per_slot="
+            f"{st['state']['bytes_per_slot']}")
 
 
 # -- phase 4: kernels ---------------------------------------------------------
@@ -649,6 +874,7 @@ def main() -> int:
     for name, phase in (("device", partial(phase_device, cache_dir)),
                         ("train", phase_train),
                         ("serve", phase_serve),
+                        ("hybrid", phase_hybrid),
                         ("kernels", phase_kernels),
                         ("multichip", phase_multichip)):
         t0, (c0, h0, m0) = time.time(), meter.snapshot()

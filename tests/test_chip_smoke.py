@@ -135,3 +135,27 @@ def test_cache_helper_default_is_fixed_under_the_checkout(monkeypatch):
          "['default_cache_dir']())", compile_cache.__file__],
         cwd="/", capture_output=True, text=True, timeout=60, check=True)
     assert out.stdout.strip() == want
+
+
+def test_phase_hybrid_toy(interpret_mode):
+    """The logits phase at toy widths in float32: the served path agrees
+    with the reference to rounding, the router chooses the reference's
+    experts, and both controls (state and router rounded to bf16) fail
+    their tolerances; a tolerance the state control passes fails the
+    phase."""
+    toy = dict(max_len=128, embed_dim=64, num_heads=4, num_layers=3,
+               layers=[["kda", "dense"], ["kda", "experts"],
+                       ["mla", "experts"]],
+               head_dim=16, ffn_dim=96, expert_dim=24, num_experts=16,
+               experts_per_token=4, n_group=4, topk_group=2, experts_held=8,
+               latent_dim=32, rope_dim=8, nope_dim=16, v_dim=16)
+    kw = dict(vocab=97, overrides=toy,
+              reference_kw=dict(num_experts_per_tok=4, n_group=4,
+                                topk_group=2, kv_lora_rank=32,
+                                qk_rope_head_dim=8),
+              prompt_len=40, slots=3, max_len=128, buckets=(64,),
+              compiled=False, dtype="float32")
+    line = chip_smoke.phase_hybrid(steps=12, tolerance=(2e-4, 2e-3), **kw)
+    assert "bf16_state=" in line and "router_agree=1.0000" in line
+    with pytest.raises(chip_smoke.SmokeFailure, match="bf16-state control"):
+        chip_smoke.phase_hybrid(steps=4, tolerance=(9.0, 9.0), **kw)
