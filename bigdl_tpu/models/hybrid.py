@@ -167,8 +167,9 @@ class HybridLM(Module):
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32, num_slots: int = 1):
         """``{"pages": [...], "slots": [...]}``, a list entry a layer:
-        the latent pool of an ``mla`` layer (``{}`` for a layer without
-        one) and the per-slot state of a ``kda`` layer (likewise)."""
+        the latent pool of an ``mla`` layer, ``(num_pages + 1, page_size,
+        W)`` with the trash page last (``{}`` for a layer without one),
+        and the per-slot state of a ``kda`` layer (likewise)."""
         return {
             "pages": [m.init_paged_cache(num_pages, page_size, dtype)
                       if kind == "mla" else {}

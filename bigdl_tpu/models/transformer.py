@@ -400,10 +400,18 @@ class TransformerLM(Module):
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32):
         """Per-layer block-paged KV pools for :meth:`decode_pages` —
-        each ``(num_pages + 1, H_kv, page_size, D)``, the last page
+        each ``(num_pages + 1, page_size, W)`` (page, token in page,
+        every KV head of the token side by side), the last page
         being the write-redirect trash page (see
         ``nn.MultiHeadAttention.init_paged_cache``)."""
         return [b.attn.init_paged_cache(num_pages, page_size, dtype)
+                for b in self.blocks]
+
+    def paged_heads(self):
+        """``(KV heads, head size)`` of each layer: what un-merges a
+        row of its page pool into the slot layout's per-head view
+        (``nn.attention.pages_view``)."""
+        return [(b.attn.num_kv_heads, b.attn.head_dim)
                 for b in self.blocks]
 
     def decode_pages(self, params, state, tokens, cache, pages, pos,

@@ -18,6 +18,7 @@ context-parallel kernels in ``bigdl_tpu/parallel/sequence.py``:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Optional
 
@@ -60,6 +61,77 @@ def apply_rope(x, pos, theta: float = 10000.0):
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+
+
+def pages_view(pool, pages, num_kv_heads: int, head_dim: int):
+    """The rows' pages of a pool ``(P + 1, ps, W)`` gathered through
+    their tables ``pages`` (B, Lp) into the contiguous per-head view
+    ``(B, Hkv, Lp * ps, D)`` the slot layout has, the padding lanes of
+    the width dropped.  Trash-mapped positions read as ZERO: the -inf
+    validity mask hides them from the softmax, but the weighted sum
+    still multiplies their V by 0 — and 0 * NaN is NaN, so a single
+    non-finite value ever written to the trash page (any slot's
+    redirected garbage) would poison EVERY row whose table holds a
+    trash entry.  Zeroing makes trash inert regardless of what was
+    dumped there."""
+    from bigdl_tpu.ops.attention import paged_pool_dims
+    ps, _ = paged_pool_dims(pool)
+    b, lp = pages.shape
+    view = pool[pages][..., :num_kv_heads * head_dim] \
+        .reshape(b, lp * ps, num_kv_heads, head_dim).transpose(0, 2, 1, 3)
+    tmask = jnp.repeat(pages == pool.shape[0] - 1, ps, axis=1)
+    return jnp.where(tmask[:, None, :, None], 0, view)
+
+
+def pages_rows(view, width: int):
+    """The inverse of :func:`pages_view`'s un-merging on a per-head
+    ``(B, Hkv, T, D)`` array: its tokens as pool rows ``(B, T, W)``,
+    zero in the padding lanes."""
+    b, hkv, t, d = view.shape
+    rows = view.transpose(0, 2, 1, 3).reshape(b, t, hkv * d)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, width - hkv * d)))
+
+
+def _page_slots(pages, positions, ps: int, trash: int, active):
+    """Where each token of ``positions`` (B, S) is written: its physical
+    page and its offset in it, both (B, S).  A logical page past the
+    table, one the host left unmapped (its table slot names the trash
+    page already) and every token of an inactive row go to ``trash``."""
+    lp = pages.shape[1]
+    logical = positions // ps
+    phys = jnp.take_along_axis(pages, jnp.clip(logical, 0, lp - 1), axis=1)
+    phys = jnp.where(logical >= lp, trash, phys)
+    phys = jnp.where(jnp.asarray(active)[:, None], phys, trash)
+    return phys, positions % ps
+
+
+@functools.partial(jax.jit, inline=True)
+def _write_rows(pool, new, phys, offs):
+    """The cache write: the tokens ``new`` (B, Hkv, S, D) as rows of a
+    pool ``(P + 1, ps, W)`` at ``(phys, offs)`` (B, S), the page and the
+    offset in it of each token — a scatter whose window is one
+    contiguous row of the pool.  Traced once for all the layers of a
+    model (and for K and V): they share its shapes."""
+    from bigdl_tpu.ops.attention import paged_pool_dims
+    ps, width = paged_pool_dims(pool)
+    b, _, s, _ = new.shape
+    flat = pages_rows(new.astype(pool.dtype), width).reshape(b * s, width)
+
+    def by_tokens(c):
+        return c.at[phys.reshape(-1), offs.reshape(-1)].set(flat)
+
+    if s % ps:
+        return by_tokens(pool)
+
+    # a whole number of pages (a prefill bucket): where every row starts
+    # on a page's first token (a prefill does: shared prefixes are whole
+    # pages) the same tokens go page by page, S / ps windows of ps x W
+    # in place of S serial rows
+    def by_pages(c):
+        return c.at[phys[:, ::ps].reshape(-1)].set(
+            flat.reshape(b * s // ps, ps, width))
+
+    return jax.lax.cond(jnp.all(offs[:, 0] == 0), by_pages, by_tokens, pool)
 
 
 class MultiHeadAttention(Module):
@@ -256,15 +328,23 @@ class MultiHeadAttention(Module):
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32):
         """Block-paged KV cache for ``apply_decode_pages`` —
-        ``(num_pages + 1, H_kv, page_size, D)`` per tensor.  The extra
-        LAST page (id ``num_pages``) is the **trash page**: unallocated
+        ``(num_pages + 1, page_size, W)`` per tensor: page, token in
+        page, width.  A token's K (or V) of every head is one
+        contiguous row, KV head ``j`` in lanes ``[j * D, (j + 1) * D)``;
+        ``W`` is ``H_kv * D`` rounded up to whole 128-lane tiles
+        (``ops.attention.paged_pool_width``: GPT-2 XL's 25 x 64 = 1,600
+        -> 1,664; the padding lanes stay zero), so that the program's
+        boundary, the write's scatter and the paged-attention kernel
+        all take the pool in the one tiling it has.  The extra LAST
+        page (id ``num_pages``) is the **trash page**: unallocated
         page-table slots and inactive rows write there, so no in-graph
         write can ever land in a page another slot owns.  Physical
         pages carry no sequence identity; the host-side page table
         (``serving/scheduler/paging.py``) is the only map from a slot's
         logical positions to pool rows."""
-        shape = (num_pages + 1, self.num_kv_heads, page_size,
-                 self.head_dim)
+        from bigdl_tpu.ops.attention import paged_pool_width
+        shape = (num_pages + 1, page_size,
+                 paged_pool_width(self.num_kv_heads, self.head_dim))
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def apply_decode_pages(self, params, x_t, cache, pages, pos, active):
@@ -274,12 +354,14 @@ class MultiHeadAttention(Module):
         in pool page ``pages[b, l]``.  ``x_t`` (B, S, E) at positions
         ``[pos_b, pos_b + S)``; ``active`` (B,) gates writes.
 
-        Writes are a scatter at ``(pages[b, p // ps], p % ps)`` per
-        token; an inactive row, and any position whose logical page the
-        host left unmapped, is redirected to the TRASH page (the pool's
-        last row) — O(S) per row, and a write can never reach a page
-        outside the row's own table.  Reads gather the row's pages into
-        a contiguous ``(B, H, Lp*ps, D)`` view; garbage in trash-mapped
+        Writes are a scatter of token rows ``(W,)`` at ``(pages[b, p //
+        ps], p % ps)`` of the pool ``(P + 1, ps, W)``; an inactive row,
+        and any position whose logical page the host left unmapped, is
+        redirected to the TRASH page (the pool's last row) — O(S) per
+        row, and a write can never reach a page outside the row's own
+        table.  Reads go through the paged-attention kernel where it is
+        on, else gather the row's pages into a contiguous ``(B, H,
+        Lp*ps, D)`` view (``pages_view``); garbage in trash-mapped
         or unwritten pages is hidden by the same per-row validity
         predicate as the slot path (``l <= positions``).  Shared
         read-only prefix pages are safe under this contract by
@@ -300,70 +382,43 @@ class MultiHeadAttention(Module):
         if self.rope:
             q = apply_rope(q, positions, self.rope_theta)
             k = apply_rope(k, positions, self.rope_theta)
-        dt = cache["k"].dtype
-        ps = cache["k"].shape[2]
+        from bigdl_tpu.ops.attention import (expand_kv_heads,
+                                             paged_attention,
+                                             paged_attention_enabled,
+                                             paged_pool_dims)
+        ps, _ = paged_pool_dims(cache["k"])
         trash = cache["k"].shape[0] - 1
         pages = jnp.asarray(pages, jnp.int32)
         lp = pages.shape[1]
 
-        # physical page + offset per token; out-of-table logical pages
-        # and inactive rows redirect to trash
-        logical = positions // ps                                # (B, S)
-        offs = positions % ps
-        phys = jnp.take_along_axis(pages,
-                                   jnp.clip(logical, 0, lp - 1), axis=1)
-        phys = jnp.where(logical >= lp, trash, phys)
-        phys = jnp.where(jnp.asarray(active)[:, None], phys, trash)
-
-        def _scatter(c, new):
-            # new (B, Hkv, S, D) -> (B*S, Hkv, D) rows at (phys, offs)
-            flat = new.astype(dt).transpose(0, 2, 1, 3) \
-                      .reshape(b * s, self.num_kv_heads, self.head_dim)
-            return c.at[phys.reshape(-1), :, offs.reshape(-1), :] \
-                    .set(flat)
+        phys, offs = _page_slots(pages, positions, ps, trash, active)
 
         # the write and the read are scoped apart, so that a trace says
         # which of the two owns a relayout of the pool
         with jax.named_scope("kv.write"):
-            ck = _scatter(cache["k"], k)
-            cv = _scatter(cache["v"], v)
+            ck = _write_rows(cache["k"], k, phys, offs)
+            cv = _write_rows(cache["v"], v, phys, offs)
         scale = 1.0 / math.sqrt(self.head_dim)
-        from bigdl_tpu.ops.attention import (paged_attention,
-                                             paged_attention_enabled)
         if paged_attention_enabled():
             # r14: gather + masked attention in ONE Pallas kernel — the
             # page table rides in as a scalar-prefetch operand and the
             # index map does the gather, so the contiguous (B, H, L, D)
             # view below never exists in HBM.  Same math operation for
             # operation (trash zeroing, validity mask, f32 softmax):
-            # bit-parity with this gather path is regression-gated.
+            # parity with this gather path is regression-gated.
             with jax.named_scope("attn.paged"):
-                o = paged_attention(q, ck, cv, pages, positions, scale)
+                o = paged_attention(q, ck, cv, pages, positions, scale,
+                                    num_kv_heads=self.num_kv_heads)
             y = _proj(self._merge(o), params["wo"],
                       params["bo"] if self.with_bias else None)
             return y, {"k": ck, "v": cv}
         # read: gather the row's pages into a contiguous (B, H, L, D)
-        # view (L = Lp * ps) — the jnp fallback path (non-Pallas
-        # backends) and the kernel's parity oracle
+        # view (L = Lp * ps), trash-mapped positions zeroed — the jnp
+        # fallback path (non-Pallas backends) and the kernel's parity
+        # oracle
         with jax.named_scope("attn.paged"):
-            kk = ck[pages].transpose(0, 2, 1, 3, 4) \
-                          .reshape(b, self.num_kv_heads, lp * ps,
-                                   self.head_dim)
-            vv = cv[pages].transpose(0, 2, 1, 3, 4) \
-                          .reshape(b, self.num_kv_heads, lp * ps,
-                                   self.head_dim)
-        # zero trash-mapped positions in the gathered view: the -inf
-        # validity mask hides them from the softmax, but the weighted
-        # sum still multiplies their V by 0 — and 0 * NaN is NaN, so a
-        # single non-finite value ever written to the trash page (any
-        # slot's redirected garbage) would poison EVERY row whose table
-        # holds a trash entry.  Zeroing makes trash inert regardless of
-        # what was dumped there.
-        tmask = jnp.repeat(pages == trash, ps,
-                           axis=1)[:, None, :, None]    # (B, 1, L, 1)
-        kk = jnp.where(tmask, 0, kk)
-        vv = jnp.where(tmask, 0, vv)
-        from bigdl_tpu.ops.attention import expand_kv_heads
+            kk = pages_view(ck, pages, self.num_kv_heads, self.head_dim)
+            vv = pages_view(cv, pages, self.num_kv_heads, self.head_dim)
         kk, vv = expand_kv_heads(q, kk, vv)         # (B, H, L, D)
         scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
         valid = (jnp.arange(lp * ps)[None, None, :]
@@ -464,8 +519,10 @@ class LatentAttention(Module):
     Wkvb c`` per head; scores ``(q_n . k_n + q_r . k_r) / sqrt(nope +
     rope)``, causal softmax, ``Wo`` on the heads' weighted ``v``.
 
-    The cache holds the latent, not the heads: a page pool ``(P + 1, 1,
-    page_size, latent + rope)`` of ``[c, k_r]`` rows, read as K and, by
+    The cache holds the latent, not the heads: a page pool ``(P + 1,
+    page_size, W)`` of ``[c, k_r]`` rows (``MultiHeadAttention``'s pool
+    with ONE head of ``latent + rope``, padded to whole 128-lane tiles
+    like it: 576 -> 640), read as K and, by
     its first ``latent`` lanes, as V.  A decode step (``S == 1``) ABSORBS
     ``Wkvb``: ``q' = q_n Wkvb^K`` (H x latent) scores the latents
     directly and the weighted sum of latents goes through ``Wkvb^V`` — one
@@ -506,8 +563,9 @@ class LatentAttention(Module):
                          dtype=jnp.float32):
         """The latent page pool, under ``"k"``; its last page is the
         write-redirect trash page, as in ``MultiHeadAttention``'s."""
-        return {"k": jnp.zeros((num_pages + 1, 1, page_size,
-                                self.latent_dim + self.rope_dim), dtype)}
+        from bigdl_tpu.ops.attention import paged_pool_width
+        return {"k": jnp.zeros((num_pages + 1, page_size, paged_pool_width(
+            1, self.latent_dim + self.rope_dim)), dtype)}
 
     def _project(self, params, x, positions):
         """Per-head queries ``q_n`` (B, H, S, nope) and roped ``q_r``
@@ -543,20 +601,17 @@ class LatentAttention(Module):
         h, c, r = self.num_heads, self.latent_dim, self.rope_dim
         n, dv = self.nope_dim, self.v_dim
         positions = jnp.asarray(pos)[:, None] + jnp.arange(s)    # (B, S)
+        from bigdl_tpu.ops.attention import (paged_attention,
+                                             paged_attention_enabled,
+                                             paged_pool_dims)
         pool = cache["k"]
-        ps, trash = pool.shape[2], pool.shape[0] - 1
+        ps, trash = paged_pool_dims(pool)[0], pool.shape[0] - 1
         pages = jnp.asarray(pages, jnp.int32)
-        lp = pages.shape[1]
         with jax.named_scope("absorb"):
             q_n, q_r, lat, wkvb = self._project(params, x_t, positions)
         with jax.named_scope("kv.write"):
-            logical = positions // ps
-            phys = jnp.take_along_axis(pages, jnp.clip(logical, 0, lp - 1),
-                                       axis=1)
-            phys = jnp.where(logical >= lp, trash, phys)
-            phys = jnp.where(jnp.asarray(active)[:, None], phys, trash)
-            pool = pool.at[phys.reshape(-1), 0, (positions % ps).reshape(-1)
-                           ].set(lat.astype(pool.dtype).reshape(b * s, c + r))
+            pool = _write_rows(pool, lat[:, None], *_page_slots(
+                pages, positions, ps, trash, active))
         if s > 1:
             # prefill from position 0: expanded heads over the call's own
             # tokens, causal
@@ -570,14 +625,13 @@ class LatentAttention(Module):
                 qa = jnp.concatenate([qa.astype(x_t.dtype), q_r],
                                      axis=-1)[:, :, 0][:, None]  # (B,1,H,c+r)
                 rows = jnp.broadcast_to(positions, (b, h))
-            from bigdl_tpu.ops.attention import (paged_attention,
-                                                 paged_attention_enabled)
             with jax.named_scope("attn.paged"):
                 if paged_attention_enabled():
                     ctx = paged_attention(qa, pool, pool, pages, rows,
-                                          self.scale)[..., :c]
+                                          self.scale,
+                                          num_kv_heads=1)[..., :c]
                 else:
-                    ctx = self._gathered(qa, pool, pages, rows, trash)
+                    ctx = self._gathered(qa, pool, pages, rows)
             with jax.named_scope("absorb"):
                 o = jnp.einsum("bhc,hdc->bhd", ctx[:, 0], wkvb[:, n:]
                                )[:, :, None]                     # (B,H,1,dv)
@@ -598,16 +652,14 @@ class LatentAttention(Module):
         return _causal_attention(jnp.concatenate([q_n, q_r], axis=-1), k,
                                  kv[..., n:], self.scale)
 
-    def _gathered(self, q, pool, pages, rows, trash):
+    def _gathered(self, q, pool, pages, rows):
         """The jnp form of the absorbed read, ``apply_decode_pages``'s
         gather path on the one latent head: (B, 1, H, latent)."""
-        b, lp = pages.shape
-        ps, c = pool.shape[2], self.latent_dim
-        kk = pool[pages][:, :, 0].reshape(b, lp * ps, -1)        # (B, L, W)
-        kk = jnp.where(jnp.repeat(pages == trash, ps, axis=1)[..., None],
-                       0, kk)
+        c = self.latent_dim
+        kk = pages_view(pool, pages, 1,
+                        c + self.rope_dim)[:, 0]          # (B, L, c + r)
         s = jnp.einsum("bhd,bld->bhl", q[:, 0], kk) * self.scale
-        valid = jnp.arange(lp * ps)[None, None] <= rows[:, :, None]
+        valid = jnp.arange(kk.shape[1])[None, None] <= rows[:, :, None]
         w = jax.nn.softmax(jnp.where(valid, s, -jnp.inf)
                            .astype(jnp.float32), axis=-1)
         return jnp.einsum("bhl,blc->bhc", w.astype(kk.dtype),

@@ -607,8 +607,14 @@ class ContinuousGenerator:
         if self._paged:
             self._cache = self._new_paged_cache()
             # bytes of ONE page across every layer's pools
-            self._page_bytes = _row_bytes(
-                self._cache["pages"] if self._recurrent else self._cache)
+            pools = self._cache["pages"] if self._recurrent \
+                else self._cache
+            self._page_bytes = _row_bytes(pools)
+            from bigdl_tpu.ops.attention import paged_pool_dims
+            import jax
+            # lanes of a token's row (padding included) in the first pool
+            self._pool_width = int(paged_pool_dims(
+                jax.tree_util.tree_leaves(pools)[0])[1])
             if self._recurrent:
                 self._state_bytes = _row_bytes(self._cache["slots"])
         else:
@@ -626,6 +632,8 @@ class ContinuousGenerator:
         self._spec_accepted = 0
         self._pages_walked = 0
         self._pages_table = 0
+        # temp_size_in_bytes of each program compiled at warm-up
+        self._program_temp: "dict[str, Optional[int]]" = {}
 
         self._build_programs()
         if warmup:
@@ -757,11 +765,14 @@ class ContinuousGenerator:
                 # shared prefix pages are never written mid-chunk, so
                 # every row scatters back the identical bytes it
                 # gathered.
+                from bigdl_tpu.nn.attention import pages_rows, pages_view
+                from bigdl_tpu.ops.attention import paged_pool_dims
                 b, lp_w = pages.shape
-                psz = cache[0]["k"].shape[2]
+                psz, width = paged_pool_dims(cache[0]["k"])
                 trash = cache[0]["k"].shape[0] - 1
-                tmask = jnp.repeat(pages == trash, psz,
-                                   axis=1)[:, None, :, None]
+                # the heads of the slot layout, which the pool's rows
+                # hold side by side: (Hkv, D) of every layer
+                heads = model.paged_heads()
                 # the chunk writes ONLY positions [pos, pos + steps)
                 # per row — at most `touch_n` logical pages — so the
                 # write-back scatters just those, not the whole table
@@ -777,24 +788,20 @@ class ContinuousGenerator:
                     (touch >= lp_w) | ~active[:, None], trash,
                     phys_touch)
 
-                def to_view(pool):
-                    hkv, hd = pool.shape[1], pool.shape[3]
-                    v = pool[pages].transpose(0, 2, 1, 3, 4) \
-                                   .reshape(b, hkv, lp_w * psz, hd)
-                    return jnp.where(tmask, 0, v)
-
                 def to_pool(pool, view):
-                    hkv, hd = pool.shape[1], pool.shape[3]
+                    hkv, hd = view.shape[1], view.shape[3]
                     v5 = view.reshape(b, hkv, lp_w, psz, hd)
                     sel = jnp.take_along_axis(
                         v5, jnp.clip(touch, 0, lp_w - 1)
                         [:, None, :, None, None], axis=2)
-                    sel = sel.transpose(0, 2, 1, 3, 4) \
-                             .reshape(b * touch_n, hkv, psz, hd)
-                    return pool.at[phys_touch.reshape(-1)].set(sel)
+                    sel = pages_rows(sel.reshape(b, hkv, touch_n * psz,
+                                                 hd), width)
+                    return pool.at[phys_touch.reshape(-1)].set(
+                        sel.reshape(b * touch_n, psz, width))
 
-                views = [{"k": to_view(l["k"]), "v": to_view(l["v"])}
-                         for l in cache]
+                views = [{"k": pages_view(l["k"], pages, *hd),
+                          "v": pages_view(l["v"], pages, *hd)}
+                         for l, hd in zip(cache, heads)]
 
                 def one(carry, key):
                     tok, views, pos, active = carry
@@ -961,6 +968,23 @@ class ContinuousGenerator:
         self._step_fn = jax.jit(
             step_chunk, donate_argnums=(3,) if self._donate else ())
 
+    def _compile(self, name: str, fn, *args):
+        """Compile ``fn`` for ``args`` ahead of its first call (which
+        then reuses the executable: jit keeps one per lowering) and
+        record its ``temp_size_in_bytes`` under ``name``: a temporary of
+        the pool's size coming back into a program shows in
+        ``stats()["pages"]["program_temp_bytes"]`` without a trace.  A
+        backend without the analysis records ``None``: a gauge that is
+        missing says so."""
+        compiled = fn.lower(*args).compile()
+        try:
+            mem = compiled.memory_analysis()
+        except NotImplementedError:
+            mem = None
+        self._program_temp[name] = None if mem is None \
+            else int(mem.temp_size_in_bytes)
+        return fn(*args)
+
     def _warmup(self) -> None:
         """Compile every prefill rung, the decode chunk and (armed) the
         speculative chunk before the first request.  Without donation
@@ -984,11 +1008,13 @@ class ContinuousGenerator:
                     # (the 0 is the shared depth, or slot 0 of a model
                     # with recurrent state, whose first real tenant
                     # starts from zero whatever this leaves there)
-                    first, new_cache, _ = self._prefill_fn(
+                    first, new_cache, _ = self._compile(
+                        f"prefill.{b}", self._prefill_fn,
                         self.params, self.state, dummy, 1, self._cache,
                         trash_row, 0, key)
                 else:
-                    first, new_cache, _ = self._prefill_fn(
+                    first, new_cache, _ = self._compile(
+                        f"prefill.{b}", self._prefill_fn,
                         self.params, self.state, dummy, 1, self._cache,
                         0, key)
                 if self._donate:
@@ -1003,14 +1029,16 @@ class ContinuousGenerator:
             keys = jax.random.split(key, self.steps_per_sync)
             if self._paged:
                 table = jnp.asarray(self._page_table)
-                out = self._step_fn(self.params, self.state,
+                out = self._compile("step", self._step_fn,
+                                    self.params, self.state,
                                     jnp.asarray(self._tokens),
                                     self._cache, table,
                                     jnp.asarray(self._pos),
                                     jnp.asarray(self._active),
                                     jnp.asarray(self._limit), keys)
             else:
-                out = self._step_fn(self.params, self.state,
+                out = self._compile("step", self._step_fn,
+                                    self.params, self.state,
                                     jnp.asarray(self._tokens),
                                     self._cache,
                                     jnp.asarray(self._pos),
@@ -1021,7 +1049,8 @@ class ContinuousGenerator:
             np.asarray(out[0])
             if self._draft is not None:
                 table = jnp.asarray(self._page_table)
-                spec = self._spec_fn(self.params, self.state,
+                spec = self._compile("spec", self._spec_fn,
+                                     self.params, self.state,
                                      self._draft_params,
                                      self._draft_state,
                                      jnp.asarray(self._tokens),
@@ -2252,7 +2281,18 @@ class ContinuousGenerator:
                 pages_total=self._alloc.num_pages,
                 prefix_pages=(self._prefix.held_pages
                               if self._prefix is not None else 0),
-                **self._tags)
+                **self._pool_gauges(), **self._tags)
+
+    def _pool_gauges(self) -> dict:
+        """What the pool's layout exists to remove, beside what it
+        costs: each compiled program's temporaries (a relayouted copy of
+        the pool would show as its size here), the lanes of a token's
+        row and the bytes of the pools as they lie on the device (trash
+        page and padding lanes included)."""
+        return {"program_temp_bytes": dict(self._program_temp),
+                "pool_width": self._pool_width,
+                "pool_padded_bytes":
+                    (self._alloc.num_pages + 1) * self._page_bytes}
 
     def _evict(self, slot: int, status: str) -> None:
         """Finish the request in ``slot`` and free it for the next
@@ -2400,6 +2440,7 @@ class ContinuousGenerator:
                 "capacity_tokens": self._alloc.capacity_tokens,
                 "page_bytes": self._page_bytes,
                 "pool_bytes": self._alloc.num_pages * self._page_bytes,
+                **self._pool_gauges(),
                 "mean_token_occupancy": (
                     self._token_occupancy_sum / self._chunks
                     if self._chunks else 0.0),

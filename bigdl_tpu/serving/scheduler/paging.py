@@ -27,6 +27,15 @@ Everything here is host bookkeeping for the single scheduler thread —
 no locks, no device arrays.  The device half (page-table gather/scatter
 attention) lives in ``nn/attention.py::apply_decode_pages``; see
 docs/serving.md for the page lifecycle diagram.
+
+A page id is an index into AXIS 0 of every device pool, and the only
+thing this module knows of them.  A pool is ``(num_pages + 1,
+page_size, W)``: page, token in page, width — a token's K (or V) of
+every KV head side by side, ``W`` padded to whole 128-lane tiles
+(``ops.attention.paged_pool_width``; ``paged_pool_dims`` reads a pool's
+page size and width).  The extra last page, id ``num_pages``, is the
+trash page.  Sharing a prefix page, parking it to the host and
+resuming it move whole ``page_size x W`` blocks by their ids.
 """
 
 from __future__ import annotations

@@ -542,12 +542,18 @@ def _attention_rows(t_fused, t_stream, heads, head_dim):
 def _paged_rows(slots, heads, wide_heads, head_dim, max_len, page_size,
                 prefill):
     """``paged_attention`` against the gather path of
-    ``apply_decode_pages``, bf16 cache, tables part trash: a decode step
-    (S=1, every slot) and a prefill bucket (one row) at the serve
-    model's heads; and at ``wide_heads`` (GPT-2 XL's 25: every KV head
-    of a page in one block) a decode step whose slots hold one to three
-    pages, so that nearly all of the walk is skipped, and one whose
-    slots are full to the last position, so that none is."""
+    ``apply_decode_pages``, bf16 cache on the token-major pool, tables
+    part trash: a decode step (S=1, every slot) and a prefill bucket
+    (one row) at the serve model's heads; and at ``wide_heads`` (GPT-2
+    XL's 25: an odd count, the pool's width padded to whole lane tiles)
+    a decode step whose slots hold one to three pages, so that nearly
+    all of the walk is skipped, and one whose slots are full to the last
+    position, so that none is.  Beside each row's error, what the step
+    costs: the layer as the generator's programs hold it (the cache
+    donated, written and read), its device time by group from a trace of
+    ``OBSERVED`` runs and the program's ``temp_size_in_bytes`` — a
+    relayout of the pool would show as copies of about the pool's time
+    and as temporaries of its size."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -573,9 +579,15 @@ def _paged_rows(slots, heads, wide_heads, head_dim, max_len, page_size,
             ("decode.full", slots, 1, wide_heads, max_len - 1, max_len)):
         attn, params = layers[h]
         num_pages = b * lp
-        ck, cv = [jax.random.normal(k, (num_pages + 1, h, page_size,
-                                        head_dim), jnp.bfloat16)
-                  for k in jax.random.split(jax.random.PRNGKey(s), 2)]
+        width = ops_attention.paged_pool_width(h, head_dim)
+
+        def pool(key):
+            # K (or V) of every head side by side, the padding lanes zero
+            return jnp.pad(jax.random.normal(
+                key, (num_pages + 1, page_size, h * head_dim),
+                jnp.bfloat16), ((0, 0), (0, 0), (0, width - h * head_dim)))
+
+        ck, cv = [pool(k) for k in jax.random.split(jax.random.PRNGKey(s), 2)]
         pos = rs.randint(lo, hi, b).astype(np.int32)
         # the rest of a row's table is trash
         pages = np.full((b, lp), num_pages, np.int32)
@@ -586,10 +598,13 @@ def _paged_rows(slots, heads, wide_heads, head_dim, max_len, page_size,
         x = jax.random.normal(jax.random.PRNGKey(7),
                               (b, s, h * head_dim), jnp.bfloat16)
 
-        def run():
-            return jax.jit(lambda p, x, k, v: attn.apply_decode_pages(
+        def layer(p, x, k, v):
+            return attn.apply_decode_pages(
                 p, x, {"k": k, "v": v}, jnp.asarray(pages),
-                jnp.asarray(pos), jnp.ones((b,), bool))[0])(params, x, ck, cv)
+                jnp.asarray(pos), jnp.ones((b,), bool))
+
+        def run():
+            return jax.jit(lambda *a: layer(*a)[0])(params, x, ck, cv)
 
         check(ops_attention.paged_attention_enabled(),
               "paged-attention gate is off")
@@ -600,9 +615,52 @@ def _paged_rows(slots, heads, wide_heads, head_dim, max_len, page_size,
         with mock.patch.object(ops_attention, "paged_attention_enabled",
                                return_value=False):
             gather = run()
-        rows.append((f"paged_attention.{name} B={b} H={h} S={s} "
-                     f"L={max_len}", _rel_err(kernel, gather), TOL_BF16))
+        label = f"paged_attention.{name} B={b} H={h} S={s} L={max_len}"
+        rows.append((label, _rel_err(kernel, gather), TOL_BF16))
+        print(f"    {label}: {_paged_step_cost(layer, params, x, ck, cv)}",
+              flush=True)
     return rows
+
+
+OBSERVED = 5        # runs of a paged step inside its traced slice
+
+
+def _paged_step_cost(layer, params, x, ck, cv) -> str:
+    """One line on what a paged step costs beside its kernel: see
+    ``_paged_rows``."""
+    import jax
+
+    from benchmark import trace_capture, trace_reduce
+
+    step = jax.jit(layer, donate_argnums=(2, 3))
+    compiled = step.lower(params, x, ck, cv).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    pool_mb = 2 * ck.size * ck.dtype.itemsize / 1e6
+    ck, cv = ck + 0, cv + 0             # the caller keeps its own
+    _, cache = step(params, x, ck, cv)
+    sl = trace_capture.Slice(os.path.join(
+        os.environ.get("TMPDIR", "/tmp"), f"chip-smoke-paged-{os.getpid()}"))
+    sl.start()
+    try:
+        for _ in range(OBSERVED):
+            y, cache = step(params, x, cache["k"], cache["v"])
+        jax.block_until_ready(y)
+    finally:
+        sl.stop()
+    by_group = {}
+    for dev in ((sl.events() or {}).get("devices") or {}).values():
+        for op_name, category, _start, dur in dev["ops"]:
+            if not op_name.lstrip("%").startswith(trace_reduce.CONTAINERS):
+                g = trace_reduce.op_group(op_name, category)
+                by_group[g] = by_group.get(g, 0.0) + dur / 1e6 / OBSERVED
+        break
+    timed = "device time not traced on this backend"
+    if by_group:
+        timed = (f"kernel {by_group.get('Pallas custom call', 0.0):.3f} ms, "
+                 f"copies and layout "
+                 f"{by_group.get('copies and layout', 0.0):.3f} ms of "
+                 f"{sum(by_group.values()):.3f} ms a step")
+    return f"{timed}; temp {temp / 1e6:.2f} MB beside pools of {pool_mb:.1f} MB"
 
 
 def _quant_rows(matmuls, conv):

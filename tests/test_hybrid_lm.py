@@ -430,6 +430,7 @@ def test_what_moves_pages_only_is_declined_or_refused_typed(toy):
 
 
 def test_stats_and_budget_tell_pages_from_slot_state(toy):
+    from bigdl_tpu.ops.attention import paged_pool_width
     model, params, state = toy
     budget = MemoryBudgeter()
     gen = _generator(model, params, state, budgeter=budget)
@@ -438,8 +439,11 @@ def test_stats_and_budget_tell_pages_from_slot_state(toy):
         per_slot = 2 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 4)   # two KDA layers
         assert st["state"] == {"bytes_per_slot": per_slot,
                                "bytes": 3 * per_slot}
-        # pages stay pages: two latent pools of 16 x 40 float32 a page
-        assert st["pages"]["page_bytes"] == 2 * 16 * 40 * 4
+        # pages stay pages: two latent pools of 16 tokens a page, their
+        # 40 lanes padded to a whole tile, float32
+        assert paged_pool_width(1, 40) == 128
+        assert st["pages"]["page_bytes"] == 2 * 16 * 128 * 4
+        assert st["pages"]["pool_width"] == 128
         assert st["pages"]["pool_bytes"] \
             == st["pages"]["total"] * st["pages"]["page_bytes"]
         fut = gen.submit(np.arange(1, 20), 24)

@@ -141,16 +141,23 @@ def test_host_offload_tier_bookkeeping():
 def test_park_resume_bit_equal_vs_never_parked(position):
     """An explicitly parked session's next turn (transparent resume)
     is bit-equal to the single-shot reference over the same history —
-    for learned positions and rope both."""
+    for learned positions and rope both.  What is parked is whole pages
+    of the token-major pool, ``(pages, page size, width)`` a tensor:
+    park and resume index the page axis and nothing else."""
     m, params, state = _lm(position=position)
     t1 = np.arange(1, 9, dtype=np.int32)
     t2 = np.array([11, 12, 13], np.int32)
     with _gen(m, params, state, num_pages=32) as g:
         out1 = g.submit(t1, 5, session="s").result(timeout=60)
+        held = g.session_info("s")["private_pages"]
         assert g.park("s").result(timeout=30) is True
         info = g.session_info("s")
         assert info["state"] == "parked" and info["private_pages"] == 0
         assert g.stats()["offload"]["parked_sessions"] == 1
+        payload = g._offload._parked["s"][0]
+        assert len(payload) == 2 and all(
+            t.shape == (held, 4, 128) for layer in payload
+            for t in layer.values())
         out2 = g.submit(t2, 5, session="s").result(timeout=60)
         assert g.session_info("s")["state"] == "resident"
     np.testing.assert_array_equal(

@@ -506,6 +506,280 @@ def test_decode_pages_matches_decode_slots():
     np.testing.assert_array_equal(beforep, np.asarray(c3[0]["k"])[:b * 8])
 
 
+# -- the pool's layout: page, token in page, width ----------------------------
+
+@pytest.mark.parametrize("hkv,d,width", [
+    (25, 64, 1664),     # GPT-2 XL: 1,600 lanes padded to 13 tiles
+    (8, 128, 1024),     # whole tiles already: nothing padded
+    (2, 64, 128), (3, 64, 256), (4, 8, 128),
+    (5, 96, 768),       # a chunk is 4 heads = 384 lanes: 480 -> 768
+    (1, 576, 640),      # the latent pool's one head
+    (1, 40, 128)])
+def test_pool_is_page_token_width(hkv, d, width):
+    """ONE helper says a pool's page size and width, and every pool —
+    per-head K and V, the latent — has the form it reads: axis 0 the
+    pages with the trash page last, a token's row whole lane tiles."""
+    from bigdl_tpu.nn.attention import (LatentAttention,
+                                        MultiHeadAttention, pages_rows,
+                                        pages_view)
+    from bigdl_tpu.ops.attention import paged_pool_dims, paged_pool_width
+    assert paged_pool_width(hkv, d) == width and width % 128 == 0
+    if hkv == 1:
+        cache = LatentAttention(64, 4, latent_dim=d - 8, rope_dim=8) \
+            .init_paged_cache(6, 16, jnp.bfloat16)
+        assert set(cache) == {"k"}
+    else:
+        cache = MultiHeadAttention(hkv * d, hkv).init_paged_cache(
+            6, 16, jnp.bfloat16)
+    for pool in cache.values():
+        assert pool.shape == (7, 16, width)
+        assert paged_pool_dims(pool) == (16, width)
+    # rows -> pool -> view is the identity on the heads, zero elsewhere
+    rs = np.random.RandomState(0)
+    kv = jnp.asarray(rs.randn(2, hkv, 32, d), jnp.float32)
+    rows = pages_rows(kv, width)
+    assert rows.shape == (2, 32, width)
+    assert not np.asarray(rows[..., hkv * d:]).any()
+    pool = jnp.zeros((5, 16, width)).at[:4].set(rows.reshape(4, 16, width))
+    pages = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
+    np.testing.assert_array_equal(pages_view(pool, pages, hkv, d), kv)
+    # a trash-mapped slot reads as zero whatever the trash page holds
+    pool = pool.at[4].set(jnp.nan)
+    got = pages_view(pool, jnp.asarray([[0, 4], [4, 3]], jnp.int32), hkv, d)
+    np.testing.assert_array_equal(got[0, :, 16:], 0)
+    np.testing.assert_array_equal(got[1, :, :16], 0)
+    np.testing.assert_array_equal(got[0, :, :16], kv[0, :, :16])
+
+
+@pytest.mark.parametrize("case", ["inactive_row", "unmapped_page",
+                                  "bucket_padding", "past_the_table"])
+@pytest.mark.parametrize("layer", ["heads", "latent"])
+def test_write_lands_only_in_the_rows_table_or_the_trash_page(layer, case):
+    """The write's containment on the token-major pool: a token row
+    goes to ``(pages[b, p // ps], p % ps)``; an inactive row, a logical
+    page the host left unmapped, the part of a padded bucket beyond the
+    mapped pages and positions past the table itself land in the trash
+    page; no other page changes by a byte, and the padding lanes of
+    what is written stay zero."""
+    from bigdl_tpu.nn.attention import LatentAttention, MultiHeadAttention
+    ps, lp, num_pages = 4, 4, 12
+    if layer == "heads":
+        attn = MultiHeadAttention(48, 3)        # 3 x 16 = 48 lanes of 128
+        used = 48
+    else:
+        attn = LatentAttention(48, 4, latent_dim=24, rope_dim=8,
+                               nope_dim=8, v_dim=8)
+        used = 32
+    params = attn.init_params(jax.random.PRNGKey(0))
+    cache = attn.init_paged_cache(num_pages, ps)
+    rs = np.random.RandomState(1)
+    # every page holds a sentinel, the trash page too
+    cache = {k: jnp.asarray(rs.randn(*v.shape), v.dtype)
+             for k, v in cache.items()}
+    pages = np.full((2, lp), num_pages, np.int32)
+    pages[0, :3] = [7, 2, 9]
+    pages[1, :2] = [4, 11]
+    s, pos, active = 1, [5, 6], [True, True]
+    if case == "inactive_row":
+        active = [True, False]
+        written = {0: [2]}                      # row 1 writes nothing
+    elif case == "unmapped_page":
+        pages[1, 1] = num_pages                 # row 1's page for pos 6
+        written = {0: [2]}
+    elif case == "bucket_padding":
+        # 12 tokens from 0 into tables that map 3 and 2 pages: row 1's
+        # last four go to the trash page
+        s, pos = 12, [0, 0]
+        written = {0: [7, 2, 9], 1: [4, 11]}
+    else:
+        # positions 14..17 of a table of 4 pages of 4: the last two
+        # are past the table
+        s, pos = 4, [14, 14]
+        pages[0, 3] = 5
+        written = {0: [5]}
+    x = jnp.asarray(rs.randn(2, s, 48), jnp.float32)
+    _, new = attn.apply_decode_pages(
+        params, x, dict(cache), jnp.asarray(pages),
+        jnp.asarray(pos, jnp.int32), jnp.asarray(active))
+    touched = sorted(p for ids in written.values() for p in ids)
+    for name, before in cache.items():
+        after = np.asarray(new[name])
+        before = np.asarray(before)
+        same = [p for p in range(num_pages) if p not in touched]
+        np.testing.assert_array_equal(before[same], after[same])
+        for p in touched:
+            assert not np.array_equal(before[p], after[p])
+            changed = np.any(before[p] != after[p], axis=-1)
+            # a written token's row is zero beyond the heads' lanes
+            assert not after[p][changed][:, used:].any()
+        # what was redirected went to the trash page
+        assert not np.array_equal(before[num_pages], after[num_pages])
+
+
+@pytest.mark.parametrize("pos", [[0, 8], [4, 4], [3, 8], [6, 5]])
+def test_page_wise_write_is_the_token_wise_write(pos):
+    """A call of a whole number of pages writes page by page where
+    every row starts on a page's first token (a prefill) and token by
+    token else: either way each token's row lands at ``(pages[b, p //
+    ps], p % ps)``, what lies beyond the table in the trash page."""
+    from bigdl_tpu.nn.attention import MultiHeadAttention, _proj, pages_rows
+    ps, lp, num_pages, s = 4, 4, 12, 8
+    attn = MultiHeadAttention(48, 3)
+    params = attn.init_params(jax.random.PRNGKey(0))
+    cache = attn.init_paged_cache(num_pages, ps)
+    rs = np.random.RandomState(2)
+    cache = {k: jnp.asarray(rs.randn(*v.shape), v.dtype)
+             for k, v in cache.items()}
+    pages = np.asarray([[7, 2, 9, 5], [4, 11, 0, 12]], np.int32)
+    x = jnp.asarray(rs.randn(2, s, 48), jnp.float32)
+    _, new = attn.apply_decode_pages(
+        params, x, dict(cache), jnp.asarray(pages),
+        jnp.asarray(pos, jnp.int32), jnp.asarray([True, True]))
+    for name, w, bias in (("k", "wk", "bk"), ("v", "wv", "bv")):
+        rows = np.asarray(pages_rows(attn._split(
+            _proj(x, params[w], params[bias]), 3), 128))
+        want = np.asarray(cache[name]).copy()
+        for b in range(2):
+            for i in range(s):
+                page, off = divmod(pos[b] + i, ps)
+                if page < lp and pages[b, page] != num_pages:
+                    want[pages[b, page], off] = rows[b, i]
+        got = np.asarray(new[name])
+        np.testing.assert_array_equal(want[:num_pages], got[:num_pages])
+
+
+def _pool_sized_ops(jaxpr, pool_size):
+    """(primitive, shapes) of every equation, through every nested
+    program, that takes or gives an array of at least the pool's size,
+    apart from the ones that only pass it on."""
+    passing = {"pjit", "jit", "closed_call", "core_call", "scan", "while",
+               "cond", "custom_jvp_call", "custom_vjp_call", "remat"}
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            subs = [v for v in eqn.params.values()
+                    if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+            subs += [b for v in eqn.params.values()
+                     if isinstance(v, (tuple, list)) for b in v
+                     if hasattr(b, "eqns") or hasattr(b, "jaxpr")]
+            if eqn.primitive.name == "pallas_call":
+                subs = []                   # the kernel's own body
+            for sub in subs:
+                walk(getattr(sub, "jaxpr", sub))
+            big = [tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)
+                   if hasattr(v, "aval") and hasattr(v.aval, "shape")
+                   and int(np.prod(v.aval.shape)) >= pool_size]
+            if big and eqn.primitive.name not in passing:
+                found.append((eqn.primitive.name, big))
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("program", ["prefill", "step"])
+def test_programs_meet_the_pool_only_at_the_scatter_and_the_kernel(
+        program, monkeypatch):
+    """What a CPU can guard of "no relayout": in the jaxpr of
+    ``prefill`` and of ``step_chunk_kernel`` an array of the pool's size
+    is an operand or a result of the write's ``scatter`` and of the
+    ``pallas_call`` only: no ``transpose``, ``reshape``, ``gather``,
+    ``pad``, ``convert_element_type`` or copy of it, whatever the
+    model's head count (3 heads of 16: a padded width)."""
+    monkeypatch.setenv("BIGDL_TPU_PALLAS_INTERPRET", "1")
+    m, params, state = _lm(embed=48, heads=3, layers=2, max_len=64)
+    g = ContinuousGenerator(m, params, state, num_slots=2, page_size=4,
+                            seq_buckets=[16], steps_per_sync=2,
+                            warmup=False, paged_kernel=True)
+    try:
+        pool = g._cache[0]["k"]
+        assert pool.shape == (2 * 16 + 1, 4, 128)
+        key = jax.random.PRNGKey(0)
+        table = jnp.asarray(g._page_table)
+        if program == "prefill":
+            jaxpr = jax.make_jaxpr(g._prefill_fn)(
+                params, state, jnp.ones((1, 16), jnp.int32), 1, g._cache,
+                table[:1], 0, key)
+        else:
+            jaxpr = jax.make_jaxpr(g._step_fn)(
+                params, state, jnp.asarray(g._tokens), g._cache, table,
+                jnp.asarray(g._pos), jnp.asarray(g._active),
+                jnp.asarray(g._limit), jax.random.split(key, 2))
+    finally:
+        g.drain(timeout=30)
+    ops = _pool_sized_ops(jaxpr, pool.size)
+    names = {name for name, _ in ops}
+    assert names == {"scatter", "pallas_call"}, ops
+    # two layers x K and V, written once and read once (a step: the
+    # scan's body holds them once; a prefill states the write twice,
+    # page by page and token by token, under one cond)
+    assert sum(name == "scatter" for name, _ in ops) == (
+        8 if program == "prefill" else 4)
+    assert sum(name == "pallas_call" for name, _ in ops) == 2
+
+
+def test_stats_carry_each_programs_temporaries_beside_the_pool():
+    """The gauge the layout brings: ``temp_size_in_bytes`` of every
+    program compiled at warm-up, beside the pools' bytes and a row's
+    width, in ``stats()["pages"]``."""
+    m, params, state = _lm(embed=48, heads=3, layers=1, max_len=32)
+    with ContinuousGenerator(m, params, state, num_slots=2, page_size=4,
+                             seq_buckets=[8, 16],
+                             steps_per_sync=2) as g:
+        pg = g.stats()["pages"]
+    assert set(pg["program_temp_bytes"]) == {"prefill.8", "prefill.16",
+                                             "step"}
+    assert all(isinstance(v, int) and v >= 0
+               for v in pg["program_temp_bytes"].values())
+    assert pg["pool_width"] == 128              # 3 x 16 = 48 lanes
+    assert pg["page_bytes"] == 2 * 4 * 128 * 4  # K and V, float32
+    assert pg["pool_bytes"] == pg["total"] * pg["page_bytes"]
+    assert pg["pool_padded_bytes"] == (pg["total"] + 1) * pg["page_bytes"]
+    # not warmed: nothing compiled yet, nothing recorded
+    g = ContinuousGenerator(m, params, state, num_slots=2, page_size=4,
+                            seq_buckets=[8], warmup=False)
+    try:
+        assert g.stats()["pages"]["program_temp_bytes"] == {}
+    finally:
+        g.drain(timeout=30)
+
+
+@pytest.mark.parametrize("analysis", [None, NotImplementedError,
+                                      RuntimeError])
+def test_a_program_without_memory_analysis_is_recorded_as_none(analysis):
+    """A backend that has no memory analysis leaves ``None`` under the
+    program's name, so the gauge says that it is missing; any other
+    failure of the compile is the caller's to see."""
+    class Program:
+        def __call__(self, x):
+            return x + 1
+
+        def lower(self, x):
+            return self
+
+        def compile(self):
+            return self
+
+        def memory_analysis(self):
+            if analysis is not None:
+                raise analysis("no analysis")
+
+    m, params, state = _lm(embed=48, heads=3, layers=1, max_len=32)
+    g = ContinuousGenerator(m, params, state, num_slots=2, page_size=4,
+                            seq_buckets=[8], warmup=False)
+    try:
+        if analysis is RuntimeError:
+            with pytest.raises(RuntimeError):
+                g._compile("step", Program(), 1)
+            assert g.stats()["pages"]["program_temp_bytes"] == {}
+        else:
+            assert g._compile("step", Program(), 1) == 2
+            assert g.stats()["pages"]["program_temp_bytes"] == {
+                "step": None}
+    finally:
+        g.drain(timeout=30)
+
+
 # -- observability ------------------------------------------------------------
 
 def test_paged_ledger_records_and_report(tmp_path):
@@ -545,6 +819,12 @@ def test_paged_ledger_records_and_report(tmp_path):
         and start["speculative"] and start["spec_k"] == 3
     pages = [r for r in records if r.get("type") == "serve.pages"]
     assert pages and all(0 <= p["token_occupancy"] <= 1 for p in pages)
+    # the layout's gauges ride on the same record: each warmed program's
+    # temporaries beside the pools' size and a row's width (32 -> 128)
+    assert all(p["pool_width"] == 128 for p in pages)
+    assert set(pages[0]["program_temp_bytes"]) == {
+        "prefill.8", "prefill.32", "step", "spec"}
+    assert pages[0]["pool_padded_bytes"] > 0
     admits = [r for r in records if r.get("type") == "serve.cache"
               and r.get("event") == "admit"]
     assert len(admits) == 4

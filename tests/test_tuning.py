@@ -17,15 +17,17 @@ Five surfaces, all under the ``tuning`` marker:
 4. the fused int8 conv: patches+fused-matmul vs the in-graph widen at
    ragged shapes, eligibility dispatch (stride/dilation/groups keep the
    widen);
-5. the Pallas paged-attention kernel: BIT-parity vs the
-   ``decode_pages`` gather path (incl. GQA + rope + a NaN-poisoned
-   trash page — the full-capacity-neighbor regression scenario), and
+5. the Pallas paged-attention kernel: parity vs the ``decode_pages``
+   gather path on the token-major pool (incl. GQA + rope + a
+   NaN-poisoned trash page — the full-capacity-neighbor regression
+   scenario; to the last place wherever the CPU sums alike), and
    the scheduler's ``paged_kernel`` mode end to end; plus the ``cli
    tune`` smoke artifact and run-report's kernel-tuning section.
 """
 
 import glob
 import json
+import math
 import os
 
 import jax
@@ -415,50 +417,236 @@ class TestFusedConv:
 
 # -- 5. paged attention + scheduler + CLI ------------------------------------
 
+def _pool(rng, pages, hkv, ps, d, dtype=jnp.float32, poison=True):
+    """A page pool ``(pages + 1, ps, W)`` of random K (or V): KV head
+    ``j`` in lanes ``[j*d, (j+1)*d)``, the padding lanes zero."""
+    from bigdl_tpu.ops.attention import paged_pool_width
+    a = np.zeros((pages + 1, ps, paged_pool_width(hkv, d)), np.float32)
+    a[..., :hkv * d] = rng.randn(pages + 1, ps, hkv * d)
+    if poison:
+        # the trash page holds NaN garbage: the kernel must zero it
+        # exactly like the gather path's tmask (the full-capacity-
+        # neighbor regression class — 0 * NaN poisons softmax sums)
+        a[pages] = np.nan
+    return jnp.asarray(a, dtype)
+
+
+def _gather(q, kp, vp, pages, pos, scale, hkv):
+    """The jnp gather path of ``apply_decode_pages`` on the pool
+    ``(P + 1, ps, W)``: the oracle."""
+    from bigdl_tpu.ops.attention import expand_kv_heads
+    b, _, _, d = q.shape
+    ps, lp, trash = kp.shape[1], pages.shape[1], kp.shape[0] - 1
+    tmask = jnp.repeat(pages == trash, ps, axis=1)[:, None, :, None]
+
+    def view(pool):
+        v = pool[pages][..., :hkv * d].reshape(b, lp * ps, hkv, d)
+        return jnp.where(tmask, 0, v.transpose(0, 2, 1, 3))
+
+    kk, vv = expand_kv_heads(q, view(kp), view(vp))
+    scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
+    valid = (jnp.arange(lp * ps)[None, None, :] <= pos[:, :, None])
+    scores = jnp.where(valid[:, None], scores, -jnp.inf)
+    wts = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    return jnp.einsum("bhsl,bhld->bhsd", wts.astype(vv.dtype), vv)
+
+
+def _shaped(q, kp, vp, pages, pos, scale, hkv, *, f32_scores=False,
+            zero_trash=True):
+    """The gather path's math, operation for operation, in products of
+    the kernel's SHAPES: the bit-exact oracle.  XLA's CPU backend picks
+    its dot emitter by shape, so `_gather`'s per-head products over
+    ``d`` lanes sum the same terms in another order than the kernel's;
+    here a head's query is zero outside its own lanes of the chunk (a
+    prefill bucket; one wide head), or every head of a lane group is a
+    row of one block-diagonal product over the group's lanes (few
+    queries a head), as `_paged_tiling` says — written with plain
+    indexing, not with the wrapper's reshapes.  ``f32_scores`` and
+    ``zero_trash=False`` plant the two faults the parity gate exists
+    for: scores left in float32 where the reference's einsum rounds to
+    the promoted dtype, and a trash page read as it is."""
+    from bigdl_tpu.ops import attention as A
+    b, h, s, d = q.shape
+    ps, width = A.paged_pool_dims(kp)
+    lp, trash, group = pages.shape[1], kp.shape[0] - 1, h // hkv
+    length = lp * ps
+    groups, chunk, rows, _ = A._paged_tiling(
+        hkv, group, s, length, d, ps, jnp.dtype(kp.dtype).itemsize)
+    tmask = jnp.repeat(pages == trash, ps, axis=1)[..., None]
+
+    def view(pool):
+        v = pool[pages].reshape(b, length, width)
+        return jnp.where(tmask, 0, v) if zero_trash else v
+
+    kk, vv = view(kp), view(vp)
+    valid = jnp.arange(length)[None, None, :] <= pos[:, :, None]
+
+    def attend(qr, k2, v2, ok):
+        # the accumulator is float32 on either path (a CPU's bf16 dot
+        # upcasts, the MXU accumulates so); the ROUNDING of the scores
+        # to the promoted dtype is the reference's
+        sc = jnp.einsum("rc,lc->rl", qr, k2,
+                        preferred_element_type=jnp.float32)
+        if not f32_scores:
+            sc = sc.astype(jnp.result_type(qr.dtype, k2.dtype))
+        sc = jnp.where(ok, sc * scale, -jnp.inf)
+        w = jax.nn.softmax(sc.astype(jnp.float32), axis=-1)
+        return jnp.einsum("rl,lc->rc", w.astype(v2.dtype), v2,
+                          preferred_element_type=jnp.float32
+                          ).astype(kp.dtype)
+
+    dp = d if hkv > 1 else width        # lanes a head takes in the pool
+    hp = width // dp
+    qh = jnp.pad(q.reshape(b, hkv, group * s, d),
+                 ((0, 0), (0, hp - hkv), (0, 0), (0, dp - d)))
+    out = [[None] * hp for _ in range(b)]
+    for i in range(b):
+        if rows:
+            hg = hp // groups
+            ok = jnp.tile(valid[i], (hg * group, 1))
+            for g in range(groups):
+                lanes = slice(g * hg * dp, (g + 1) * hg * dp)
+                qr = jnp.zeros((hg, group * s, hg, dp), q.dtype)
+                for j in range(hg):
+                    qr = qr.at[j, :, j].set(qh[i, g * hg + j])
+                o = attend(qr.reshape(hg * group * s, hg * dp),
+                           kk[i, :, lanes], vv[i, :, lanes], ok)
+                o = o.reshape(hg, group * s, hg, dp)
+                for j in range(hg):
+                    out[i][g * hg + j] = o[j, :, j]
+            continue
+        hc = chunk // dp
+        ok = jnp.tile(valid[i], (group, 1))
+        for j in range(hp):
+            c, t = divmod(j, hc)
+            lanes = slice(c * chunk, (c + 1) * chunk)
+            qr = jnp.zeros((group * s, hc, dp), q.dtype).at[:, t].set(
+                qh[i, j])
+            o = attend(qr.reshape(group * s, chunk), kk[i, :, lanes],
+                       vv[i, :, lanes], ok)
+            out[i][j] = o.reshape(group * s, hc, dp)[:, t]
+    out = jnp.stack([jnp.stack(r) for r in out])    # (B, hp, group x S, dp)
+    return out[:, :hkv, :, :d].reshape(b, h, s, d)
+
+
+def _bits(want, got):
+    """Bit for bit, NaN where NaN is."""
+    want, got = np.asarray(want), np.asarray(got)
+    assert want.dtype == got.dtype and want.shape == got.shape
+    return np.array_equal(want.astype(np.float32), got.astype(np.float32),
+                          equal_nan=True)
+
+
+def _close(want, got):
+    """`_shaped` against `_gather`: the same terms summed in another
+    order (see `_shaped`), so a few units in the last place of the
+    float32 accumulator, at most ONE of a bf16 result (2**-7 of it) —
+    what a float32 score where the reference's is bf16 moves a result
+    by, so that fault is held by `_bits`, not here."""
+    want, got = np.asarray(want), np.asarray(got)
+    assert want.dtype == got.dtype and want.shape == got.shape
+    rtol, atol = (2.0 ** -7, 0) if want.dtype == jnp.bfloat16 \
+        else (2e-6, 2e-6)
+    return np.allclose(want.astype(np.float32), got.astype(np.float32),
+                       rtol=rtol, atol=atol, equal_nan=True)
+
+
 class TestPagedAttention:
-    def _pools(self, rng, p, hkv, ps, d, poison=True):
-        kp = jnp.asarray(rng.randn(p + 1, hkv, ps, d), jnp.float32)
-        vp = jnp.asarray(rng.randn(p + 1, hkv, ps, d), jnp.float32)
-        if poison:
-            # the trash page holds NaN garbage: the kernel must zero it
-            # exactly like the gather path's tmask (the full-capacity-
-            # neighbor regression class — 0 * NaN poisons softmax sums)
-            kp = kp.at[p].set(jnp.nan)
-            vp = vp.at[p].set(jnp.nan)
-        return kp, vp
+    # (B, H, Hkv, S, D, table slots): pages of 16, the shapes the cells
+    # run.  A row's context is drawn so that its last page is partial
+    # and the rest of its table trash.
+    SHAPES = {
+        # GPT-2 XL's heads: an odd count, the width padded 1,600 -> 1,664
+        "gpt2xl_decode": (3, 25, 25, 1, 64, 4),
+        "gpt2xl_verify": (2, 25, 25, 4, 64, 4),
+        "gpt2xl_prefill": (1, 25, 25, 64, 64, 8),
+        "gqa4_decode": (3, 8, 2, 1, 64, 4),
+        "gqa4_prefill": (1, 8, 2, 48, 64, 6),
+        # the latent pool: ONE head of 576 read by 32 query rows
+        "latent_decode": (2, 1, 1, 32, 576, 8),
+        "head128_verify": (2, 4, 4, 4, 128, 4),
+    }
 
-    def test_kernel_bit_parity_vs_gather(self, interpret_mode):
-        from bigdl_tpu.ops.attention import (expand_kv_heads,
-                                             paged_attention)
+    def _case(self, monkeypatch, shape, dtype):
+        """(q, K pool, V pool, tables, positions, scale, Hkv) of a
+        shape: NaN in the trash page, the rest of a row's table trash
+        and one slot inside the longest row's walk.
+        The scale is a Python float, as the layer's: a numpy scalar is
+        no weak type and would promote bf16 scores to float32."""
+        from bigdl_tpu.ops import attention as A
+        b, h, hkv, s, d, lp = self.SHAPES[shape]
+        if shape.endswith("prefill"):
+            # a bucket of 256 over a table of 1,024 has 26 MB of scores;
+            # this small one takes the same form under a smaller bound
+            monkeypatch.setattr(A, "_PAGED_BATCHED_SCORES", 64 * 1024)
+        ps, p = 16, b * lp
         rng = np.random.RandomState(1)
-        b, h, hkv, s, d, p, ps, lp = 3, 4, 2, 2, 8, 10, 4, 5
-        q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
-        kp, vp = self._pools(rng, p, hkv, ps, d)
+        kp, vp = (_pool(rng, p, hkv, ps, d, dtype) for _ in "kv")
+        q = jnp.asarray(rng.randn(b, h, s, d), dtype)
+        ctx = rng.randint(0, lp * ps - s, size=b)
+        # the longest: its last page partial, and the table's last slot
+        # trash where no shorter row has trash slots
+        ctx[0] = (lp - (b == 1)) * ps - s - 3
+        pos = ctx[:, None] + np.arange(s)[None]
         pages = np.full((b, lp), p, np.int32)
-        pages[0, :3] = [0, 1, 2]
-        pages[1, :2] = [3, 4]
-        pages[2, :5] = [5, 6, 7, 8, 9]
-        pages = jnp.asarray(pages)
-        pos = jnp.asarray([[9, 10], [4, 5], [17, 18]], jnp.int32)
-        scale = 1.0 / np.sqrt(d)
+        for i in range(b):
+            used = pos[i, -1] // ps + 1
+            pages[i, :used] = rng.permutation(p)[:used]
+        pages[0, 1] = p         # an unmapped hole INSIDE the longest walk
+        pages, pos = jnp.asarray(pages), jnp.asarray(pos, jnp.int32)
+        return q, kp, vp, pages, pos, 1.0 / math.sqrt(d), hkv
 
-        kk = kp[pages].transpose(0, 2, 1, 3, 4).reshape(b, hkv,
-                                                        lp * ps, d)
-        vv = vp[pages].transpose(0, 2, 1, 3, 4).reshape(b, hkv,
-                                                        lp * ps, d)
-        tmask = jnp.repeat(pages == p, ps, axis=1)[:, None, :, None]
-        kk = jnp.where(tmask, 0, kk)
-        vv = jnp.where(tmask, 0, vv)
-        kk, vv = expand_kv_heads(q, kk, vv)
-        scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
-        valid = (jnp.arange(lp * ps)[None, None, :] <= pos[:, :, None])
-        scores = jnp.where(valid[:, None], scores, -jnp.inf)
-        wts = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        want = jnp.einsum("bhsl,bhld->bhsd", wts.astype(vv.dtype), vv)
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("shape", sorted(SHAPES))
+    def test_kernel_bit_parity_vs_gather(self, interpret_mode, monkeypatch,
+                                         shape, dtype):
+        """The kernel against the gather path on the token-major pool,
+        NaN in the trash page, at the shapes that matter: bit for bit
+        against the gather path's math in products of the kernel's
+        shapes, and that within the summation order of `_gather`."""
+        from bigdl_tpu.ops import attention as A
+        args = self._case(monkeypatch, shape, dtype)
+        (_, h, s, d), hkv, lp = args[0].shape, args[-1], args[3].shape[1]
+        rows = A._paged_tiling(hkv, h // hkv, s, lp * 16, d, 16,
+                               jnp.dtype(dtype).itemsize)[2]
+        # few queries a head: every head a row of one product
+        assert rows == (shape.endswith(("decode", "verify"))
+                        and hkv > 1)
+        got = A.paged_attention(*args[:-1], num_kv_heads=hkv)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        ref = _shaped(*args)
+        assert _bits(ref, got)
+        assert _close(_gather(*args), ref)
 
-        got = paged_attention(q, kp, vp, pages, pos, scale)
-        assert np.isfinite(np.asarray(got)).all()
-        assert np.array_equal(np.asarray(want), np.asarray(got))
+    @pytest.mark.parametrize("fault", ["f32_scores", "trash_as_it_is"])
+    @pytest.mark.parametrize("shape", ["gpt2xl_decode", "gpt2xl_prefill",
+                                       "latent_decode"])
+    def test_bit_parity_sees_planted_fault(self, interpret_mode,
+                                           monkeypatch, shape, fault):
+        """What the gate above is for, in each of the kernel's forms on
+        a bf16 cache: the oracle with the fault planted is NOT what the
+        kernel returns — so a kernel with that fault fails the gate."""
+        from bigdl_tpu.ops import attention as A
+        args = self._case(monkeypatch, shape, "bfloat16")
+        got = A.paged_attention(*args[:-1], num_kv_heads=args[-1])
+        assert _bits(_shaped(*args), got)
+        planted = _shaped(*args, f32_scores=fault == "f32_scores",
+                          zero_trash=fault != "trash_as_it_is")
+        assert not _bits(planted, got)
+
+    @staticmethod
+    def _paths(monkeypatch, run):
+        """``run()`` through the kernel, through `_shaped` in the
+        kernel's place, and through the layer's own gather path."""
+        from bigdl_tpu.ops import attention as A
+        outs = {"kernel": run()}
+        with monkeypatch.context() as patch:
+            patch.setattr(A, "paged_attention", lambda *a, num_kv_heads:
+                          _shaped(*a, num_kv_heads))
+            outs["shaped"] = run()
+        monkeypatch.setenv("BIGDL_TPU_PAGED_ATTN", "0")
+        outs["gather"] = run()
+        return outs
 
     def test_kernel_bit_parity_bf16_cache(self, interpret_mode,
                                           monkeypatch):
@@ -484,34 +672,11 @@ class TestPagedAttention:
         pages = jnp.asarray(pages)
         pos = jnp.asarray([12, 4], jnp.int32)
         active = jnp.asarray([True, True])
-        outs = {}
-        for flag in ("1", "0"):
-            monkeypatch.setenv("BIGDL_TPU_PAGED_ATTN", flag)
-            y, _ = attn.apply_decode_pages(params, x, dict(cache),
-                                           pages, pos, active)
-            outs[flag] = np.asarray(y, np.float32)
-        assert np.isfinite(outs["1"]).all()
-        assert np.array_equal(outs["1"], outs["0"])
-
-    @staticmethod
-    def _gather(q, kp, vp, pages, pos, scale):
-        """The jnp gather path of ``apply_decode_pages``: the oracle."""
-        from bigdl_tpu.ops.attention import expand_kv_heads
-        b, hkv, ps, d = q.shape[0], kp.shape[1], kp.shape[2], q.shape[3]
-        lp, trash = pages.shape[1], kp.shape[0] - 1
-        kk = kp[pages].transpose(0, 2, 1, 3, 4).reshape(b, hkv,
-                                                        lp * ps, d)
-        vv = vp[pages].transpose(0, 2, 1, 3, 4).reshape(b, hkv,
-                                                        lp * ps, d)
-        tmask = jnp.repeat(pages == trash, ps, axis=1)[:, None, :, None]
-        kk = jnp.where(tmask, 0, kk)
-        vv = jnp.where(tmask, 0, vv)
-        kk, vv = expand_kv_heads(q, kk, vv)
-        scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
-        valid = (jnp.arange(lp * ps)[None, None, :] <= pos[:, :, None])
-        scores = jnp.where(valid[:, None], scores, -jnp.inf)
-        wts = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        return jnp.einsum("bhsl,bhld->bhsd", wts.astype(vv.dtype), vv)
+        outs = self._paths(monkeypatch, lambda: attn.apply_decode_pages(
+            params, x, dict(cache), pages, pos, active)[0])
+        assert np.isfinite(np.asarray(outs["kernel"], np.float32)).all()
+        assert _bits(outs["shaped"], outs["kernel"])
+        assert _close(outs["gather"], outs["shaped"])
 
     # first query position of each row, in the grid's order; tables of
     # 4 pages of 16.  A row's table maps the pages its queries reach.
@@ -531,47 +696,40 @@ class TestPagedAttention:
         "stale_nan": dict(ctx=[50, 2, 1], nan_after=0),
     }
 
-    # a lone query is scored for all heads of a step in one batched
-    # dot_general (the shape rule); the head-by-head form is a prefill
-    # bucket's, and XLA's CPU backend rounds a one-row product taken
-    # from a slice apart from the oracle's batched einsum, so only the
-    # chip's tolerance (chip_smoke) can gate that pairing
     @pytest.mark.parametrize("s,variant", [
-        (1, "one_group"), (1, "head_groups"), (5, "one_group"),
-        (5, "head_groups"), (5, "lone_heads"), (5, "head_loop")])
+        (1, "rows"), (1, "rows_lane_groups"), (5, "rows"),
+        (5, "rows_lane_groups"), (5, "chunks"), (5, "chunk_groups")])
     @pytest.mark.parametrize("case", sorted(WALKS))
     def test_walk_bit_parity_vs_gather(self, interpret_mode, monkeypatch,
-                                       case, s, variant):
-        """The bounded walk against the gather path, ``np.array_equal``:
-        GQA group 2 over an ODD number of KV heads, pages of 16, width
-        64, a decode step and a 5-token verify; every KV head in one
-        block, the heads split into two groups (a group's scratch then
-        follows its own row's, not the previous row's), one head a
-        group, and scored head by head."""
+                                   case, s, variant):
+        """The bounded walk against the gather path: GQA group 2 over
+        an ODD number of KV heads (three of 64 in a width of 256),
+        pages of 16, a decode step and a 5-token verify; every head a
+        row of one product over the whole width, the width split into
+        two lane groups (a group's scratch then follows its own row's,
+        not the previous row's), and chunk by chunk, head by head, in
+        one lane group and in two."""
         from bigdl_tpu.ops import attention as A
         hkv, d, ps, lp = 3, 64, 16, 4
-        groups = 1
-        limit = A._PAGED_VMEM_DECODE[1]
-        if variant == "head_groups":
-            hkv, groups = 6, 2
-            monkeypatch.setattr(A, "_PAGED_VMEM_DECODE", (
-                A._paged_vmem_bytes(3, 2, s, lp * ps, d, ps, 4, True),
-                limit))
-        elif variant == "lone_heads":
-            groups = 3
-            monkeypatch.setattr(A, "_PAGED_VMEM_DECODE", (0, limit))
-        elif variant == "head_loop":
+        limit = A._PAGED_VMEM[1]
+        groups = 2 if variant.endswith("groups") else 1
+        rows = variant.startswith("rows")
+        if not rows:
             monkeypatch.setattr(A, "_PAGED_BATCHED_SCORES", 0)
+        if groups == 2:
+            # room for one chunk (two heads of 64) a step, not for two
+            monkeypatch.setattr(A, "_PAGED_VMEM", (A._paged_step_bytes(
+                128, 2, 2, s, lp * ps, ps, 4, rows), limit))
         h = 2 * hkv
-        assert A._paged_plan(hkv, 2, s, lp * ps, d, ps, 4)[:2] == (
-            groups, variant in ("one_group", "head_groups"))
+        assert A._paged_tiling(hkv, 2, s, lp * ps, d, ps, 4) == (
+            groups, 256 // groups if rows else 128, rows, limit)
         spec = self.WALKS[case]
         ctx = np.asarray(spec["ctx"])
         b = len(ctx)
         rng = np.random.RandomState(3)
         p = b * lp
         q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
-        kp, vp = self._pools(rng, p, hkv, ps, d)
+        kp, vp = (_pool(rng, p, hkv, ps, d) for _ in "kv")
         pos = ctx[:, None] + np.arange(s)[None]
         pages = np.full((b, lp), p, np.int32)
         for i in range(b):
@@ -583,24 +741,28 @@ class TestPagedAttention:
             i = spec["nan_after"]
             page, off = divmod(int(pos[i, -1]) + 1, ps)
             assert 0 < off and page == pos[i, -1] // ps
-            vp = vp.at[pages[i, page], :, off:].set(jnp.nan)
+            vp = vp.at[pages[i, page], off:, :hkv * d].set(jnp.nan)
         pages, pos = jnp.asarray(pages), jnp.asarray(pos, jnp.int32)
-        scale = 1.0 / np.sqrt(d)
-        want = np.asarray(self._gather(q, kp, vp, pages, pos, scale))
-        got = np.asarray(A.paged_attention(q, kp, vp, pages, pos, scale))
+        args = q, kp, vp, pages, pos, 1.0 / math.sqrt(d), hkv
+        got = np.asarray(A.paged_attention(*args[:-1], num_kv_heads=hkv))
         clean = [i for i in range(b) if i != spec.get("nan_after")]
         assert np.isfinite(got[clean]).all()
         assert np.isnan(got).any() == ("nan_after" in spec)
-        assert np.array_equal(want, got, equal_nan=True)
+        ref = _shaped(*args)
+        assert _bits(ref, got)
+        assert _close(_gather(*args), ref)
 
     @pytest.mark.parametrize("s", [1, 256, 768])
-    def test_grid_has_no_head_axis(self, s):
-        """Structural guard at GPT-2 XL's shapes: a grid step holds a
-        page of every KV head of its group, so the grid is rows x head
-        groups x table slots and no axis has the heads' extent."""
+    def test_grid_moves_whole_pages(self, s):
+        """Structural guard at GPT-2 XL's shapes: a grid step moves ONE
+        contiguous page of the pool, 16 tokens x 1,664 lanes with every
+        KV head in it, so the grid is rows x 1 x table slots, the pool
+        reaches the kernel as it is and nothing has the heads' extent."""
         from bigdl_tpu.ops import attention as A
         b, h, d, ps, lp = (10 if s == 1 else 1), 25, 64, 16, 64
-        pool = jax.ShapeDtypeStruct((b * lp + 1, h, ps, d), jnp.bfloat16)
+        width = A.paged_pool_width(h, d)
+        assert width == 1664
+        pool = jax.ShapeDtypeStruct((b * lp + 1, ps, width), jnp.bfloat16)
         jaxpr = jax.make_jaxpr(
             lambda *a: A.paged_attention(*a, 0.125))(
             jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16), pool, pool,
@@ -609,21 +771,28 @@ class TestPagedAttention:
         calls = [e for e in jaxpr.jaxpr.eqns
                  if e.primitive.name == "pallas_call"]
         assert len(calls) == 1
-        grid = tuple(calls[0].params["grid_mapping"].grid)
-        groups, batched, limit = A._paged_plan(h, 1, s, lp * ps, d, ps, 2)
-        # a decode step holds every head and scores them at once; a
-        # prefill bucket splits them to stay lean
-        assert (groups, batched) == ((1, True) if s == 1 else (5, False))
-        assert limit == (A._PAGED_VMEM_DECODE if s == 1
-                         else A._PAGED_VMEM_PREFILL)[1]
-        assert grid == (b, groups, lp)
-        assert int(np.prod(grid)) <= b * lp * groups and h not in grid
+        # the pools go from the arguments into the call untouched
+        assert [v for v in calls[0].invars
+                if v in jaxpr.jaxpr.invars[1:3]] == jaxpr.jaxpr.invars[1:3]
+        mapping = calls[0].params["grid_mapping"]
+        groups, chunk, rows, limit = A._paged_tiling(h, 1, s, lp * ps, d,
+                                                     ps, 2)
+        # a decode step scores every head as a row over the whole
+        # width; a prefill bucket goes pair by pair of heads
+        assert (groups, chunk, rows) == ((1, width, True) if s == 1
+                                         else (1, 128, False))
+        assert limit == A._PAGED_VMEM[1]
+        assert tuple(mapping.grid) == (b, 1, lp)
+        blocks = [tuple(getattr(n, "block_size", n) for n in m.block_shape)
+                  for m in mapping.block_mappings]
+        assert blocks.count((1, ps, width)) == 2
+        assert h not in mapping.grid
 
     def test_decode_pages_kernel_on_off_bit_equal(self, interpret_mode,
                                                   monkeypatch):
         """The integration gate: TransformerLM.decode_pages (GQA +
-        rope) with the kernel vs the jnp gather path, bit for bit —
-        including rows whose tables hold trash entries."""
+        rope) with the kernel vs the jnp gather path — including rows
+        whose tables hold trash entries."""
         from bigdl_tpu.models.transformer import TransformerLM
         m = TransformerLM(vocab_size=64, max_len=64, embed_dim=32,
                           num_heads=4, num_kv_heads=2, num_layers=2,
@@ -639,17 +808,19 @@ class TestPagedAttention:
         pos = jnp.asarray([12, 4], jnp.int32)
         active = jnp.asarray([True, True])
 
-        outs = {}
-        for flag in ("1", "0"):
-            monkeypatch.setenv("BIGDL_TPU_PAGED_ATTN", flag)
+        def run():
             lp, new_cache = m.decode_pages(params, state, toks,
                                            [dict(c) for c in cache],
                                            pages, pos, active)
-            outs[flag] = (np.asarray(lp),
-                          [np.asarray(c["k"]) for c in new_cache])
-        assert np.array_equal(outs["1"][0], outs["0"][0])
-        for a, b in zip(outs["1"][1], outs["0"][1]):
-            assert np.array_equal(a, b)
+            return [lp] + [c["k"] for c in new_cache]
+
+        outs = self._paths(monkeypatch, run)
+        for kern, shaped, gather in zip(*(outs[k] for k in (
+                "kernel", "shaped", "gather"))):
+            assert _bits(shaped, kern)
+            assert _close(gather, shaped)
+        # the first layer's write does not pass through the read
+        assert _bits(outs["gather"][1], outs["kernel"][1])
 
     def test_generator_paged_kernel_end_to_end(self, interpret_mode):
         """ContinuousGenerator(paged_kernel=True) — the scan-of-
