@@ -92,7 +92,7 @@ def new_run(**kw) -> SimpleNamespace:
     run = SimpleNamespace(
         e2e={}, attempted=0, failed=0, correct=False, setup_s=None,
         window=None, trace=None, records=[], counters={}, samples={},
-        train=None)
+        train=None, compared={}, memory_peak_bytes=None)
     run.__dict__.update(kw)
     return run
 
@@ -156,10 +156,13 @@ def stop_ledger(run, path: Optional[str]) -> None:
 
 
 def result_line(run, metrics: dict, device: dict) -> str:
+    """The contract's one JSON object; ``compared``, each number that
+    decided ``correct`` as ``[value, limit]``, comes last."""
     out = {"correct": bool(run.correct), "attempted": int(run.attempted),
            "failed": int(run.failed), "metrics": metrics, "device": device}
     if getattr(run, "breakdown", None):
         out["breakdown"] = run.breakdown
+    out["compared"] = run.compared
     return json.dumps(out)
 
 

@@ -72,6 +72,8 @@ def forward_check(run, model, params, state, reference, cfg, seed):
     tol = cfg["tolerance"]
     ok = (np.isfinite(got).all() and err <= tol["logits_rel"]
           and abs(nll[0] - nll[1]) <= tol["nll_abs"])
+    run.compared.update(logits_rel=[err, tol["logits_rel"]],
+                        nll_abs=[abs(nll[0] - nll[1]), tol["nll_abs"]])
     harness.say(f"reference check: 8 images, max|logp - ref| / spread "
                      f"of ref = {err:.3e} (tolerance {tol['logits_rel']}), "
                      f"NLL {nll[0]:.5f} against {nll[1]:.5f} (tolerance "
@@ -154,6 +156,9 @@ def run(run) -> None:
     run.setup_s = trigger.start - run.t0
     run.attempted, run.failed = steps, skipped
     run.correct = correct and finite and skipped == 0 and moved > 0
+    run.compared.update(steps_skipped=[skipped, 0],
+                        losses_not_finite=[int(not finite), 0],
+                        weights_unmoved=[int(not moved > 0), 0])
     run.e2e["train_samples_per_s"] = rate
     run.train = {"samples_per_s": rate, "batch": batch, "chips": chips,
                  "steps": steps, "slice_steps": SLICE_STEPS,

@@ -274,24 +274,30 @@ def test_cost_functions_reproduce_the_issues_arithmetic():
         == pytest.approx(10 ** 6 * 6 * 2560 * 768 / 197e12)
 
 
-# -- the shared kernel's plan at the accepted cells' shapes ---------------------------
+# -- the shared kernel's tiling at the accepted cells' shapes ------------------------
 
-@pytest.mark.parametrize("queries,plan", [
-    (1, (1, True, 48 * 1024 * 1024)),       # decode: all 25 heads, batched
-    (256, (5, False, 24 * 1024 * 1024)),    # prefill buckets: 5 head groups
-    (512, (5, False, 24 * 1024 * 1024)),
-    (768, (5, False, 24 * 1024 * 1024))])
-def test_paged_plan_is_pinned_at_gpt2_xl_shapes(queries, plan):
-    """10 x 25 heads of 64, 64 table slots of 16 tokens, bf16: what the
-    accepted cells' programs were compiled with (PR 25).  A new shape rule
-    for another model must not move them."""
-    from bigdl_tpu.ops.attention import _paged_plan
-    assert _paged_plan(25, 1, queries, 64 * 16, 64, 16, 2) == plan
+VMEM = 48 * 1024 * 1024
 
 
-def test_paged_plan_takes_the_latent_pool_from_its_shapes():
-    """One KV head of width 576 read by 32 query rows over 256 table
-    slots: one head group, the per-head path, the decode call's VMEM."""
-    from bigdl_tpu.ops.attention import _paged_plan
-    assert _paged_plan(1, 1, 32, 256 * 16, 576, 16, 2) \
-        == (1, False, 48 * 1024 * 1024)
+@pytest.mark.parametrize("queries,tiling", [
+    (1, (1, 1664, True, VMEM)),     # decode: 26 head rows x the whole width
+    (256, (1, 128, False, VMEM)),   # prefill buckets: one lane group, by
+    (512, (1, 128, False, VMEM)),   # 128-lane chunks, head by head
+    (768, (1, 128, False, VMEM))])
+def test_paged_tiling_is_pinned_at_gpt2_xl_shapes(queries, tiling):
+    """25 heads of 64 on a pool 1,664 lanes wide, 64 table slots of 16
+    tokens, bf16: what the accepted cells' programs are compiled with
+    (PR 28; the slot count is no argument).  A new shape rule for another
+    model must not move them."""
+    from bigdl_tpu.ops.attention import _paged_tiling
+    assert _paged_tiling(25, 1, queries, 64 * 16, 64, 16, 2) == tiling
+
+
+def test_paged_tiling_takes_the_latent_pool_from_its_shapes():
+    """One KV head of width 576 (a pool 640 lanes wide) read by 32 query
+    rows over 256 table slots: one lane group, one chunk of the whole
+    width, a lone head is its own row (no rows form), the one VMEM
+    declaration."""
+    from bigdl_tpu.ops.attention import _paged_tiling
+    assert _paged_tiling(1, 1, 32, 256 * 16, 576, 16, 2) \
+        == (1, 640, False, VMEM)

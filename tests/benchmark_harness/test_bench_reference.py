@@ -68,3 +68,31 @@ def test_gpt2_reference_agrees_with_the_model_at_toy_widths():
     again = jax.nn.log_softmax(
         gpt2.logits_at(params, padded, np.arange(40), heads=4), axis=-1)
     assert float(jnp.abs(again - want).max()) < 1e-5
+
+
+def test_the_int8_control_is_seen_by_the_statistic_at_toy_widths(monkeypatch):
+    """``benchmark/control.py`` at a size a test can hold: the reference
+    with int8 weights puts another token first at some position and the
+    harness's statistic reads it above 0, which the same weights left as
+    they are read exactly (the chip's readings at GPT-2 XL's size against
+    the configuration's limit are in PERF.md)."""
+    from benchmark import control
+    from bigdl_tpu.models.transformer import TransformerLM
+    cfg = {"reference": "benchmark.reference.gpt2",
+           "model": {"args": [997], "kwargs": {"num_heads": 4}},
+           "server": {"max_len": 64}, "tolerance": {"rows": 32}}
+    model = TransformerLM(997, max_len=64, embed_dim=64, num_heads=4,
+                          num_layers=4, ffn_dim=128, position="learned")
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        jax.jit(model.init)(jax.random.PRNGKey(1))[0])
+    low = control.int8_weights(params)
+    w = params["blocks"][0]["fc1"]["weight"]
+    q = low["blocks"][0]["fc1"]["weight"]
+    assert q.dtype == w.dtype and not bool(jnp.array_equal(q, w))
+    levels = np.unique(np.asarray(q[0], np.float32))
+    assert len(levels) <= 255
+    assert bool(jnp.array_equal(low["ln_f"]["bias"], params["ln_f"]["bias"]))
+    assert control.reading(cfg, params, 1) > 0.005
+    monkeypatch.setattr(control, "int8_weights", lambda p: p)
+    assert control.reading(cfg, params, 1) == 0.0

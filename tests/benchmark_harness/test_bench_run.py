@@ -72,8 +72,12 @@ def test_closed_loop_runs_counts_whole_chunks_and_parks(tmp_path):
     assert run.counters["occupancy_pct"] == pytest.approx(100.0, abs=5.0)
     assert run.setup_s > 0 and not run.hard_exit
     line = json.loads(harness.result_line(run, {}, run.device))
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    # each number that decided `correct` beside its limit, last in the line
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    assert line["compared"]["logit_gap_std"][1] == 0.1
+    assert line["compared"]["shed"] == [0, 0]
+    assert run.memory_peak_bytes is None        # no TPU: nothing to read
 
 
 def test_open_loop_times_each_request_from_when_it_was_due(tmp_path):
@@ -87,5 +91,30 @@ def test_open_loop_times_each_request_from_when_it_was_due(tmp_path):
     assert run.e2e["ttft_p95_ms"] >= run.e2e["ttft_p50_ms"] > 0
     start, end = run.window
     assert end - start == pytest.approx(1.5)
-    # the five warm requests are sent and not counted
-    assert len(run.samples["sent"]) == run.attempted + 5
+    # the warm requests are sent and not counted
+    assert len(run.samples["sent"]) == run.attempted \
+        + run.cell.traffic["discard_requests"]
+
+
+@pytest.mark.parametrize("workload", ["gpt2_xl.chat_closed",
+                                      "gpt2_xl.score_open"])
+def test_a_token_altered_where_it_is_produced_reads_not_correct(
+        tmp_path, monkeypatch, workload):
+    """The rest of a run with the timed path broken underneath: the
+    program's head hands every program (prefill and decode chunk) the
+    logits moved by one place, so each served token is the neighbour of
+    the one the model puts first; the reference, which shares nothing
+    with the program, has to say so."""
+    import jax.numpy as jnp
+
+    from benchmark import serve_cell
+    from bigdl_tpu.models.transformer import TransformerLM
+    head = TransformerLM._head
+    monkeypatch.setattr(
+        TransformerLM, "_head",
+        lambda self, p, s, x: jnp.roll(head(self, p, s, x), 1, axis=-1))
+    run = _toy_serve_run(tmp_path, workload, 1.0)
+    serve_cell.run(run)
+    gap, limit = run.compared["logit_gap_std"]
+    assert not run.correct and gap > 3 * limit
+    assert run.failed == 0 and run.compared["shed"] == [0, 0]
