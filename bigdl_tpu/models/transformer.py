@@ -124,7 +124,7 @@ class TransformerBlock(Module):
             return x + h, new_state
 
     def _decode_block(self, params, state, x_t, attend):
-        """What the three incremental variants share: ``attend(attn
+        """What the two incremental variants share: ``attend(attn
         params, normed x)`` -> ``(attention output, cache')`` through
         whichever cache layout, then the FFN/MoE as in eval.  The
         ``attn`` and ``mlp`` scopes name the two halves in the device
@@ -154,24 +154,15 @@ class TransformerBlock(Module):
 
     def decode_step_pages(self, params, state, cache, x_t, pages, pos,
                           active):
-        """Page-table :meth:`decode_step_slots`: the per-row cache is an
-        indirection through ``pages`` (B, Lp) into a shared page pool —
-        the per-decode-step unit of the PAGED continuous-batching
-        scheduler."""
+        """Page-table :meth:`decode_step`: ``pos`` (B,) is each row's
+        own depth, ``active`` (B,) gates its write, and the per-row
+        cache is an indirection through ``pages`` (B, Lp) into a shared
+        page pool — the per-decode-step unit of the continuous-batching
+        scheduler (``serving/scheduler/continuous.py``)."""
         return self._decode_block(
             params, state, x_t,
             lambda p, h: self.attn.apply_decode_pages(p, h, cache, pages,
                                                       pos, active))
-
-    def decode_step_slots(self, params, state, cache, x_t, pos, active):
-        """Slot-addressable :meth:`decode_step`: ``pos`` (B,) is each
-        cache slot's own depth and ``active`` (B,) gates its cache
-        write — the per-decode-step unit of the continuous-batching
-        scheduler (``serving/scheduler/continuous.py``)."""
-        return self._decode_block(
-            params, state, x_t,
-            lambda p, h: self.attn.apply_decode_slots(p, h, cache, pos,
-                                                      active))
 
 
 class TransformerLM(Module):
@@ -364,39 +355,6 @@ class TransformerLM(Module):
                     pos)
         return self._head(params, state, x), new_cache
 
-    def decode_slots(self, params, state, tokens, cache, pos, active):
-        """Slot-addressable :meth:`decode`: every batch row is an
-        independent KV-cache SLOT at its own depth.  ``tokens`` (B, S)
-        1-based ids at positions ``[pos_b, pos_b + S)`` per row,
-        ``pos`` (B,) int32, ``active`` (B,) bool — inactive slots
-        compute garbage logits but never write their cache (the free
-        slot stays clean for the next admit).  Returns
-        (log-probs (B, S, vocab), cache').
-
-        Capacity contract mirrors :meth:`decode`: ``pos + S`` must stay
-        within the cache length and (for ``position="learned"``)
-        ``max_len``; all arguments may be traced, so the check lives in
-        the caller — the continuous-batching slot manager enforces it
-        eagerly at admit (typed ``SlotCapacityError``) and deactivates
-        slots in-graph before they can reach the bound.  An overrun row
-        here CLAMPS, like the scalar path: its per-row
-        ``dynamic_update_slice`` lands in the row's last slots
-        (corrupting that row's own cache tail) and its position-table
-        gather clamps — wrong output for that row; other rows' caches
-        are untouched (per-row writes never cross rows)."""
-        ids = jnp.asarray(tokens, jnp.int32) - 1
-        b, s = ids.shape
-        # per-row gather replaces decode()'s dynamic_slice: each slot
-        # reads the position table at its own depth
-        x = self._embed(params, ids, pos)
-        new_cache = list(cache)
-        for i, blk in enumerate(self.blocks):
-            with jax.named_scope(f"block_{i}"):
-                x, new_cache[i] = blk.decode_step_slots(
-                    params["blocks"][i], state["blocks"][i], cache[i], x,
-                    pos, active)
-        return self._head(params, state, x), new_cache
-
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32):
         """Per-layer block-paged KV pools for :meth:`decode_pages` —
@@ -407,17 +365,11 @@ class TransformerLM(Module):
         return [b.attn.init_paged_cache(num_pages, page_size, dtype)
                 for b in self.blocks]
 
-    def paged_heads(self):
-        """``(KV heads, head size)`` of each layer: what un-merges a
-        row of its page pool into the slot layout's per-head view
-        (``nn.attention.pages_view``)."""
-        return [(b.attn.num_kv_heads, b.attn.head_dim)
-                for b in self.blocks]
-
     def decode_pages(self, params, state, tokens, cache, pages, pos,
                      active):
-        """Page-table :meth:`decode_slots`: every batch row is a slot
-        whose cache positions live in the shared page pool at
+        """Page-table :meth:`decode`, the SERVED path: every batch row
+        is an independent slot at its own depth, whose cache positions
+        live in the shared page pool at
         ``pages[b, p // page_size]``.  ``tokens`` (B, S) 1-based ids at
         positions ``[pos_b, pos_b + S)``, ``pages`` (B, Lp) int32 page
         table, ``pos`` (B,), ``active`` (B,) — inactive rows and
@@ -426,8 +378,8 @@ class TransformerLM(Module):
         shared read-only prefix) owns.  Returns
         (log-probs (B, S, vocab), cache').
 
-        Capacity contract: unlike :meth:`decode_slots`, an over-table
-        position cannot corrupt a neighbor — it lands in trash — but
+        Capacity contract: unlike :meth:`decode`, an over-table
+        position cannot corrupt the cache — it lands in trash — but
         its READ view is garbage-masked only up to the table's mapped
         range, so the scheduler still bounds positions eagerly at admit
         (typed ``SlotCapacityError``) and deactivates rows in-graph."""

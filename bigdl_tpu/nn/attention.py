@@ -255,76 +255,6 @@ class MultiHeadAttention(Module):
                   params["bo"] if self.with_bias else None)
         return y, {"k": ck, "v": cv}
 
-    def apply_decode_slots(self, params, x_t, cache, pos, active):
-        """Slot-addressable incremental attention: every batch row is an
-        independent KV-cache SLOT at its own depth.  ``x_t`` (B, S, E)
-        holds each slot's next ``S`` tokens, ``pos`` (B,) each slot's
-        write position, ``active`` (B,) bool gates the cache write —
-        an inactive (free / finished) slot computes garbage but must
-        never mutate its cache, or an admit into that slot later would
-        inherit a corrupted prefix.
-
-        This is ``apply_decode`` with the scalar position generalised to
-        a vector: the write becomes a vmapped per-row
-        ``dynamic_update_slice`` (an inactive row writes its EXISTING
-        values back, so the update stays O(S) per row instead of an
-        O(L) one-hot scatter — measured 2x on the whole decode step)
-        and the causal-banded validity mask becomes per-row.  The
-        scalar path's overrun hazard (a position past the cache end
-        clamps into the last slot and corrupts it) exists here PER ROW,
-        which is why the continuous-batching slot manager enforces
-        capacity eagerly at admit and deactivates rows in-graph before
-        their position can reach the bound.  Returns
-        (y (B, S, E), cache')."""
-        bias = self.with_bias
-        q = _proj(x_t, params["wq"], params["bq"] if bias else None)
-        k = _proj(x_t, params["wk"], params["bk"] if bias else None)
-        v = _proj(x_t, params["wv"], params["bv"] if bias else None)
-        q = self._split(q)                          # (B, H, S, D)
-        k = self._split(k, self.num_kv_heads)       # (B, Hkv, S, D)
-        v = self._split(v, self.num_kv_heads)
-        s = q.shape[2]
-        # (B, S): each slot's tokens sit at [pos_b, pos_b + S)
-        positions = jnp.asarray(pos)[:, None] + jnp.arange(s)
-        if self.rope:
-            q = apply_rope(q, positions, self.rope_theta)
-            k = apply_rope(k, positions, self.rope_theta)
-        dt = cache["k"].dtype
-        length = cache["k"].shape[2]
-
-        # per-row cache write at each row's own depth: vmapped
-        # dynamic_update_slice with the row's position as a batched
-        # start index.  An inactive row writes its EXISTING values back
-        # (read-modify-write) — a no-op update instead of a masked
-        # scatter, so the per-step write cost stays O(S), not O(L)
-        def _write_row(c, new, p, a):
-            old = jax.lax.dynamic_slice(
-                c, (0, p, 0), (c.shape[0], new.shape[1], c.shape[2]))
-            return jax.lax.dynamic_update_slice(
-                c, jnp.where(a, new, old), (0, p, 0))
-
-        write = jax.vmap(_write_row)
-        act = jnp.asarray(active)
-        pos_v = jnp.asarray(pos)
-        with jax.named_scope("kv.write"):
-            ck = write(cache["k"], k.astype(dt), pos_v, act)
-            cv = write(cache["v"], v.astype(dt), pos_v, act)
-        from bigdl_tpu.ops.attention import expand_kv_heads
-        kk, vv = expand_kv_heads(q, ck, cv)         # (B, H, L, D)
-        scale = 1.0 / math.sqrt(self.head_dim)
-        scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
-        # per-row causal-banded validity: key slot l visible to row b's
-        # local token s iff l <= positions[b, s] (unwritten/garbage
-        # slots are beyond it, so the same predicate masks them)
-        valid = (jnp.arange(length)[None, None, :]
-                 <= positions[:, :, None])          # (B, S, L)
-        scores = jnp.where(valid[:, None], scores, -jnp.inf)
-        w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        o = jnp.einsum("bhsl,bhld->bhsd", w.astype(vv.dtype), vv)
-        y = _proj(self._merge(o), params["wo"],
-                  params["bo"] if self.with_bias else None)
-        return y, {"k": ck, "v": cv}
-
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32):
         """Block-paged KV cache for ``apply_decode_pages`` —
@@ -348,11 +278,15 @@ class MultiHeadAttention(Module):
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def apply_decode_pages(self, params, x_t, cache, pages, pos, active):
-        """Page-table incremental attention: ``apply_decode_slots``
-        with the per-slot cache row replaced by an indirection through
-        ``pages`` (B, Lp) int32 — logical page ``l`` of row ``b`` lives
-        in pool page ``pages[b, l]``.  ``x_t`` (B, S, E) at positions
-        ``[pos_b, pos_b + S)``; ``active`` (B,) gates writes.
+        """Page-table incremental attention: every batch row is an
+        independent SLOT at its own depth, its cache an indirection
+        through ``pages`` (B, Lp) int32 — logical page ``l`` of row
+        ``b`` lives in pool page ``pages[b, l]``.  ``x_t`` (B, S, E)
+        holds each slot's next ``S`` tokens at positions ``[pos_b,
+        pos_b + S)``; ``active`` (B,) gates writes: an inactive (free /
+        finished) slot computes garbage but must never mutate a page,
+        or an admit into that slot later would inherit a corrupted
+        prefix.
 
         Writes are a scatter of token rows ``(W,)`` at ``(pages[b, p //
         ps], p % ps)`` of the pool ``(P + 1, ps, W)``; an inactive row,
@@ -362,8 +296,8 @@ class MultiHeadAttention(Module):
         table.  Reads go through the paged-attention kernel where it is
         on, else gather the row's pages into a contiguous ``(B, H,
         Lp*ps, D)`` view (``pages_view``); garbage in trash-mapped
-        or unwritten pages is hidden by the same per-row validity
-        predicate as the slot path (``l <= positions``).  Shared
+        or unwritten pages is hidden by the per-row validity
+        predicate (``l <= positions``).  Shared
         read-only prefix pages are safe under this contract by
         construction: a reader's write positions start at the end of
         its shared prefix, so its scatter indices never land in a
@@ -405,27 +339,27 @@ class MultiHeadAttention(Module):
             # index map does the gather, so the contiguous (B, H, L, D)
             # view below never exists in HBM.  Same math operation for
             # operation (trash zeroing, validity mask, f32 softmax):
-            # parity with this gather path is regression-gated.
+            # parity with the gather path is regression-gated.
             with jax.named_scope("attn.paged"):
                 o = paged_attention(q, ck, cv, pages, positions, scale,
                                     num_kv_heads=self.num_kv_heads)
-            y = _proj(self._merge(o), params["wo"],
-                      params["bo"] if self.with_bias else None)
-            return y, {"k": ck, "v": cv}
-        # read: gather the row's pages into a contiguous (B, H, L, D)
-        # view (L = Lp * ps), trash-mapped positions zeroed — the jnp
-        # fallback path (non-Pallas backends) and the kernel's parity
-        # oracle
-        with jax.named_scope("attn.paged"):
-            kk = pages_view(ck, pages, self.num_kv_heads, self.head_dim)
-            vv = pages_view(cv, pages, self.num_kv_heads, self.head_dim)
-        kk, vv = expand_kv_heads(q, kk, vv)         # (B, H, L, D)
-        scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
-        valid = (jnp.arange(lp * ps)[None, None, :]
-                 <= positions[:, :, None])          # (B, S, L)
-        scores = jnp.where(valid[:, None], scores, -jnp.inf)
-        w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        o = jnp.einsum("bhsl,bhld->bhsd", w.astype(vv.dtype), vv)
+        else:
+            # gather the row's pages into a contiguous (B, H, L, D)
+            # view (L = Lp * ps), trash-mapped positions zeroed — the
+            # jnp fallback (non-Pallas backends) and the kernel's
+            # parity oracle
+            with jax.named_scope("attn.paged"):
+                kk = pages_view(ck, pages, self.num_kv_heads,
+                                self.head_dim)
+                vv = pages_view(cv, pages, self.num_kv_heads,
+                                self.head_dim)
+            kk, vv = expand_kv_heads(q, kk, vv)         # (B, H, L, D)
+            scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
+            valid = (jnp.arange(lp * ps)[None, None, :]
+                     <= positions[:, :, None])          # (B, S, L)
+            scores = jnp.where(valid[:, None], scores, -jnp.inf)
+            w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            o = jnp.einsum("bhsl,bhld->bhsd", w.astype(vv.dtype), vv)
         y = _proj(self._merge(o), params["wo"],
                   params["bo"] if self.with_bias else None)
         return y, {"k": ck, "v": cv}

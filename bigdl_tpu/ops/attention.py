@@ -775,7 +775,7 @@ def fused_attention(q, k, v, causal: bool = False, scale=None,
 # identical to `nn.MultiHeadAttention.apply_decode_pages`'s gather path
 # (zero trash pages, f32 scores, -inf validity mask, f32 softmax,
 # cache-dtype weighted sum), so the outputs are bit-parity-gated against
-# `decode_pages` in tests and the bench-serve ablation.
+# it in tests.
 #
 # The pool is TOKEN-MAJOR and LANE-DENSE: ``(P + 1, ps, W)``, a token's
 # K (or V) of every head one contiguous row of ``W`` lanes, KV head ``j``
@@ -787,11 +787,8 @@ def fused_attention(q, k, v, causal: bool = False, scale=None,
 
 def paged_attention_enabled() -> bool:
     """Dispatch gate for the paged-attention kernel: on wherever the
-    Pallas kernels are (TPU, or the test interpreter), killable with
-    ``BIGDL_TPU_PAGED_ATTN=0``.  Off means the jnp gather path — the
-    r11 behavior, also the ablation baseline."""
-    if os.environ.get("BIGDL_TPU_PAGED_ATTN") == "0":
-        return False
+    Pallas kernels are (TPU, or the test interpreter).  Off means the
+    layers' jnp gather path, which is also the kernel's oracle."""
     return _use_pallas()
 
 
@@ -1088,42 +1085,3 @@ def _paged_call(q, k_pool, v_pool, pages, positions, *, scale, hkv, tiling,
             .transpose(0, 1, 4, 2, 3, 5)
     return out.reshape(b, hp, group, s, dp)[:, :hkv, ..., :d] \
         .reshape(b, h, s, d)
-
-
-# DEAD CODE, kept for one test.  PR 25's plan for the pool it had,
-# (P + 1, Hkv, ps, D) with a page of every head a grid step: nothing in
-# the program calls it since the pool is token-major.  `_paged_tiling`
-# is the live planner, pinned at the cells' shapes by
-# tests/test_tuning.py (`test_grid_moves_whole_pages`).  These four
-# names stay, as they were, because
-# tests/benchmark_harness/test_bench_hybrid.py (`test_paged_plan_*`)
-# pins their answers and only a `benchmark` PR may edit that file: that
-# test NO LONGER describes what the accepted cells compile, and guards
-# nothing that runs.  Retire the test and these names together, and pin
-# `_paged_tiling` at the GPT-2 XL and latent shapes in their place
-# (PERF.md, section 7).
-_PAGED_VMEM_DECODE = (40 * 1024 * 1024, 48 * 1024 * 1024)
-_PAGED_VMEM_PREFILL = (20 * 1024 * 1024, 24 * 1024 * 1024)
-
-
-def _paged_vmem_bytes(heads, group, queries, length, d, ps, itemsize,
-                      batched):
-    lanes = -(-d // 128) * 128
-    sub = 8 * max(1, 4 // itemsize)
-    q_rows = group * (-(-queries // sub) * sub)
-    ps_rows = -(-ps // sub) * sub
-    blocks = heads * lanes * itemsize * (2 * length + 4 * q_rows
-                                         + 4 * ps_rows)
-    scores = _paged_scores_bytes(queries, length) * (heads if batched else 1)
-    return blocks + 4 * scores
-
-
-def _paged_plan(hkv, group, queries, length, d, ps, itemsize):
-    few = hkv * _paged_scores_bytes(queries, length) <= _PAGED_BATCHED_SCORES
-    budget, limit = _PAGED_VMEM_DECODE if few else _PAGED_VMEM_PREFILL
-    for groups in range(1, hkv + 1):
-        if hkv % groups == 0 and _paged_vmem_bytes(
-                hkv // groups, group, queries, length, d, ps, itemsize,
-                few) <= budget:
-            break
-    return groups, few and groups < hkv, limit

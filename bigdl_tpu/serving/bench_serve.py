@@ -4,7 +4,7 @@ Mixed-length generation traffic with a SHARED-SYSTEM-PROMPT head (the
 consumer mix: ``--prefix-frac`` of requests open with the same
 ``--prefix-len`` token head), served the same ways r8 measured —
 static waves, a bucketed ladder, and continuous batching — plus the
-r11 ablation ladder over the paged continuous scheduler
+r11 ablation ladder over the continuous scheduler
 (``python -m bigdl_tpu.cli bench-serve`` / ``bigdl-tpu-bench-serve``):
 
 * **static** — the fixed-shape baseline: waves of ``--batch`` requests
@@ -12,22 +12,18 @@ r11 ablation ladder over the paged continuous scheduler
   the GLOBAL maximum ``max_new`` for every wave.
 * **bucketed** — waves grouped by a ``max_new`` bucket ladder, one
   pre-compiled executable per rung.
-* **continuous (row_slot)** — the r8
+* **continuous** —
   :class:`~bigdl_tpu.serving.scheduler.continuous.ContinuousGenerator`
-  layout (``paged=False``): contiguous max-capacity cache rows, admit
-  per chunk, evict on finish.  This is the baseline the r11 features
-  must beat.
-* **ablations** — the same traffic through the paged scheduler with
-  each win toggled on in turn: ``paged`` (block-paged KV only),
-  ``paged_kernel`` (r14: decode scanned straight through
-  ``decode_pages`` so the Pallas paged-attention kernel serves the
-  read path — no materialised gathered view), ``paged_prefix``
-  (+ content-hash prefix cache — the shared head is prefilled once),
-  ``paged_prefix_spec`` (+ speculative decoding against a truncated
-  int8 draft).  Every ablation's outputs are asserted EQUAL to the
-  row-slot run's — the bench never reports a tokens/s number for wrong
-  tokens — and the prefix-hit and draft-accept rates land in the
-  artifact.
+  with ``prefix_cache=False``: block-paged KV, admit per chunk, evict
+  on finish, every prompt prefilled whole.  This is the baseline the
+  features below must beat.
+* **ablations** — the same traffic with each win toggled on in turn:
+  ``paged_prefix`` (+ content-hash prefix cache — the shared head is
+  prefilled once), ``paged_prefix_spec`` (+ speculative decoding
+  against a truncated int8 draft).  Every ablation's outputs are
+  asserted EQUAL to the baseline's — the bench never reports a
+  tokens/s number for wrong tokens — and the prefix-hit and
+  draft-accept rates land in the artifact.
 
 Useful tokens = sum of *requested* ``max_new`` over all requests; a
 mode's tokens/s divides that by ITS wall, so decode steps spent past a
@@ -141,14 +137,12 @@ def _run_continuous(gen, requests, useful_total: int, name: str,
     wall = time.monotonic() - t0
     st = gen.stats()
     extra = dict(mean_slot_occupancy=st["mean_occupancy"],
-                 decode_chunks=st["chunks"])
-    if st.get("paged"):
-        extra["mean_token_occupancy"] = \
-            st["pages"]["mean_token_occupancy"]
-        if st.get("prefix"):
-            extra["prefix_hit_rate"] = st["prefix"]["hit_rate"]
-            extra["prefix_shared_tokens"] = \
-                st["prefix"]["hit_pages"] * st["pages"]["page_size"]
+                 decode_chunks=st["chunks"],
+                 mean_token_occupancy=st["pages"]["mean_token_occupancy"])
+    if st.get("prefix"):
+        extra["prefix_hit_rate"] = st["prefix"]["hit_rate"]
+        extra["prefix_shared_tokens"] = \
+            st["prefix"]["hit_pages"] * st["pages"]["page_size"]
     if st.get("spec"):
         extra["draft_accept_rate"] = st["spec"]["accept_rate"]
     res = _mode_result(name, useful_total, wall, lats, **extra)
@@ -197,7 +191,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         "bench-serve",
         description="static vs bucketed vs continuous-batching generate, "
-                    "with paged / +prefix / +speculative ablations "
+                    "with +prefix / +speculative ablations "
                     "(docs/serving.md); writes BENCH_serve_r11.json")
     ap.add_argument("--requests", type=int, default=48)
     ap.add_argument("--batch", type=int, default=8,
@@ -319,21 +313,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                                            draft_layers)
 
     variants = [
-        ("continuous", dict(paged=False), True),
-        ("paged", dict(paged=True, page_size=args.page_size,
-                       prefix_cache=False), False),
-        # r14: scan decode_pages directly so the Pallas paged-attention
-        # kernel serves the read path (no materialised gathered view);
-        # on non-Pallas backends the same scan runs the jnp gather per
-        # step — either way the outputs must stay bit-equal to the
-        # row-slot baseline (the kernel's parity gate, ablated here)
-        ("paged_kernel", dict(paged=True, page_size=args.page_size,
-                              prefix_cache=False, paged_kernel=True),
-         False),
-        ("paged_prefix", dict(paged=True, page_size=args.page_size,
-                              prefix_cache=True), False),
-        ("paged_prefix_spec", dict(paged=True, page_size=args.page_size,
-                                   prefix_cache=True, draft_model=dm,
+        ("continuous", dict(prefix_cache=False), True),
+        ("paged_prefix", dict(prefix_cache=True), False),
+        ("paged_prefix_spec", dict(prefix_cache=True, draft_model=dm,
                                    draft_params=dparams,
                                    draft_state=dstate,
                                    draft_quantize="w8",
@@ -348,6 +330,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         gen = ContinuousGenerator(
             model, params, state, num_slots=args.batch, max_len=max_len,
             seq_buckets=seq_buckets, temperature=0.0,
+            page_size=args.page_size,
             steps_per_sync=args.steps_per_sync, warmup=True,
             queue_capacity=max(args.requests, 256), **kw)
         # live /metrics over the generator's counters — the bench
@@ -368,7 +351,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             live_ok = ok
             print(f"  live /metrics mid-traffic: "
                   f"{'OK' if ok else 'FAILED'}")
-        # correctness gate: every variant must produce the row-slot
+        # correctness gate: every variant must produce the baseline
         # run's exact tokens — no tokens/s number for wrong tokens
         if ref_outs is None:
             ref_outs = outs
@@ -377,7 +360,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 if not np.array_equal(a, b):
                     raise AssertionError(
                         f"{name}: request {i} output diverged from the "
-                        "row-slot baseline")
+                        "continuous baseline")
         results[name] = res
         rates = "".join(
             f"  {k.replace('_', ' ')} {res[k] * 100:.0f}%"
@@ -388,8 +371,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     continuous = results.pop("continuous")
     best_name = max(results, key=lambda k: results[k]["tokens_per_s"])
-    row = continuous["tokens_per_s"]
-    ratio = results[best_name]["tokens_per_s"] / row if row > 0 else 0.0
+    base = continuous["tokens_per_s"]
+    ratio = results[best_name]["tokens_per_s"] / base if base > 0 else 0.0
     out = {
         "bench": "serve_r11",
         "meta": {
@@ -415,18 +398,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         "ablations": results,
         "acceptance": {
             "best_ablation": best_name,
-            "best_vs_row_slot_tokens_per_s": ratio,
-            "per_feature_vs_row_slot": {
-                k: (v["tokens_per_s"] / row if row > 0 else 0.0)
+            "best_vs_continuous_tokens_per_s": ratio,
+            "per_feature_vs_continuous": {
+                k: (v["tokens_per_s"] / base if base > 0 else 0.0)
                 for k, v in results.items()},
-            # the kernel ablation's outputs are covered by the generic
-            # outputs_bit_equal_across_variants gate (a divergence
-            # raises before this artifact exists) — only its measured
-            # ratio is new information
-            "paged_kernel_vs_paged_tokens_per_s": (
-                results["paged_kernel"]["tokens_per_s"]
-                / results["paged"]["tokens_per_s"]
-                if results["paged"]["tokens_per_s"] > 0 else 0.0),
             "prefix_hit_rate":
                 results["paged_prefix"].get("prefix_hit_rate", 0.0),
             "draft_accept_rate":
@@ -440,7 +415,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(out, f, indent=2)
         f.write("\n")
-    print(f"  best ablation ({best_name}) vs row-slot continuous: "
+    print(f"  best ablation ({best_name}) vs continuous: "
           f"{ratio:.2f}x tokens/s "
           f"({'OK' if ratio > 1.0 else 'BELOW 1.0'}) -> {args.out}")
     return 0 if live_ok else 1

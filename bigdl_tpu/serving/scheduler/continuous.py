@@ -9,15 +9,15 @@ admission unit instead (the vLLM/Orca-style design, built directly on
 the existing ``TransformerLM`` decode stack so the math stays on
 device), in three compounding pieces:
 
-* **Block-paged KV** (``paged=True``, the default): the cache is a
+* **Block-paged KV**: the cache is a
   pool of fixed-size pages behind a free-list
   :class:`~.paging.PageAllocator`; a slot owns a *page list* (a
   host-side page table row), so **capacity is tokens actually held**,
   not ``num_slots x max_len`` rows provisioned.  A request that can
-  never fit the pool sheds typed (``SlotCapacityError``) exactly as
-  the row design shed over-length requests; one that merely cannot fit
-  *right now* is held back and placed when pages free up.
-* **Content-hash prefix cache** (``prefix_cache=True`` under paging):
+  never fit the pool sheds typed (``SlotCapacityError``); one that
+  merely cannot fit *right now* is held back and placed when pages
+  free up.
+* **Content-hash prefix cache** (``prefix_cache=True``, the default):
   full pages of a prompt are published refcounted + read-only under a
   chained token-content hash (:class:`~.paging.PrefixCache`), so a
   shared system prompt is prefilled ONCE and every later request
@@ -28,31 +28,37 @@ device), in three compounding pieces:
   allocated page; the shared page bytes are never touched.
 * **Speculative decoding** (``draft_model=...``): a small resident
   draft (PR 9's packed int8 trees make one nearly free to hold)
-  proposes ``spec_k`` tokens per chunk through its own slot cache; the
-  target model verifies all of them in ONE ``decode_pages`` pass and
+  proposes ``spec_k`` tokens per chunk through ``decode_pages`` on a
+  pool of its own (slot ``i`` owns pages ``i*Lp .. (i+1)*Lp - 1``: a
+  fixed table, no allocator); the target model verifies all of them
+  in ONE ``decode_pages`` pass and
   the host accepts the longest prefix that matches the target's own
   greedy picks, plus the target's correction token — so accepted
   output is exactly the target model's greedy path (the bit-equality
   PR 8 already proves), and a chunk emits up to ``spec_k + 1`` tokens
   for one target dispatch.
 
-The rest of the scheduler is unchanged from the row design: admit per
-decode chunk into free slot rows (prompt suffix padded to a
-:class:`~.buckets.BucketLadder` rung), evict on finish, per-chunk
-``serve.slots``/``serve.pages`` occupancy records, and EAGER capacity
-enforcement at ``submit()`` (the guard for ``TransformerLM.decode``'s
-documented clamp-and-corrupt overrun; under paging an overrun write is
-additionally redirected to the pool's trash page, so it cannot reach a
-neighbor's — or a shared prefix's — page even if the host bookkeeping
-were wrong).
+Around them: admit per decode chunk into free slots (prompt suffix
+padded to a :class:`~.buckets.BucketLadder` rung), evict on finish,
+per-chunk ``serve.slots``/``serve.pages`` occupancy records, and EAGER
+capacity enforcement at ``submit()`` (an overrun write is additionally
+redirected to the pool's trash page, so it cannot reach a neighbor's —
+or a shared prefix's — page even if the host bookkeeping were wrong).
+
+ONE cache layout and ONE decode program: the generator compiles a
+prefill per bucket and a ``lax.scan`` of ``model.decode_pages`` over
+``steps_per_sync`` steps.  Whether a read goes through the Pallas
+paged-attention kernel or gathers the row's pages is decided inside
+the attention layer from the backend it runs on
+(``ops.attention.paged_attention_enabled``), not here.  A model is
+servable when it has ``init_paged_cache`` and ``decode_pages``.
 
 Right-padded prefill is safe by construction, as before: garbage K/V
 beyond the real length is hidden by the validity predicate
 (``l <= pos``) and overwritten the step it first becomes visible.  The
 same argument covers speculative rejects: a rejected proposal's K/V
 sit at positions beyond the accepted frontier, invisible until the
-very chunk that overwrites them.  ``paged=False`` keeps the r8
-row-slot layout — the in-bench ablation baseline.
+very chunk that overwrites them.
 
 A model may DECLARE a second kind of state (``model.recurrent_state``: a
 linear-attention or state-space layer keeps a fixed-size state per SLOT,
@@ -242,7 +248,7 @@ class _Control:
 class SlotManager:
     """KV-cache slots as the admission unit: allocation, release, and
     the EAGER capacity check that keeps over-length requests out of the
-    decode loop entirely.  Under paging, ``pool_tokens`` adds the
+    decode loop entirely.  ``pool_tokens`` adds the
     token-pool bound: a request needing more cache tokens than the
     whole page pool holds can NEVER be placed and sheds typed."""
 
@@ -325,10 +331,8 @@ class ContinuousGenerator:
                  warmup: bool = True,
                  quantize: Optional[str] = None,
                  donate_cache: Optional[bool] = None,
-                 paged: bool = True,
                  page_size: int = 16,
                  num_pages: Optional[int] = None,
-                 paged_kernel: Optional[bool] = None,
                  prefix_cache: Optional[bool] = None,
                  draft_model=None,
                  draft_params=None,
@@ -382,17 +386,12 @@ class ContinuousGenerator:
         (rung executables), prefix-cache leaves, then idle-session
         parking — before holding back or shedding.
 
-        ``paged``/``page_size``/``num_pages``: block-paged KV (module
-        doc).  ``paged_kernel`` (r14): scan ``decode_pages`` directly so
-        the Pallas paged-attention kernel serves the read path (gather +
-        masked attention in one kernel, no materialised view); default
-        ``None`` follows the kernel's platform gate — off on plain CPU,
-        where the hoisted-gather chunk measures faster.  Greedy output
-        is bit-equal either way (ablated in bench-serve).  ``num_pages`` defaults to the row-equivalent pool
+        ``page_size``/``num_pages``: the page pool (module doc).
+        ``num_pages`` defaults to a full table for every slot
         (``num_slots * ceil(max_len / page_size)``); smaller pools make
-        capacity genuinely token-scarce.  ``prefix_cache`` (default: on
-        under paging) shares page-aligned prompt prefixes across
-        requests.  ``draft_model``/``draft_params``/``draft_state``/
+        capacity genuinely token-scarce.  ``prefix_cache`` (default: on)
+        shares page-aligned prompt prefixes across requests.
+        ``draft_model``/``draft_params``/``draft_state``/
         ``spec_k`` arm speculative decoding (greedy only; the draft
         must share the target's vocab); ``draft_quantize="w8"`` packs
         the draft int8 — the nearly-free-resident configuration."""
@@ -409,16 +408,14 @@ class ContinuousGenerator:
         # state, and counters its decode_pages returns
         self._recurrent = bool(getattr(model, "recurrent_state", False))
         self._counted = dict(getattr(model, "decode_counters", None) or {})
-        if self._recurrent and not paged:
-            raise ValueError("a model with recurrent state is served "
-                             "paged (its state is addressed by slot "
-                             "beside the page table)")
-        if self._recurrent and draft_model is not None:
+        if draft_model is not None and (
+                self._recurrent
+                or getattr(draft_model, "recurrent_state", False)):
             raise RecurrentStateError(
                 "speculative decoding rolls rejected proposals back by "
                 "position, which pages allow and a recurrent state does "
-                "not: the verify pass would leave the slot's state past "
-                "the accepted frontier")
+                "not: the draft's proposals and the verify pass would "
+                "leave the slot's state past the accepted frontier")
         qmode = quant.normalize_mode(quantize)
         if qmode is not None:
             if qmode not in ("w8", "w8a8", "w4", "f8"):
@@ -444,7 +441,7 @@ class ContinuousGenerator:
                            for p in prompts]
                 calib = quant.calibrate(model, self.params, self.state,
                                         batches)
-            # extra_keys=("tok",): decode/decode_slots fully support a
+            # extra_keys=("tok",): decode/decode_pages fully support a
             # packed tied embedding/head table (any r14 rung — the
             # gather and logit matmul dispatch on the leaf kind), and
             # it is the dominant residual tenant of a quantized LM —
@@ -488,60 +485,35 @@ class ContinuousGenerator:
                 jax.random.PRNGKey(0), max(int(steps_per_sync), 1))
 
         # -- paging ----------------------------------------------------------
-        self._paged = bool(paged)
-        prefix_declined = False
         n = int(num_slots)
-        if self._paged:
-            ps = int(page_size)
-            lp = -(-self.max_len // ps)          # page-table width
-            if num_pages is None:
-                num_pages = n * lp               # row-equivalent pool
-            self._alloc = PageAllocator(int(num_pages), ps)
-            if prefix_cache is None:
-                prefix_cache = True
-            # a shared page carries the prefix's keys, not the recurrent
-            # state after it: declined (counted below), not silently wrong
-            prefix_declined = bool(prefix_cache) and self._recurrent
-            self._prefix = PrefixCache(ps) \
-                if prefix_cache and not self._recurrent else None
-            self._lp = lp
-            self._page_table = np.full((n, lp), self._alloc.trash,
-                                       np.int32)
-            self._slot_priv: List[List[int]] = [[] for _ in range(n)]
-            self._slot_keys: List[List[str]] = [[] for _ in range(n)]
-            self._slot_shared = [0] * n      # shared-prefix tokens/slot
-            self._offload = HostOffloadTier()
-            self._sessions: "dict[str, Session]" = {}
-            pool_tokens = self._alloc.capacity_tokens
-        else:
-            if prefix_cache:
-                raise ValueError("prefix_cache requires paged=True "
-                                 "(shared pages need the page table)")
-            if draft_model is not None:
-                raise ValueError("speculative decoding requires "
-                                 "paged=True (the verify pass runs "
-                                 "through decode_pages)")
-            self._alloc = None
-            self._prefix = None
-            self._offload = None
-            self._sessions = {}
-            pool_tokens = None
-        if paged_kernel and not self._paged:
-            raise ValueError("paged_kernel requires paged=True (the "
-                             "kernel reads through the page table)")
-        if paged_kernel is None:
-            # auto: scan decode_pages directly wherever the Pallas
-            # paged-attention kernel serves the read path (TPU / the
-            # test interpreter) — there the per-step gather never
-            # materialises, so the r11 hoist buys nothing; elsewhere
-            # keep the hoisted-gather chunk (the measured CPU winner)
-            from bigdl_tpu.ops.attention import paged_attention_enabled
-            paged_kernel = self._paged and paged_attention_enabled()
-        self._paged_kernel = bool(paged_kernel)
+        ps = int(page_size)
+        lp = -(-self.max_len // ps)          # page-table width
+        if num_pages is None:
+            num_pages = n * lp               # a full table for every slot
+        self._alloc = PageAllocator(int(num_pages), ps)
+        if prefix_cache is None:
+            prefix_cache = True
+        # a shared page carries the prefix's keys, not the recurrent
+        # state after it: declined (counted below), not silently wrong
+        prefix_declined = bool(prefix_cache) and self._recurrent
+        self._prefix = PrefixCache(ps) \
+            if prefix_cache and not self._recurrent else None
+        self._lp = lp
+        self._page_table = np.full((n, lp), self._alloc.trash, np.int32)
+        self._slot_priv: List[List[int]] = [[] for _ in range(n)]
+        self._slot_keys: List[List[str]] = [[] for _ in range(n)]
+        self._slot_shared = [0] * n      # shared-prefix tokens/slot
+        self._offload = HostOffloadTier()
+        self._sessions: "dict[str, Session]" = {}
+        # do reads go through the paged-attention kernel?  The layer
+        # decides that from the backend; read once, for the spans'
+        # walk counters and stats()
+        from bigdl_tpu.ops.attention import paged_attention_enabled
+        self._kernel_reads = paged_attention_enabled()
         self._pending: Optional[GenRequest] = None
 
         self.slots = SlotManager(n, self.max_len, self.seq_ladder.max,
-                                 pool_tokens=pool_tokens)
+                                 pool_tokens=self._alloc.capacity_tokens)
 
         # -- speculative decoding --------------------------------------------
         self._draft = draft_model
@@ -574,8 +546,10 @@ class ContinuousGenerator:
                 quant.emit_param_bytes(self._draft_params,
                                        kind="ContinuousGenerator.draft",
                                        mode="w8")
-            self._dcache = self._draft.init_cache(n, self.max_len,
-                                                  self._cache_dtype)
+            # the draft's own pool behind a FIXED table: slot i owns
+            # pages i*Lp .. (i+1)*Lp - 1
+            self._dtable = jnp.arange(n * lp, dtype=jnp.int32).reshape(n, lp)
+            self._dcache = self._new_draft_cache()
         else:
             self._dcache = None
 
@@ -603,24 +577,17 @@ class ContinuousGenerator:
         # use can exceed the pool
         self._budget = budgeter
         self._bt = budget_tenant or self._tags.get("tenant", "default")
-        self._state_bytes = 0       # recurrent state of ONE slot
-        if self._paged:
-            self._cache = self._new_paged_cache()
-            # bytes of ONE page across every layer's pools
-            pools = self._cache["pages"] if self._recurrent \
-                else self._cache
-            self._page_bytes = _row_bytes(pools)
-            from bigdl_tpu.ops.attention import paged_pool_dims
-            import jax
-            # lanes of a token's row (padding included) in the first pool
-            self._pool_width = int(paged_pool_dims(
-                jax.tree_util.tree_leaves(pools)[0])[1])
-            if self._recurrent:
-                self._state_bytes = _row_bytes(self._cache["slots"])
-        else:
-            self._cache = model.init_cache(n, self.max_len,
-                                           self._cache_dtype)
-            self._page_bytes = 0
+        self._cache = self._new_paged_cache()
+        # bytes of ONE page across every layer's pools
+        pools = self._cache["pages"] if self._recurrent else self._cache
+        self._page_bytes = _row_bytes(pools)
+        from bigdl_tpu.ops.attention import paged_pool_dims
+        # lanes of a token's row (padding included) in the first pool
+        self._pool_width = int(paged_pool_dims(
+            jax.tree_util.tree_leaves(pools)[0])[1])
+        # recurrent state of ONE slot
+        self._state_bytes = _row_bytes(self._cache["slots"]) \
+            if self._recurrent else 0
         self._moe_pairs = 0
         self._moe_hit = 0
         self._chunks = 0
@@ -652,6 +619,12 @@ class ContinuousGenerator:
             self._alloc.num_pages, self._alloc.page_size,
             self._cache_dtype, **extra)
 
+    def _new_draft_cache(self):
+        """The draft's page pool: a full table row for every slot."""
+        return self._draft.init_paged_cache(
+            self.slots.num_slots * self._lp, self._alloc.page_size,
+            self._cache_dtype)
+
     # -- compiled programs ---------------------------------------------------
 
     def _build_programs(self) -> None:
@@ -661,8 +634,6 @@ class ContinuousGenerator:
         model = self.model
         temperature = self.temperature
         eos_id = self.eos_id
-        cache_len = self.max_len
-        cache_dtype = self._cache_dtype
 
         def pick(logp, key):
             with jax.named_scope("sample"):
@@ -685,264 +656,51 @@ class ContinuousGenerator:
             return {k: (jnp.max if counted[k] == "max" else jnp.sum)(v)
                     for k, v in counts.items()}
 
-        if self._paged:
-            def prefill_slot(params, state, tokens, ts, cache, pages, slot,
-                             key):
-                # a model with recurrent state: the prompt WHOLE (nothing
-                # below it is shared or retained), from position 0, where
-                # the model zeroes the state of `slot` in-graph; `ts`
-                # keeps the right-padding out of the state and selects
-                # the one row of log-probs that is computed
-                lp, cache, counts = decode_pages(
-                    params, state, tokens, cache, pages,
-                    jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool),
-                    slots=jnp.asarray(slot, jnp.int32)[None],
-                    lengths=jnp.asarray(ts, jnp.int32)[None])
-                return pick(lp[:, 0], key)[0], cache, counts
+        def prefill_slot(params, state, tokens, ts, cache, pages, slot,
+                         key):
+            # a model with recurrent state: the prompt WHOLE (nothing
+            # below it is shared or retained), from position 0, where
+            # the model zeroes the state of `slot` in-graph; `ts`
+            # keeps the right-padding out of the state and selects
+            # the one row of log-probs that is computed
+            lp, cache, counts = decode_pages(
+                params, state, tokens, cache, pages,
+                jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool),
+                slots=jnp.asarray(slot, jnp.int32)[None],
+                lengths=jnp.asarray(ts, jnp.int32)[None])
+            return pick(lp[:, 0], key)[0], cache, counts
 
-            def prefill(params, state, tokens, ts, cache, pages, start,
-                        key):
-                # tokens (1, Tb): the prompt SUFFIX beyond the shared
-                # prefix, right-padded to a seq rung; ts is its REAL
-                # length and start the shared-prefix depth in tokens
-                # (both traced, one executable per rung).  Writes land
-                # in the slot's own pages via the page table — shared
-                # prefix pages sit below `start` and are never indexed.
-                pos = jnp.asarray(start, jnp.int32)[None]
-                active = jnp.ones((1,), bool)
-                lp, cache, counts = decode_pages(params, state, tokens,
-                                                 cache, pages, pos, active)
-                last = jax.lax.dynamic_slice_in_dim(lp, ts - 1, 1,
-                                                    axis=1)[:, 0]
-                first = pick(last, key)[0]
-                return first, cache, counts
-
-            def step_chunk_kernel(params, state, tokens, cache, pages,
-                                  pos, active, limit, keys):
-                # r14 kernel mode (``paged_kernel=True``): scan
-                # ``decode_pages`` directly — the Pallas paged-
-                # attention kernel gathers pages and attends in one
-                # pass, so there is no materialised view to hoist and
-                # the per-step writes scatter straight into the pool.
-                # Outputs are bit-parity-gated against the hoisted
-                # chunk below (bench-serve ablation + tests).  A model
-                # with recurrent state always takes this form: its slot
-                # state is updated step by step beside the pool, and its
-                # attention layers gather through the table themselves
-                # where the kernel is off.
-                def one(carry, key):
-                    tok, cache, pos, active = carry
-                    lp, cache, counts = decode_pages(params, state,
-                                                     tok[:, None], cache,
-                                                     pages, pos, active)
-                    nxt = pick(lp[:, -1], key)
-                    nxt = jnp.where(active, nxt, tok)
-                    pos = jnp.where(active, pos + 1, pos)
-                    emitted = active
-                    active = jnp.logical_and(active, pos < limit)
-                    if eos_id is not None:
-                        active = jnp.logical_and(active, nxt != eos_id)
-                    return (nxt, cache, pos, active), (nxt, emitted, counts)
-
-                (tok, cache, pos, active), (toks, emitted, counts) = \
-                    jax.lax.scan(one, (tokens, cache, pos, active), keys)
-                return tok, cache, pos, active, toks, emitted, \
-                    reduce_counts(counts)
-
-            def step_chunk(params, state, tokens, cache, pages, pos,
-                           active, limit, keys):
-                # one scanned span of steps_per_sync decode steps over
-                # ALL slots; admit/evict happens host-side between
-                # chunks.  The paging indirection is hoisted OUT of the
-                # scan: each layer's pages are gathered into a
-                # contiguous per-slot working view once, the steps run
-                # through the same decode_slots math as the row layout
-                # (so per-step cost — and bits — match it exactly), and
-                # the views scatter back into the pool once at chunk
-                # end.  Trash-mapped positions are zeroed at gather
-                # (inert regardless of what was dumped there) and the
-                # write-back is value-stable under duplicate page ids:
-                # shared prefix pages are never written mid-chunk, so
-                # every row scatters back the identical bytes it
-                # gathered.
-                from bigdl_tpu.nn.attention import pages_rows, pages_view
-                from bigdl_tpu.ops.attention import paged_pool_dims
-                b, lp_w = pages.shape
-                psz, width = paged_pool_dims(cache[0]["k"])
-                trash = cache[0]["k"].shape[0] - 1
-                # the heads of the slot layout, which the pool's rows
-                # hold side by side: (Hkv, D) of every layer
-                heads = model.paged_heads()
-                # the chunk writes ONLY positions [pos, pos + steps)
-                # per row — at most `touch_n` logical pages — so the
-                # write-back scatters just those, not the whole table
-                # (inactive rows and out-of-table pages redirect to
-                # trash, the same containment as the in-step writes)
-                steps = keys.shape[0]
-                touch_n = (steps - 1) // psz + 2
-                touch = (pos // psz)[:, None] \
-                    + jnp.arange(touch_n)[None]             # (B, T)
-                phys_touch = jnp.take_along_axis(
-                    pages, jnp.clip(touch, 0, lp_w - 1), axis=1)
-                phys_touch = jnp.where(
-                    (touch >= lp_w) | ~active[:, None], trash,
-                    phys_touch)
-
-                def to_pool(pool, view):
-                    hkv, hd = view.shape[1], view.shape[3]
-                    v5 = view.reshape(b, hkv, lp_w, psz, hd)
-                    sel = jnp.take_along_axis(
-                        v5, jnp.clip(touch, 0, lp_w - 1)
-                        [:, None, :, None, None], axis=2)
-                    sel = pages_rows(sel.reshape(b, hkv, touch_n * psz,
-                                                 hd), width)
-                    return pool.at[phys_touch.reshape(-1)].set(
-                        sel.reshape(b * touch_n, psz, width))
-
-                views = [{"k": pages_view(l["k"], pages, *hd),
-                          "v": pages_view(l["v"], pages, *hd)}
-                         for l, hd in zip(cache, heads)]
-
-                def one(carry, key):
-                    tok, views, pos, active = carry
-                    lp, views = model.decode_slots(params, state,
-                                                   tok[:, None], views,
-                                                   pos, active)
-                    nxt = pick(lp[:, -1], key)
-                    nxt = jnp.where(active, nxt, tok)
-                    pos = jnp.where(active, pos + 1, pos)
-                    emitted = active
-                    active = jnp.logical_and(active, pos < limit)
-                    if eos_id is not None:
-                        active = jnp.logical_and(active, nxt != eos_id)
-                    return (nxt, views, pos, active), (nxt, emitted)
-
-                (tok, views, pos, active), (toks, emitted) = jax.lax.scan(
-                    one, (tokens, views, pos, active), keys)
-                cache = [{"k": to_pool(l["k"], v["k"]),
-                          "v": to_pool(l["v"], v["v"])}
-                         for l, v in zip(cache, views)]
-                return tok, cache, pos, active, toks, emitted, {}
-
-            self._prefill_fn = jax.jit(
-                prefill_slot if self._recurrent else prefill,
-                donate_argnums=(4,) if self._donate else ())
-            self._step_fn = jax.jit(
-                step_chunk_kernel if self._paged_kernel or self._recurrent
-                else step_chunk,
-                donate_argnums=(3,) if self._donate else ())
-
-            if self._draft is not None:
-                draft = self._draft
-                k = self.spec_k
-                dcap = self.max_len
-
-                def draft_prefill(dparams, dstate, prompt, dcache, slot):
-                    # the draft ingests the FULL prompt (its cache is a
-                    # cheap per-slot row; prefix pages are a target-side
-                    # economy) — local 1-row prefill scattered into the
-                    # slot's row, exactly the r8 row prefill shape
-                    lcache = draft.init_cache(1, dcap, cache_dtype)
-                    _, lcache = draft.decode(dparams, dstate, prompt,
-                                             lcache, 0)
-                    return [
-                        {"k": jax.lax.dynamic_update_slice(
-                             big["k"], small["k"], (slot, 0, 0, 0)),
-                         "v": jax.lax.dynamic_update_slice(
-                             big["v"], small["v"], (slot, 0, 0, 0))}
-                        for big, small in zip(dcache, lcache)]
-
-                def spec_chunk(params, state, dparams, dstate, cur,
-                               tcache, dcache, pages, pos, active):
-                    # 1. the draft proposes k tokens autoregressively
-                    # through its own slot cache (write-gated past its
-                    # capacity: a clamped draft write could only dent
-                    # the draft's OWN row and hence the accept rate,
-                    # never correctness — but gate it anyway)
-                    def dstep(carry, _):
-                        tok, dc, p = carry
-                        lp, dc = draft.decode_slots(
-                            dparams, dstate, tok[:, None], dc, p,
-                            jnp.logical_and(active, p < dcap))
-                        nxt = jnp.argmax(
-                            lp[:, -1], axis=-1).astype(jnp.int32) + 1
-                        nxt = jnp.where(active, nxt, tok)
-                        return (nxt, dc, p + 1), nxt
-
-                    # k+1 steps, k proposals used: the extra step
-                    # exists to WRITE d_k's K/V at pos+k, which a
-                    # full-accept round (pos advances by k+1) would
-                    # otherwise leave as a permanent zero hole in the
-                    # draft cache — every later proposal for the
-                    # request would attend a zero row at a valid
-                    # position and the accept rate would silently decay
-                    # (a self-draft must accept at exactly 1.0;
-                    # regression-tested at depth)
-                    (_, dcache, _), drafts = jax.lax.scan(
-                        dstep, (cur, dcache, pos), None, length=k + 1)
-                    drafts = jnp.transpose(drafts)[:, :k]   # (B, k)
-                    # 2. the target verifies cur + all k proposals in
-                    # ONE pass — ROW-EXPANDED: each verify token
-                    # becomes its own batch row at S=1, sharing the
-                    # slot's page table with per-row positions.  The
-                    # scatter lands before the gather inside
-                    # decode_pages, so row i reads rows < i's K/V
-                    # written this same pass (the layer-by-layer
-                    # dependency of sequential decode, satisfied
-                    # structurally); keeping S=1 keeps the per-token
-                    # float math the EXACT shape of the plain decode
-                    # path, so greedy[:, i] — the target's pick after
-                    # [prefix, cur, d_1..d_i] — is bit-identical to
-                    # what sequential decoding would produce (an
-                    # S=k+1 pass reduces in a different order and can
-                    # flip near-tie argmaxes)
-                    toks = jnp.concatenate([cur[:, None], drafts],
-                                           axis=1)           # (B, k+1)
-                    b = cur.shape[0]
-                    lp, tcache = model.decode_pages(
-                        params, state, toks.reshape(b * (k + 1), 1),
-                        tcache, jnp.repeat(pages, k + 1, axis=0),
-                        (pos[:, None] + jnp.arange(k + 1)).reshape(-1),
-                        jnp.repeat(active, k + 1))
-                    greedy = jnp.argmax(
-                        lp[:, 0], axis=-1).astype(jnp.int32) + 1
-                    greedy = greedy.reshape(b, k + 1)        # (B, k+1)
-                    return drafts, greedy, tcache, dcache
-
-                self._draft_prefill_fn = jax.jit(
-                    draft_prefill,
-                    donate_argnums=(3,) if self._donate else ())
-                self._spec_fn = jax.jit(
-                    spec_chunk,
-                    donate_argnums=(5, 6) if self._donate else ())
-            return
-
-        # -- legacy row-slot layout (paged=False): the r8 design -------------
-        def prefill(params, state, prompt, tp, cache, slot, key):
-            # prompt (1, Tb) right-padded to a seq rung; tp is the REAL
-            # length (traced, so one executable serves the whole rung)
-            lcache = model.init_cache(1, cache_len, cache_dtype)
-            lp, lcache = model.decode(params, state, prompt, lcache, 0)
-            last = jax.lax.dynamic_slice_in_dim(lp, tp - 1, 1,
+        def prefill(params, state, tokens, ts, cache, pages, start, key):
+            # tokens (1, Tb): the prompt SUFFIX beyond the shared
+            # prefix, right-padded to a seq rung; ts is its REAL
+            # length and start the shared-prefix depth in tokens
+            # (both traced, one executable per rung).  Writes land
+            # in the slot's own pages via the page table — shared
+            # prefix pages sit below `start` and are never indexed.
+            pos = jnp.asarray(start, jnp.int32)[None]
+            active = jnp.ones((1,), bool)
+            lp, cache, counts = decode_pages(params, state, tokens,
+                                             cache, pages, pos, active)
+            last = jax.lax.dynamic_slice_in_dim(lp, ts - 1, 1,
                                                 axis=1)[:, 0]
             first = pick(last, key)[0]
-            new_cache = [
-                {"k": jax.lax.dynamic_update_slice(
-                     big["k"], small["k"], (slot, 0, 0, 0)),
-                 "v": jax.lax.dynamic_update_slice(
-                     big["v"], small["v"], (slot, 0, 0, 0))}
-                for big, small in zip(cache, lcache)]
-            return first, new_cache, {}
+            return first, cache, counts
 
-        def step_chunk(params, state, tokens, cache, pos, active, limit,
-                       keys):
-            # one scanned span of steps_per_sync decode steps over ALL
-            # slots; admit/evict happens host-side between chunks
+        def step_chunk_kernel(params, state, tokens, cache, pages,
+                              pos, active, limit, keys):
+            # THE decode program: one scanned span of steps_per_sync
+            # ``decode_pages`` steps over ALL slots; admit/evict happens
+            # host-side between chunks.  Each step's writes scatter
+            # straight into the pool and its reads go through the page
+            # table (the Pallas kernel, or the layer's gather where
+            # there is none); a model with recurrent state updates its
+            # slot state step by step beside the pool.  (The name is
+            # what the trace readers find the program by.)
             def one(carry, key):
                 tok, cache, pos, active = carry
-                lp, cache = model.decode_slots(params, state,
-                                               tok[:, None], cache,
-                                               pos, active)
+                lp, cache, counts = decode_pages(params, state,
+                                                 tok[:, None], cache,
+                                                 pages, pos, active)
                 nxt = pick(lp[:, -1], key)
                 nxt = jnp.where(active, nxt, tok)
                 pos = jnp.where(active, pos + 1, pos)
@@ -950,11 +708,12 @@ class ContinuousGenerator:
                 active = jnp.logical_and(active, pos < limit)
                 if eos_id is not None:
                     active = jnp.logical_and(active, nxt != eos_id)
-                return (nxt, cache, pos, active), (nxt, emitted)
+                return (nxt, cache, pos, active), (nxt, emitted, counts)
 
-            (tok, cache, pos, active), (toks, emitted) = jax.lax.scan(
-                one, (tokens, cache, pos, active), keys)
-            return tok, cache, pos, active, toks, emitted, {}
+            (tok, cache, pos, active), (toks, emitted, counts) = \
+                jax.lax.scan(one, (tokens, cache, pos, active), keys)
+            return tok, cache, pos, active, toks, emitted, \
+                reduce_counts(counts)
 
         # cache donation: the live cache enters each program exactly
         # once and is immediately rebound to the program's output, so
@@ -964,9 +723,92 @@ class ContinuousGenerator:
         # the donated input is never touched again (graftlint:
         # use-after-donate)
         self._prefill_fn = jax.jit(
-            prefill, donate_argnums=(4,) if self._donate else ())
+            prefill_slot if self._recurrent else prefill,
+            donate_argnums=(4,) if self._donate else ())
         self._step_fn = jax.jit(
-            step_chunk, donate_argnums=(3,) if self._donate else ())
+            step_chunk_kernel,
+            donate_argnums=(3,) if self._donate else ())
+
+        if self._draft is None:
+            return
+        draft = self._draft
+        k = self.spec_k
+        dcap = self.max_len
+        dtable = self._dtable
+
+        def draft_prefill(dparams, dstate, prompt, dcache, slot):
+            # the draft ingests the FULL prompt from position 0 into
+            # the slot's own row of its table (prefix pages are a
+            # target-side economy)
+            row = jax.lax.dynamic_slice_in_dim(dtable, slot, 1, axis=0)
+            _, dcache = draft.decode_pages(
+                dparams, dstate, prompt, dcache, row,
+                jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool))
+            return dcache
+
+        def spec_chunk(params, state, dparams, dstate, cur,
+                       tcache, dcache, pages, pos, active):
+            # 1. the draft proposes k tokens autoregressively
+            # through its own pool (write-gated past its capacity;
+            # a position past the table goes to the trash page
+            # anyway, so an overrun could only dent the accept
+            # rate, never a neighbour's pages or correctness)
+            def dstep(carry, _):
+                tok, dc, p = carry
+                lp, dc = draft.decode_pages(
+                    dparams, dstate, tok[:, None], dc, dtable, p,
+                    jnp.logical_and(active, p < dcap))
+                nxt = jnp.argmax(
+                    lp[:, -1], axis=-1).astype(jnp.int32) + 1
+                nxt = jnp.where(active, nxt, tok)
+                return (nxt, dc, p + 1), nxt
+
+            # k+1 steps, k proposals used: the extra step
+            # exists to WRITE d_k's K/V at pos+k, which a
+            # full-accept round (pos advances by k+1) would
+            # otherwise leave as a permanent zero hole in the
+            # draft cache — every later proposal for the
+            # request would attend a zero row at a valid
+            # position and the accept rate would silently decay
+            # (a self-draft must accept at exactly 1.0;
+            # regression-tested at depth)
+            (_, dcache, _), drafts = jax.lax.scan(
+                dstep, (cur, dcache, pos), None, length=k + 1)
+            drafts = jnp.transpose(drafts)[:, :k]   # (B, k)
+            # 2. the target verifies cur + all k proposals in
+            # ONE pass — ROW-EXPANDED: each verify token
+            # becomes its own batch row at S=1, sharing the
+            # slot's page table with per-row positions.  The
+            # scatter lands before the gather inside
+            # decode_pages, so row i reads rows < i's K/V
+            # written this same pass (the layer-by-layer
+            # dependency of sequential decode, satisfied
+            # structurally); keeping S=1 keeps the per-token
+            # float math the EXACT shape of the plain decode
+            # path, so greedy[:, i] — the target's pick after
+            # [prefix, cur, d_1..d_i] — is bit-identical to
+            # what sequential decoding would produce (an
+            # S=k+1 pass reduces in a different order and can
+            # flip near-tie argmaxes)
+            toks = jnp.concatenate([cur[:, None], drafts],
+                                   axis=1)           # (B, k+1)
+            b = cur.shape[0]
+            lp, tcache = model.decode_pages(
+                params, state, toks.reshape(b * (k + 1), 1),
+                tcache, jnp.repeat(pages, k + 1, axis=0),
+                (pos[:, None] + jnp.arange(k + 1)).reshape(-1),
+                jnp.repeat(active, k + 1))
+            greedy = jnp.argmax(
+                lp[:, 0], axis=-1).astype(jnp.int32) + 1
+            greedy = greedy.reshape(b, k + 1)        # (B, k+1)
+            return drafts, greedy, tcache, dcache
+
+        self._draft_prefill_fn = jax.jit(
+            draft_prefill,
+            donate_argnums=(3,) if self._donate else ())
+        self._spec_fn = jax.jit(
+            spec_chunk,
+            donate_argnums=(5, 6) if self._donate else ())
 
     def _compile(self, name: str, fn, *args):
         """Compile ``fn`` for ``args`` ahead of its first call (which
@@ -990,33 +832,28 @@ class ContinuousGenerator:
         speculative chunk before the first request.  Without donation
         the outputs are discarded (the programs are pure, the live
         cache untouched); with donation the input cache is CONSUMED, so
-        every warmup call adopts the returned cache.  Paged warmup runs
+        every warmup call adopts the returned cache.  Warmup runs
         against an all-trash page table, so the dummy K/V never land in
-        an allocatable page at all; row-mode warmup relies on the
-        right-padding argument in the module doc."""
+        an allocatable page at all (the draft's land in slot 0's own
+        pages, hidden from its first tenant by the right-padding
+        argument in the module doc)."""
         import jax
         import jax.numpy as jnp
         with tracer.span("serve.warmup", buckets=list(self.seq_ladder),
-                         slots=self.slots.num_slots, paged=self._paged):
+                         slots=self.slots.num_slots):
             key = jax.random.PRNGKey(0)
             n = self.slots.num_slots
+            trash_row = jnp.full((1, self._lp), self._alloc.trash,
+                                 jnp.int32)
             for b in self.seq_ladder:
                 dummy = jnp.ones((1, b), jnp.int32)
-                if self._paged:
-                    trash_row = jnp.full((1, self._lp), self._alloc.trash,
-                                         jnp.int32)
-                    # (the 0 is the shared depth, or slot 0 of a model
-                    # with recurrent state, whose first real tenant
-                    # starts from zero whatever this leaves there)
-                    first, new_cache, _ = self._compile(
-                        f"prefill.{b}", self._prefill_fn,
-                        self.params, self.state, dummy, 1, self._cache,
-                        trash_row, 0, key)
-                else:
-                    first, new_cache, _ = self._compile(
-                        f"prefill.{b}", self._prefill_fn,
-                        self.params, self.state, dummy, 1, self._cache,
-                        0, key)
+                # (the 0 is the shared depth, or slot 0 of a model
+                # with recurrent state, whose first real tenant
+                # starts from zero whatever this leaves there)
+                first, new_cache, _ = self._compile(
+                    f"prefill.{b}", self._prefill_fn,
+                    self.params, self.state, dummy, 1, self._cache,
+                    trash_row, 0, key)
                 if self._donate:
                     self._cache = new_cache
                 np.asarray(first)
@@ -1027,28 +864,18 @@ class ContinuousGenerator:
                     if self._donate:
                         self._dcache = dcache
             keys = jax.random.split(key, self.steps_per_sync)
-            if self._paged:
-                table = jnp.asarray(self._page_table)
-                out = self._compile("step", self._step_fn,
-                                    self.params, self.state,
-                                    jnp.asarray(self._tokens),
-                                    self._cache, table,
-                                    jnp.asarray(self._pos),
-                                    jnp.asarray(self._active),
-                                    jnp.asarray(self._limit), keys)
-            else:
-                out = self._compile("step", self._step_fn,
-                                    self.params, self.state,
-                                    jnp.asarray(self._tokens),
-                                    self._cache,
-                                    jnp.asarray(self._pos),
-                                    jnp.asarray(self._active),
-                                    jnp.asarray(self._limit), keys)
+            table = jnp.asarray(self._page_table)
+            out = self._compile("step", self._step_fn,
+                                self.params, self.state,
+                                jnp.asarray(self._tokens),
+                                self._cache, table,
+                                jnp.asarray(self._pos),
+                                jnp.asarray(self._active),
+                                jnp.asarray(self._limit), keys)
             if self._donate:
                 self._cache = out[1]
             np.asarray(out[0])
             if self._draft is not None:
-                table = jnp.asarray(self._page_table)
                 spec = self._compile("spec", self._spec_fn,
                                      self.params, self.state,
                                      self._draft_params,
@@ -1136,7 +963,7 @@ class ContinuousGenerator:
             self.slots.check(p.size, max_new)
         except SlotCapacityError as e:
             self._shed(e)
-        if self._budget is not None and self._paged:
+        if self._budget is not None:
             need = self._alloc.pages_for(p.size + max_new - 1) \
                 * self._page_bytes + self._state_bytes
             try:
@@ -1157,14 +984,10 @@ class ContinuousGenerator:
         """The session half of :meth:`submit`: claim the session's
         turn latch, build the full logical prompt (history + new
         tokens) and run the capacity/budget guards against it."""
-        if not self._paged:
-            self._shed(InvalidRequestError(
-                "sessions require paged=True (KV retention is a "
-                "page-list swap)"))
         if self._draft is not None:
             self._shed(InvalidRequestError(
                 "sessions are not supported with speculative decoding "
-                "(the draft's row cache has no park/resume path)"))
+                "(the draft's pool has no park/resume path)"))
         if self._recurrent:
             self._shed(RecurrentStateError(
                 "a session keeps its PAGES between turns; the slot's "
@@ -1304,12 +1127,8 @@ class ContinuousGenerator:
                             steps_per_sync=self.steps_per_sync,
                             donate_cache=self._donate,
                             quantize=self.quantize,
-                            paged=self._paged,
-                            paged_kernel=self._paged_kernel,
-                            page_size=(self._alloc.page_size
-                                       if self._paged else None),
-                            num_pages=(self._alloc.num_pages
-                                       if self._paged else None),
+                            page_size=self._alloc.page_size,
+                            num_pages=self._alloc.num_pages,
                             prefix_cache=self._prefix is not None,
                             recurrent_state=self._recurrent,
                             speculative=self._draft is not None,
@@ -1350,36 +1169,30 @@ class ContinuousGenerator:
         continuing to pass the deleted arrays would fail every future
         request while the generator looked healthy — so the donating
         path rebuilds a fresh cache (the tenants' prefixes died with
-        the donated buffers; they were just failed typed anyway).  In
-        paged mode the prefix cache's pages died with the pool too, so
-        its entries are evicted wholesale back to the allocator."""
+        the donated buffers; they were just failed typed anyway).  The
+        prefix cache's pages died with the pool too, so its entries are
+        evicted wholesale back to the allocator."""
         for j, r in enumerate(self._requests):
             if r is not None:
                 self._evict(j, "failed")
         self._active[:] = False
         if self._donate:
-            if self._paged:
-                self._cache = self._new_paged_cache()
-                # every retained session's KV died with the donated
-                # pool (parked copies too — their shared heads are
-                # gone, a resume could not be bit-faithful): close
-                # them all, which also releases their prefix pins so
-                # the wholesale evict below can actually drain; the
-                # budget discharges ride along, keeping the budgeter
-                # exact through the crash path
-                for sid in list(self._sessions):
-                    self._destroy_session(self._sessions[sid])
-                if self._prefix is not None:
-                    freed = self._prefix.evict_for(self._alloc.num_pages,
-                                                   self._alloc)
-                    self._budget_sub("prefix_pages",
-                                     freed * self._page_bytes)
-            else:
-                self._cache = self.model.init_cache(
-                    self.slots.num_slots, self.max_len, self._cache_dtype)
+            self._cache = self._new_paged_cache()
+            # every retained session's KV died with the donated
+            # pool (parked copies too — their shared heads are
+            # gone, a resume could not be bit-faithful): close
+            # them all, which also releases their prefix pins so
+            # the wholesale evict below can actually drain; the
+            # budget discharges ride along, keeping the budgeter
+            # exact through the crash path
+            for sid in list(self._sessions):
+                self._destroy_session(self._sessions[sid])
+            if self._prefix is not None:
+                freed = self._prefix.evict_for(self._alloc.num_pages,
+                                               self._alloc)
+                self._budget_sub("prefix_pages", freed * self._page_bytes)
             if self._draft is not None:
-                self._dcache = self._draft.init_cache(
-                    self.slots.num_slots, self.max_len, self._cache_dtype)
+                self._dcache = self._new_draft_cache()
 
     def _admit(self) -> None:
         """Fill free slots from the queue — the per-decode-step admit.
@@ -1512,7 +1325,7 @@ class ContinuousGenerator:
     def _session_abort(self, req: GenRequest) -> None:
         """A turn died before retention (shed, cancel): release the
         session's turn latch, and drop a session that never built KV."""
-        if req.session is None or not self._paged:
+        if req.session is None:
             return
         with self._lock:
             sess = self._sessions.get(req.session)
@@ -1592,9 +1405,6 @@ class ContinuousGenerator:
             tl.t_admit = time.monotonic()
             self.metrics.observe("serve.gen.queue_wait_s",
                                  tl.t_admit - tl.t_submit)
-        if not self._paged:
-            self._place_row(req)
-            return True
 
         import jax
         import jax.numpy as jnp
@@ -1943,45 +1753,6 @@ class ContinuousGenerator:
         self._commit_placed(req, slot, tp, first, bucket)
         return True
 
-    def _place_row(self, req: GenRequest) -> None:
-        """The r8 row-slot placement (``paged=False``)."""
-        import jax
-        import jax.numpy as jnp
-
-        if not req.future.set_running_or_notify_cancel():
-            self.metrics.incr("serve.gen.cancelled")
-            self._emit_request(req, "cancelled")
-            return
-        slot = self.slots.alloc()
-        assert slot is not None, "placed with no free slot"
-        tp = int(req.prompt.size)
-        bucket = self.seq_ladder.pick(tp)
-        padded = np.ones((1, bucket), np.int32)
-        padded[0, :tp] = req.prompt
-        try:
-            prompt_dev = jnp.asarray(padded)
-            if self._greedy_keys is not None:
-                key = self._greedy_keys[0]
-            else:
-                self._rng, key = jax.random.split(self._rng)
-        except Exception as e:
-            self.slots.release(slot)
-            self._prefill_failed(req, e, consumed_cache=False)
-            return
-        try:
-            with tracer.span("serve.prefill", slot=slot, bucket=bucket,
-                             tp=tp, rid=req.rid):
-                first, self._cache, _ = self._prefill_fn(
-                    self.params, self.state, prompt_dev, tp,
-                    self._cache, slot, key)
-                first = int(np.asarray(first))
-                self._first_token(req)
-        except Exception as e:
-            self.slots.release(slot)
-            self._prefill_failed(req, e, consumed_cache=True)
-            return
-        self._commit_placed(req, slot, tp, first, bucket)
-
     def _first_token(self, req: GenRequest) -> None:
         """The host holds the request's first token: stamp it."""
         tl = req.timeline
@@ -2004,13 +1775,11 @@ class ContinuousGenerator:
         if not run_ledger.enabled():
             return {}
         act = self._active
-        out = {"ctx_tokens": int(self._pos[act].sum())}
-        if self._paged:
-            out["pages_mapped"] = int(
-                (self._page_table[act] != self._alloc.trash).sum())
-            out.update(self._walk_attrs(
-                self._pos[act][:, None] + np.arange(queries)))
-        return out
+        return {"ctx_tokens": int(self._pos[act].sum()),
+                "pages_mapped": int(
+                    (self._page_table[act] != self._alloc.trash).sum()),
+                **self._walk_attrs(
+                    self._pos[act][:, None] + np.arange(queries))}
 
     def _walk_attrs(self, last_visible) -> dict:
         """``pages_walked`` and ``pages_table`` of one call of the paged
@@ -2019,7 +1788,7 @@ class ContinuousGenerator:
         (``last_visible``, one position a row) are walked, the rest move
         no data.  Their running ratio is the gauge ``serve.paged walk
         share``."""
-        if not (self._paged_kernel and run_ledger.enabled()):
+        if not (self._kernel_reads and run_ledger.enabled()):
             return {}
         last = np.asarray(last_visible).reshape(-1) // self._alloc.page_size
         walked = int((np.clip(last, 0, self._lp - 1) + 1).sum())
@@ -2146,13 +1915,12 @@ class ContinuousGenerator:
                 keys = jax.random.split(key, self.steps_per_sync)
             # the mirrors go up in ONE call and the results come back in
             # one: each separate transfer is a round trip to the device
-            host = [self._tokens, self._pos, self._active, self._limit]
-            if self._paged:
-                host.append(self._page_table)
-            tokens, pos, active, limit, *table = jax.device_put(host)
+            tokens, pos, active, limit, table = jax.device_put(
+                [self._tokens, self._pos, self._active, self._limit,
+                 self._page_table])
             tok, self._cache, pos, active, toks, emitted, counts = \
                 self._step_fn(self.params, self.state, tokens, self._cache,
-                              *table, pos, active, limit, keys)
+                              table, pos, active, limit, keys)
             tok, pos, new_active, toks, emitted, counts = jax.device_get(
                 (tok, pos, active, toks, emitted, counts))
             # np.array (copy): what device_get returns may be a read-only
@@ -2252,36 +2020,34 @@ class ContinuousGenerator:
                         active=n_active, slots=self.slots.num_slots,
                         occupancy=occ, tokens=chunk_tokens,
                         **self._tags)
-        if self._paged:
-            # tokens actually held, counted ONCE: each slot's private
-            # positions (pos minus its shared head) plus each DISTINCT
-            # resident shared page — summing raw pos would count a
-            # shared prefix once per reader and overstate (even past
-            # 100%) under exactly the shared-head traffic paging is for
-            held = int(sum(int(self._pos[j]) - self._slot_shared[j]
-                           for j, r in enumerate(self._requests)
-                           if r is not None))
-            if self._prefix is not None:
-                held += self._prefix.held_pages * self._alloc.page_size
-            # idle RESIDENT sessions hold device tokens too (their
-            # private positions; the shared head is already counted
-            # through the prefix side)
-            held += int(sum(s.kv_pos - len(s.keys) * self._alloc.page_size
-                            for s in self._sessions.values()
-                            if s.state == "resident"))
-            cap = self._alloc.capacity_tokens
-            tocc = held / cap if cap else 0.0
-            self._token_occupancy_sum += tocc
-            self.metrics.set("serve.token occupancy", tocc,
-                             unit="scalar")
-            run_ledger.emit(
-                "serve.pages", chunk=self._chunks, tokens_held=held,
-                capacity_tokens=cap, token_occupancy=tocc,
-                pages_used=self._alloc.used_count,
-                pages_total=self._alloc.num_pages,
-                prefix_pages=(self._prefix.held_pages
-                              if self._prefix is not None else 0),
-                **self._pool_gauges(), **self._tags)
+        # tokens actually held, counted ONCE: each slot's private
+        # positions (pos minus its shared head) plus each DISTINCT
+        # resident shared page — summing raw pos would count a
+        # shared prefix once per reader and overstate (even past
+        # 100%) under exactly the shared-head traffic paging is for
+        held = int(sum(int(self._pos[j]) - self._slot_shared[j]
+                       for j, r in enumerate(self._requests)
+                       if r is not None))
+        if self._prefix is not None:
+            held += self._prefix.held_pages * self._alloc.page_size
+        # idle RESIDENT sessions hold device tokens too (their
+        # private positions; the shared head is already counted
+        # through the prefix side)
+        held += int(sum(s.kv_pos - len(s.keys) * self._alloc.page_size
+                        for s in self._sessions.values()
+                        if s.state == "resident"))
+        cap = self._alloc.capacity_tokens
+        tocc = held / cap if cap else 0.0
+        self._token_occupancy_sum += tocc
+        self.metrics.set("serve.token occupancy", tocc, unit="scalar")
+        run_ledger.emit(
+            "serve.pages", chunk=self._chunks, tokens_held=held,
+            capacity_tokens=cap, token_occupancy=tocc,
+            pages_used=self._alloc.used_count,
+            pages_total=self._alloc.num_pages,
+            prefix_pages=(self._prefix.held_pages
+                          if self._prefix is not None else 0),
+            **self._pool_gauges(), **self._tags)
 
     def _pool_gauges(self) -> dict:
         """What the pool's layout exists to remove, beside what it
@@ -2306,57 +2072,55 @@ class ContinuousGenerator:
         self._requests[slot] = None
         self._active[slot] = False
         self.slots.release(slot)
-        if self._paged:
-            self._budget_sub("slot_state", self._state_bytes)
-            sess = (self._sessions.get(req.session)
-                    if req.session is not None else None)
-            if sess is not None and status == "ok":
-                # session turn retired: RETAIN the KV up to kv_pos
-                # (cache holds positions 0..kv_pos-1; the final emitted
-                # token's KV was never written), trim the tail pages
-                # that only existed for max_new headroom.  The prefix
-                # pins move to the session so shared pages stay
-                # refcount-protected across idle/park.
-                kv_pos = int(self._pos[slot])
-                keep_n = self._alloc.pages_for(kv_pos)
-                nk = len(self._slot_keys[slot])
-                priv = self._slot_priv[slot]
-                keep = priv[:keep_n - nk]
-                tail = priv[keep_n - nk:]
-                if tail:
-                    self._alloc.free(tail)
-                    self._budget_sub("kv_pages",
-                                     len(tail) * self._page_bytes)
-                sess.tokens = req.prompt.tolist() + list(req.tokens)
-                sess.kv_pos = kv_pos
-                sess.row = np.array(self._page_table[slot][:keep_n])
-                sess.pages = keep
-                sess.keys = list(self._slot_keys[slot])
-                sess.state = "resident"
-                sess.last_used = time.monotonic()
+        self._budget_sub("slot_state", self._state_bytes)
+        sess = (self._sessions.get(req.session)
+                if req.session is not None else None)
+        if sess is not None and status == "ok":
+            # session turn retired: RETAIN the KV up to kv_pos
+            # (cache holds positions 0..kv_pos-1; the final emitted
+            # token's KV was never written), trim the tail pages
+            # that only existed for max_new headroom.  The prefix
+            # pins move to the session so shared pages stay
+            # refcount-protected across idle/park.
+            kv_pos = int(self._pos[slot])
+            keep_n = self._alloc.pages_for(kv_pos)
+            nk = len(self._slot_keys[slot])
+            priv = self._slot_priv[slot]
+            keep = priv[:keep_n - nk]
+            tail = priv[keep_n - nk:]
+            if tail:
+                self._alloc.free(tail)
+                self._budget_sub("kv_pages", len(tail) * self._page_bytes)
+            sess.tokens = req.prompt.tolist() + list(req.tokens)
+            sess.kv_pos = kv_pos
+            sess.row = np.array(self._page_table[slot][:keep_n])
+            sess.pages = keep
+            sess.keys = list(self._slot_keys[slot])
+            sess.state = "resident"
+            sess.last_used = time.monotonic()
+            with self._lock:
+                sess.busy = False
+        else:
+            if self._slot_keys[slot] and self._prefix is not None:
+                self._prefix.release(self._slot_keys[slot])
+            if self._slot_priv[slot]:
+                self._alloc.free(self._slot_priv[slot])
+                self._budget_sub(
+                    "kv_pages",
+                    len(self._slot_priv[slot]) * self._page_bytes)
+            if sess is not None:
+                # failed turn tears the session down with it — the
+                # retained KV past kv_pos is unrecoverable
                 with self._lock:
+                    self._sessions.pop(sess.sid, None)
                     sess.busy = False
-            else:
-                if self._slot_keys[slot] and self._prefix is not None:
-                    self._prefix.release(self._slot_keys[slot])
-                if self._slot_priv[slot]:
-                    self._alloc.free(self._slot_priv[slot])
-                    self._budget_sub(
-                        "kv_pages",
-                        len(self._slot_priv[slot]) * self._page_bytes)
-                if sess is not None:
-                    # failed turn tears the session down with it — the
-                    # retained KV past kv_pos is unrecoverable
-                    with self._lock:
-                        self._sessions.pop(sess.sid, None)
-                        sess.busy = False
-                    sess.pages = []
-                    sess.keys = []
-                    sess.state = "closed"
-            self._slot_keys[slot] = []
-            self._slot_priv[slot] = []
-            self._slot_shared[slot] = 0
-            self._page_table[slot, :] = self._alloc.trash
+                sess.pages = []
+                sess.keys = []
+                sess.state = "closed"
+        self._slot_keys[slot] = []
+        self._slot_priv[slot] = []
+        self._slot_shared[slot] = 0
+        self._page_table[slot, :] = self._alloc.trash
         if status == "ok":
             out = np.asarray(req.tokens[:req.max_new], np.int32)
             try:
@@ -2400,7 +2164,7 @@ class ContinuousGenerator:
                             if self._chunks else 0.0),
             mean_token_occupancy=(
                 self._token_occupancy_sum / self._chunks
-                if self._paged and self._chunks else None),
+                if self._chunks else None),
             prefix_hit_rate=(self._prefix.stats()["hit_rate"]
                              if self._prefix is not None else None),
             draft_accept_rate=(
@@ -2429,51 +2193,50 @@ class ContinuousGenerator:
             "tokens": self._emitted,
             "mean_occupancy": (self._occupancy_sum / self._chunks
                                if self._chunks else 0.0),
-            "paged": self._paged,
-            "paged_kernel": self._paged_kernel,
+            # facts, not options: benchmark/serve_cell.py indexes both
+            "paged": True,
+            "paged_kernel": self._kernel_reads,
         }
-        if self._paged:
-            out["pages"] = {
-                "page_size": self._alloc.page_size,
-                "total": self._alloc.num_pages,
-                "free": self._alloc.free_count,
-                "capacity_tokens": self._alloc.capacity_tokens,
-                "page_bytes": self._page_bytes,
-                "pool_bytes": self._alloc.num_pages * self._page_bytes,
-                **self._pool_gauges(),
-                "mean_token_occupancy": (
-                    self._token_occupancy_sum / self._chunks
-                    if self._chunks else 0.0),
-            }
-            if self._recurrent:
-                out["state"] = {
-                    "bytes_per_slot": self._state_bytes,
-                    "bytes": self.slots.num_slots * self._state_bytes}
-            out["prefix"] = (self._prefix.stats()
-                             if self._prefix is not None else None)
-            with self._lock:
-                sessions = list(self._sessions.values())
-            out["sessions"] = {
-                "open": len(sessions),
-                "active": sum(1 for s in sessions
-                              if s.state == "active"),
-                "resident": sum(1 for s in sessions
-                                if s.state == "resident"),
-                "parked": sum(1 for s in sessions
-                              if s.state == "parked"),
-                "device_tokens": int(sum(
-                    s.kv_pos for s in sessions
-                    if s.state in ("active", "resident"))),
-                "parked_tokens": int(sum(
-                    s.kv_pos for s in sessions
-                    if s.state == "parked")),
-                "total_tokens": int(sum(s.kv_pos for s in sessions)),
-            }
-            out["offload"] = (self._offload.stats()
-                              if self._offload is not None else None)
-            if self._budget is not None:
-                snap = self._budget.snapshot()
-                out["budget"] = snap["tenants"].get(self._bt)
+        out["pages"] = {
+            "page_size": self._alloc.page_size,
+            "total": self._alloc.num_pages,
+            "free": self._alloc.free_count,
+            "capacity_tokens": self._alloc.capacity_tokens,
+            "page_bytes": self._page_bytes,
+            "pool_bytes": self._alloc.num_pages * self._page_bytes,
+            **self._pool_gauges(),
+            "mean_token_occupancy": (
+                self._token_occupancy_sum / self._chunks
+                if self._chunks else 0.0),
+        }
+        if self._recurrent:
+            out["state"] = {
+                "bytes_per_slot": self._state_bytes,
+                "bytes": self.slots.num_slots * self._state_bytes}
+        out["prefix"] = (self._prefix.stats()
+                         if self._prefix is not None else None)
+        with self._lock:
+            sessions = list(self._sessions.values())
+        out["sessions"] = {
+            "open": len(sessions),
+            "active": sum(1 for s in sessions
+                          if s.state == "active"),
+            "resident": sum(1 for s in sessions
+                            if s.state == "resident"),
+            "parked": sum(1 for s in sessions
+                          if s.state == "parked"),
+            "device_tokens": int(sum(
+                s.kv_pos for s in sessions
+                if s.state in ("active", "resident"))),
+            "parked_tokens": int(sum(
+                s.kv_pos for s in sessions
+                if s.state == "parked")),
+            "total_tokens": int(sum(s.kv_pos for s in sessions)),
+        }
+        out["offload"] = self._offload.stats()
+        if self._budget is not None:
+            snap = self._budget.snapshot()
+            out["budget"] = snap["tenants"].get(self._bt)
         if self._draft is not None:
             out["spec"] = {
                 "k": self.spec_k,

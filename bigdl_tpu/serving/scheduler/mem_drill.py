@@ -91,7 +91,7 @@ def _phase_a(args, failures: List[str]) -> dict:
           f"{args.num_pages}-page pool (page_size={args.page_size})")
     with ContinuousGenerator(
             m, params, state, num_slots=2, seq_buckets=[16],
-            steps_per_sync=2, paged=True, page_size=args.page_size,
+            steps_per_sync=2, page_size=args.page_size,
             num_pages=args.num_pages, budgeter=budgeter,
             budget_tenant="a", ledger_tags={"tenant": "a"}) as g:
         pb = g.stats()["pages"]["page_bytes"]
@@ -195,7 +195,7 @@ def _victim_run(args, budgeted: bool) -> dict:
     budgeter = MemoryBudgeter() if budgeted else None
     with ContinuousGenerator(
             m, params, state, num_slots=2, seq_buckets=[16],
-            steps_per_sync=2, paged=True, page_size=args.page_size,
+            steps_per_sync=2, page_size=args.page_size,
             num_pages=args.num_pages, budgeter=budgeter,
             budget_tenant="noisy",
             ledger_tags={"tenant": "noisy"}) as g:

@@ -407,13 +407,51 @@ def test_an_inactive_rows_state_is_bit_equal_after_a_chunk(toy):
         gen.drain(timeout=60)
 
 
+@pytest.mark.parametrize("kind", ["transformer", "hybrid"])
+def test_one_decode_program(kind, toy):
+    """One cache layout and one decode program, whatever the model: after
+    warm-up the generator has compiled a prefill per bucket and ``step``,
+    the scan of ``decode_pages`` (the name the trace readers find it by),
+    on the CPU too; the two options that chose between layouts are
+    gone, the two ``stats()`` keys the benchmark reads are not."""
+    import re
+    if kind == "hybrid":
+        model, params, state = toy
+    else:
+        from bigdl_tpu.models.transformer import TransformerLM
+        model = TransformerLM(VOCAB, max_len=64, embed_dim=32, num_heads=2,
+                              num_layers=2)
+        params, state = model.init(jax.random.PRNGKey(0))
+    assert hasattr(model, "init_paged_cache") \
+        and hasattr(model, "decode_pages")
+    for gone in ("paged", "paged_kernel"):
+        with pytest.raises(TypeError, match=gone):
+            _generator(model, params, state, warmup=False, **{gone: True})
+    gen = _generator(model, params, state, steps_per_sync=2)
+    try:
+        st = gen.stats()
+        assert set(st["pages"]["program_temp_bytes"]) == {
+            "prefill.16", "prefill.32", "step"}
+        assert st["paged"] is True and isinstance(st["paged_kernel"], bool)
+        assert not st["paged_kernel"]       # plain CPU: the gather reads
+        lowered = gen._step_fn.lower(
+            params, state, jnp.asarray(gen._tokens), gen._cache,
+            jnp.asarray(gen._page_table), jnp.asarray(gen._pos),
+            jnp.asarray(gen._active), jnp.asarray(gen._limit),
+            jax.random.split(jax.random.PRNGKey(0), 2))
+        name = re.search(r"module @(\S+)", lowered.as_text()).group(1)
+        assert "step_chunk" in name
+        out = gen.generate([np.arange(1, 8)], 6)[0]
+        assert out.shape == (6,)
+    finally:
+        gen.drain(timeout=60)
+
+
 def test_what_moves_pages_only_is_declined_or_refused_typed(toy):
     model, params, state = toy
     with pytest.raises(RecurrentStateError):
         _generator(model, params, state, warmup=False, draft_model=model,
                    draft_params=params, draft_state=state)
-    with pytest.raises(ValueError, match="paged"):
-        _generator(model, params, state, warmup=False, paged=False)
     gen = _generator(model, params, state, warmup=False, prefix_cache=True)
     try:
         st = gen.stats()

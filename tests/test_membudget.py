@@ -51,7 +51,6 @@ def _gen(m, params, state, **kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("seq_buckets", [16])
     kw.setdefault("steps_per_sync", 2)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 4)
     return ContinuousGenerator(m, params, state, **kw)
 

@@ -440,6 +440,77 @@ def test_speculative_full_capacity_request_cannot_poison_neighbors():
         np.testing.assert_array_equal(r, o)
 
 
+def test_speculative_draft_past_its_capacity_stays_in_its_own_pages():
+    """The draft is served through ``decode_pages`` on a pool of its own
+    behind a fixed table (slot ``i`` owns pages ``i*Lp .. (i+1)*Lp-1``).
+    A request that ends at the cache boundary drives the draft's k + 1
+    proposal steps PAST its capacity (``p >= dcap``): those writes are
+    gated to the trash page, so the output is still the plain
+    generator's and the pages of the slot beside it, which never held a
+    request, hold what they held before."""
+    m, params, state = _lm(max_len=32, layers=1)
+    rs = np.random.RandomState(14)
+    full = rs.randint(1, 65, size=6).astype(np.int32)    # 6 + 26 = 32
+    with ContinuousGenerator(m, params, state, num_slots=2, max_len=32,
+                             page_size=8, seq_buckets=[8]) as g:
+        plain = g.submit(full, 26).result(timeout=120)
+    with ContinuousGenerator(m, params, state, num_slots=2, max_len=32,
+                             page_size=8, seq_buckets=[8],
+                             draft_model=m, draft_params=params,
+                             draft_state=state, spec_k=4) as g:
+        lp = g._lp
+        np.testing.assert_array_equal(
+            np.asarray(g._dtable), np.arange(2 * lp).reshape(2, lp))
+        assert g._dcache[0]["k"].shape[0] == 2 * lp + 1   # + trash
+        before = [np.asarray(l[kv]) for l in g._dcache for kv in "kv"]
+        out = g.submit(full, 26).result(timeout=120)
+        spec = g.stats()["spec"]
+        after = [np.asarray(l[kv]) for l in g._dcache for kv in "kv"]
+    np.testing.assert_array_equal(plain, out)
+    np.testing.assert_array_equal(
+        plain, _refs(m, params, state, [full], [26])[0])
+    # the last rounds proposed at positions 31 + 1 .. 31 + 4 >= dcap
+    assert spec["proposed"] >= 4 and spec["accept_rate"] == 1.0
+    for b4, af in zip(before, after):
+        assert not np.array_equal(b4[:lp], af[:lp])      # slot 0 served
+        np.testing.assert_array_equal(b4[lp:2 * lp], af[lp:2 * lp])
+
+
+def test_speculative_recovers_both_pools_after_a_failed_round():
+    """Under donation a failed speculative round may have consumed the
+    target's pool AND the draft's: both are rebuilt in the paged layout
+    (the draft's behind the same fixed table), the tenants fail typed,
+    and the next request is served bit-equal to ``generate()``."""
+    m, params, state = _lm(max_len=32, layers=1)
+    prompt = np.arange(3, 9).astype(np.int32)
+    g = ContinuousGenerator(m, params, state, num_slots=2, max_len=32,
+                            page_size=8, seq_buckets=[8], draft_model=m,
+                            draft_params=params, draft_state=state,
+                            spec_k=3, donate_cache=True)
+    spec, failed = g._spec_fn, []
+
+    def flaky(*a):
+        if not failed:
+            failed.append(True)
+            raise RuntimeError("injected speculative failure")
+        return spec(*a)
+
+    g._spec_fn = flaky
+    fresh, rebuilt = g._new_draft_cache, []
+    g._new_draft_cache = lambda: rebuilt.append(fresh()) or rebuilt[-1]
+    try:
+        with pytest.raises(RuntimeError, match="generation failed"):
+            g.submit(prompt, 10).result(timeout=120)
+        out = g.submit(prompt, 10).result(timeout=120)
+    finally:
+        g.drain(timeout=60)
+    assert len(rebuilt) == 1            # once, by the scheduler's recovery
+    assert rebuilt[0][0]["k"].shape == (2 * g._lp + 1, 8, 128)
+    assert g.stats()["pages"]["free"] == g.stats()["pages"]["total"]
+    np.testing.assert_array_equal(
+        out, _refs(m, params, state, [prompt], [10])[0])
+
+
 def test_speculative_validation():
     m, params, state = _lm(layers=1)
     dm, dparams, dstate = _truncated(m, params, state)
@@ -452,21 +523,22 @@ def test_speculative_validation():
     with pytest.raises(ValueError, match="vocab"):
         ContinuousGenerator(m, params, state, draft_model=bad,
                             warmup=False)
-    with pytest.raises(ValueError, match="paged=True"):
-        ContinuousGenerator(m, params, state, paged=False,
-                            draft_model=dm, draft_params=dparams,
-                            draft_state=dstate, warmup=False)
-    with pytest.raises(ValueError, match="paged=True"):
-        ContinuousGenerator(m, params, state, paged=False,
-                            prefix_cache=True, warmup=False)
+    with pytest.raises(ValueError, match="spec_k"):
+        ContinuousGenerator(m, params, state, draft_model=dm,
+                            draft_params=dparams, draft_state=dstate,
+                            spec_k=0, warmup=False)
 
 
 # -- decode_pages unit parity -------------------------------------------------
 
-def test_decode_pages_matches_decode_slots():
-    """Same tokens through the paged and slot paths: logits match and
-    an inactive row's pages stay untouched (the write-redirect-to-trash
-    contract)."""
+@pytest.mark.parametrize("depths", [(7, 7, 7), (3, 7, 5)],
+                         ids=["same_depth", "own_depths"])
+def test_decode_pages_matches_decode(depths):
+    """Same tokens through the paged path and the scalar ``decode`` (the
+    offline path, the oracle): a prefill's logits match, then a step
+    with every row at ITS OWN depth matches that row's own scalar
+    decode (values, not just argmax), and an inactive row's pages stay
+    untouched (the write-redirect-to-trash contract)."""
     m, params, state = _lm(layers=1, max_len=32)
     rs = np.random.RandomState(10)
     b, tp, ps = 3, 7, 4
@@ -484,6 +556,21 @@ def test_decode_pages_matches_decode_slots():
                                atol=1e-5, rtol=1e-5)
     np.testing.assert_array_equal(np.argmax(np.asarray(lp_ref), -1),
                                   np.argmax(np.asarray(lp_pg), -1))
+    # one step, row r at depth d_r: what lies at and beyond d_r in its
+    # pages (the rest of the prompt) is overwritten or masked, so the
+    # row equals a scalar decode that only ever saw its first d_r tokens
+    nxt = rs.randint(1, 65, size=(b, 1)).astype(np.int32)
+    lp_step, _ = m.decode_pages(params, state, nxt, pcache,
+                                jnp.asarray(pages),
+                                jnp.asarray(depths, jnp.int32),
+                                jnp.ones(b, bool))
+    for r, d in enumerate(depths):
+        _, c1 = m.decode(params, state, prompt[r:r + 1, :d],
+                         m.init_cache(1, 32), 0)
+        want, _ = m.decode(params, state, nxt[r:r + 1], c1, d)
+        np.testing.assert_allclose(np.asarray(want)[0],
+                                   np.asarray(lp_step)[r],
+                                   atol=1e-5, rtol=1e-5)
     # an INACTIVE row's pages must stay untouched; the write redirects
     # to the trash page
     tok = prompt[:, :1]
@@ -690,7 +777,7 @@ def test_programs_meet_the_pool_only_at_the_scatter_and_the_kernel(
     m, params, state = _lm(embed=48, heads=3, layers=2, max_len=64)
     g = ContinuousGenerator(m, params, state, num_slots=2, page_size=4,
                             seq_buckets=[16], steps_per_sync=2,
-                            warmup=False, paged_kernel=True)
+                            warmup=False)
     try:
         pool = g._cache[0]["k"]
         assert pool.shape == (2 * 16 + 1, 4, 128)
@@ -815,7 +902,7 @@ def test_paged_ledger_records_and_report(tmp_path):
     records, bad = load_ledger(run_dir, strict=True)
     assert bad == 0
     start = next(r for r in records if r.get("type") == "run.start")
-    assert start["paged"] and start["prefix_cache"] \
+    assert start["page_size"] == 8 and start["prefix_cache"] \
         and start["speculative"] and start["spec_k"] == 3
     pages = [r for r in records if r.get("type") == "serve.pages"]
     assert pages and all(0 <= p["token_occupancy"] <= 1 for p in pages)
@@ -844,20 +931,3 @@ def test_paged_ledger_records_and_report(tmp_path):
     txt = render_report(build_report(records))
     assert "prefix cache:" in txt and "speculative:" in txt
     assert "TOKEN occupancy" in txt
-
-
-def test_row_slot_mode_still_serves():
-    """paged=False keeps the r8 row-slot layout exactly — the ablation
-    baseline stays available and bit-equal."""
-    m, params, state = _lm(max_len=64, layers=1)
-    rs = np.random.RandomState(12)
-    prompts = [rs.randint(1, 65, size=6).astype(np.int32)
-               for _ in range(4)]
-    refs = _refs(m, params, state, prompts, [6] * 4)
-    with ContinuousGenerator(m, params, state, num_slots=2, paged=False,
-                             seq_buckets=[8], steps_per_sync=2) as g:
-        outs = [f.result(timeout=60)
-                for f in [g.submit(p, 6) for p in prompts]]
-        assert g.stats()["paged"] is False
-    for r, o in zip(refs, outs):
-        np.testing.assert_array_equal(r, o)

@@ -21,7 +21,7 @@ Five surfaces, all under the ``tuning`` marker:
    gather path on the token-major pool (incl. GQA + rope + a
    NaN-poisoned trash page — the full-capacity-neighbor regression
    scenario; to the last place wherever the CPU sums alike), and
-   the scheduler's ``paged_kernel`` mode end to end; plus the ``cli
+   the scheduler's one decode program end to end on both; plus the ``cli
    tune`` smoke artifact and run-report's kernel-tuning section.
 """
 
@@ -644,8 +644,9 @@ class TestPagedAttention:
             patch.setattr(A, "paged_attention", lambda *a, num_kv_heads:
                           _shaped(*a, num_kv_heads))
             outs["shaped"] = run()
-        monkeypatch.setenv("BIGDL_TPU_PAGED_ATTN", "0")
-        outs["gather"] = run()
+        with monkeypatch.context() as patch:
+            patch.setattr(A, "paged_attention_enabled", lambda: False)
+            outs["gather"] = run()
         return outs
 
     def test_kernel_bit_parity_bf16_cache(self, interpret_mode,
@@ -822,13 +823,16 @@ class TestPagedAttention:
         # the first layer's write does not pass through the read
         assert _bits(outs["gather"][1], outs["kernel"][1])
 
-    def test_generator_paged_kernel_end_to_end(self, interpret_mode):
-        """ContinuousGenerator(paged_kernel=True) — the scan-of-
-        decode_pages read path — produces the row-mode/hoisted outputs
-        exactly, including a FULL-CAPACITY request beside an active
-        neighbor (the NaN regression scenario r11 pinned, now through
-        the kernel)."""
+    def test_generator_paged_kernel_end_to_end(self, interpret_mode,
+                                               monkeypatch):
+        """The generator's ONE decode program, the scan of
+        ``decode_pages``, with its reads through the kernel
+        (interpreted) and through the layer's gather: the same tokens,
+        and ``TransformerLM.generate()``'s, bit for bit — including a
+        FULL-CAPACITY request beside an active neighbor (the NaN
+        regression scenario r11 pinned)."""
         from bigdl_tpu.models.transformer import TransformerLM
+        from bigdl_tpu.ops import attention as A
         from bigdl_tpu.serving.scheduler.continuous import \
             ContinuousGenerator
         m = TransformerLM(vocab_size=64, max_len=32, embed_dim=32,
@@ -839,26 +843,23 @@ class TestPagedAttention:
         # the neighbor that must stay finite and identical
         prompts = [np.arange(1, 25), np.arange(2, 10)]
         outs = {}
-        for kern in (False, True):
-            g = ContinuousGenerator(m, num_slots=2, max_len=32,
-                                    steps_per_sync=3, paged=True,
-                                    page_size=4, paged_kernel=kern)
-            outs[kern] = g.generate(prompts, 8)
-            g.drain()
-        for a, b in zip(outs[False], outs[True]):
-            assert np.array_equal(a, b)
-        assert all(np.asarray(o).size == 8 for o in outs[True])
-
-    def test_kernel_requires_paged(self):
-        from bigdl_tpu.models.transformer import TransformerLM
-        from bigdl_tpu.serving.scheduler.continuous import \
-            ContinuousGenerator
-        m = TransformerLM(vocab_size=32, max_len=16, embed_dim=32,
-                          num_heads=2, num_layers=1)
-        m.params, m.state = m.init(jax.random.PRNGKey(0))
-        with pytest.raises(ValueError):
-            ContinuousGenerator(m, paged=False, paged_kernel=True,
-                                warmup=False)
+        for kern in (True, False):
+            with monkeypatch.context() as patch:
+                if not kern:
+                    patch.setattr(A, "paged_attention_enabled",
+                                  lambda: False)
+                g = ContinuousGenerator(m, num_slots=2, max_len=32,
+                                        steps_per_sync=3, page_size=4)
+                try:
+                    assert g.stats()["paged_kernel"] is kern
+                    outs[kern] = g.generate(prompts, 8)
+                finally:
+                    g.drain()
+        for p, gather, kernel in zip(prompts, outs[False], outs[True]):
+            want = np.asarray(m.generate(params, state, p[None], max_new=8,
+                                         temperature=0.0))[0]
+            assert np.array_equal(gather, want)
+            assert np.array_equal(kernel, want)
 
 
 class TestCliAndReport:
