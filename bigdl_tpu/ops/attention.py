@@ -762,27 +762,36 @@ def fused_attention(q, k, v, causal: bool = False, scale=None,
 # The block-paged serving read path (PR 11) materialised the gathered
 # per-row KV view in HBM before attending — (B, Hkv, Lp*ps, D) written
 # out and read back every decode step.  This kernel removes that round
-# trip: the host page table rides in as a SCALAR-PREFETCH operand, each
-# grid step DMAs one physical pool page — one contiguous ``ps x W``
-# block holding every KV head of its tokens — straight into its slot of
-# a VMEM scratch (the index map does the gather — the view never exists
-# in HBM), and the last step computes the same masked softmax attention
-# the jnp reference runs on the materialised view.  The walk stops at
-# the row's last visible page: a second scalar-prefetch operand carries
-# it, the index map clamps the table slot to it (the steps beyond name
-# the block already resident, so the pipeline moves nothing) and their
-# scratch slots are zero-filled.  Math is kept OPERATION-FOR-OPERATION
-# identical to `nn.MultiHeadAttention.apply_decode_pages`'s gather path
-# (zero trash pages, f32 scores, -inf validity mask, f32 softmax,
-# cache-dtype weighted sum), so the outputs are bit-parity-gated against
-# it in tests.
+# trip: the pools stay in HBM, the host page table rides in as a
+# SCALAR-PREFETCH operand, and the kernel copies a row's pages itself —
+# one contiguous ``ps x W`` page holding every KV head of its tokens,
+# straight into its slot of a VMEM scratch (the table does the gather —
+# the view never exists in HBM) — BLOCK by block: a block is
+# ``paged_block_pages`` pages, one MXU tile of key slots.  A grid step
+# is one ROW (of one lane group): it first starts every copy of the NEXT
+# row's blocks into the other half of the scratch, then takes its own
+# blocks as they arrive.  The walk stops at the block of the row's last
+# visible page (a second scalar-prefetch operand carries that page):
+# the blocks beyond it cost no copy and no loop turn.  With few queries
+# a head (a decode step, a speculative verify, the latent pool's 32
+# query rows) a block's scores are formed in the turn that took the
+# block, while the blocks behind it arrive, and the softmax and the
+# weighted sum run over the visible blocks only; a prefill bucket walks
+# the same way and then attends head by head over the whole table.  Math
+# is kept OPERATION-FOR-OPERATION identical to
+# `nn.MultiHeadAttention.apply_decode_pages`'s gather path (zero trash
+# pages, scores rounded to the promoted dtype, -inf validity mask, f32
+# softmax over the whole visible row, cache-dtype weighted sum): a score
+# is one sum over lanes whatever block it sits in, a masked slot weighs
+# exactly 0, so the blocks left out add nothing — the outputs are
+# bit-parity-gated against that math in tests.
 #
 # The pool is TOKEN-MAJOR and LANE-DENSE: ``(P + 1, ps, W)``, a token's
 # K (or V) of every head one contiguous row of ``W`` lanes, KV head ``j``
 # in lanes ``[j * D, (j + 1) * D)``, ``W`` rounded up to whole chunks
 # (`paged_pool_width`).  Its minor dimension is whole 128-lane tiles and
 # ``ps`` = 16 one bf16 sublane tile, so the program's parameter, the
-# cache write's scatter and this kernel's operand all take the one
+# cache write's scatter and this kernel's copies all take the one
 # tiling the compiler gives it: nothing converts the pool between them.
 
 def paged_attention_enabled() -> bool:
@@ -820,18 +829,30 @@ def paged_pool_dims(pool):
     return pool.shape[1], pool.shape[2]
 
 
-# up to this many bytes of f32 scores, every head of a step is scored as
-# a ROW of one product (a decode step, a speculative verify); above it
-# (a prefill bucket) a loop runs chunk by chunk, head by head
+# up to this many bytes of f32 scores, a step has FEW QUERIES (a decode
+# step, a speculative verify, the latent pool's query rows): scored
+# block by block as the blocks arrive, every head of a lane group a ROW
+# of one product; above it (a prefill bucket) a loop runs chunk by
+# chunk, head by head, once the row's blocks are in
 _PAGED_BATCHED_SCORES = 4 * 1024 * 1024
 # VMEM, as (what the lane-group rule plans a step for, what the call
 # declares): the compiler's default scoped limit of 16 MiB is under what
-# a GPT-2 XL prefill holds (K and V scratch of a row 6.8 MB, the query
-# and output blocks twice each 10.2 MB at 768 queries, one head's f32
-# scores and their softmax 12.6 MB).  One pair serves every call: the
-# pool has one layout, so nothing else of it lives in VMEM, and the
+# a GPT-2 XL prefill holds (K and V scratch of two rows 13.6 MB, the
+# query and output blocks twice each 10.2 MB at 768 queries, one head's
+# f32 scores and their softmax 12.6 MB).  One pair serves every call:
+# the pool has one layout, so nothing else of it lives in VMEM, and the
 # declaration is a limit, not an allocation.
 _PAGED_VMEM = (40 * 1024 * 1024, 48 * 1024 * 1024)
+# key slots of one block of the walk: one MXU tile of keys
+_PAGED_BLOCK_SLOTS = 128
+
+
+def paged_block_pages(page_size: int, table_slots: int) -> int:
+    """Pages of one block of the kernel's walk: `_PAGED_BLOCK_SLOTS`
+    key slots (8 pages of 16), at most the table.  What the host needs
+    to count the walk's blocks (``blocks_walked``): a row whose last
+    visible page is ``p`` costs ``p // pages + 1`` of them."""
+    return max(1, min(_PAGED_BLOCK_SLOTS // page_size, table_slots))
 
 
 def _paged_scores_bytes(queries, length):
@@ -840,19 +861,30 @@ def _paged_scores_bytes(queries, length):
     return -(-queries // 8) * 8 * length * 4
 
 
-def _paged_step_bytes(lanes, heads, group, queries, length, ps, itemsize,
-                      rows):
+def _paged_step_bytes(lanes, heads, group, queries, slots, itemsize,
+                      rows, few):
     """VMEM of one grid step over ``lanes`` of the pool's width holding
-    ``heads`` KV heads: the K and V scratch, the double-buffered page,
-    query and output blocks, and the f32 scores with their softmax
-    temporaries — of every head at once in the ``rows`` form, of one
-    head's queries else."""
+    ``heads`` KV heads, ``slots`` key slots a row (whole blocks): the K
+    and V scratch of the row at work and of the next one arriving, the
+    double-buffered query and output blocks, and the f32 scores — of
+    every query row of the step with the f32 output they are summed
+    into where the queries are ``few``, else of one head's queries with
+    their softmax temporaries."""
     sub = 8 * max(1, 4 // itemsize)
     q_rows = group * queries * (heads if rows else 1)
     q_rows = -(-q_rows // sub) * sub
-    ps_rows = -(-ps // sub) * sub
-    blocks = lanes * itemsize * (2 * length + 4 * q_rows + 4 * ps_rows)
-    return blocks + 4 * _paged_scores_bytes(q_rows, length)
+    blocks = lanes * itemsize * (4 * slots + 4 * q_rows)
+    if few:
+        return blocks + 2 * _paged_scores_bytes(q_rows, slots) \
+            + 2 * q_rows * lanes * 4
+    return blocks + 4 * _paged_scores_bytes(q_rows, slots)
+
+
+def _paged_few(hkv, group, queries, length):
+    """Does a step have FEW QUERIES: do the f32 scores of all its heads
+    over ``length`` key slots fit `_PAGED_BATCHED_SCORES`."""
+    return hkv * _paged_scores_bytes(group * queries, length) \
+        <= _PAGED_BATCHED_SCORES
 
 
 def _paged_tiling(hkv, group, queries, length, d, ps, itemsize):
@@ -860,79 +892,205 @@ def _paged_tiling(hkv, group, queries, length, d, ps, itemsize):
     on a pool of ``hkv`` heads of ``d`` — a function of the shapes and
     the dtype's size only.  The pool's width is split over the grid
     into the fewest lane groups (whole chunks each) whose step fits the
-    budget.  ``rows form``: few queries a head (a decode step), so every
-    head of the group is a row of ONE product against the whole group's
-    lanes, its query zero outside its own head's; else (a prefill
-    bucket; one shared head) chunk by chunk and head by head."""
+    budget, the walk's blocks (`paged_block_pages`) counted in.  ``rows
+    form``: few queries a head (a decode step), so every head of the
+    group is a row of ONE product against the whole group's lanes, its
+    query zero outside its own head's; else (a prefill bucket; one
+    shared head, which is its own row already) chunk by chunk and head
+    by head."""
     chunk = _paged_chunk(hkv, d)
     n_chunks = paged_pool_width(hkv, d) // chunk
     heads = chunk // d if hkv > 1 else 1             # KV heads a chunk
-    # a lone head is its own row already
-    rows = hkv > 1 and hkv * _paged_scores_bytes(group * queries, length) \
-        <= _PAGED_BATCHED_SCORES
+    bs = paged_block_pages(ps, length // ps) * ps
+    slots = -(-length // bs) * bs
+    few = _paged_few(hkv, group, queries, length)
+    rows = few and hkv > 1
     budget, limit = _PAGED_VMEM
     for groups in range(1, n_chunks + 1):
         per = n_chunks // groups
         if n_chunks % groups == 0 and _paged_step_bytes(
-                per * chunk, per * heads, group, queries, length, ps,
-                itemsize, rows) <= budget:
+                per * chunk, per * heads, group, queries, slots, itemsize,
+                rows, few) <= budget:
             break
     return groups, (per * chunk if rows else chunk), rows, limit
 
 
-def _paged_kernel(pages_ref, last_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
-                  k_scr, v_scr, *, lp, ps, trash, scale, heads, d):
-    # grid (B, lane groups, Lp), walked in order: a row's pages stream
-    # into scratch, one page of the group's lanes a step; compute fires
-    # on the row's last step.  k_ref/v_ref blocks were already gathered
-    # BY THE INDEX MAP (pages_ref[b, min(l, last)] picked the pool row),
-    # so the kernel only zeroes what must read as zero — trash pages,
-    # the reference's tmask, and the slots past the row's last visible
-    # page, whose block the pipeline did not fetch: the weights there
-    # are exactly 0 after the -inf mask, but 0 x NaN is NaN, so they may
-    # not keep what an earlier row left — and attends.  The scratch past
-    # the walk of whoever filled it before (the same row's previous lane
-    # group, else the previous row; the whole of it on the first step of
-    # all) is zero already.
-    b, g, l = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    last = last_ref[b]
-    filled = jnp.where(g > 0, last, jnp.where(
-        b > 0, last_ref[jnp.maximum(b - 1, 0)], lp - 1))
-    live = jnp.logical_and(l <= last, pages_ref[b, l] != trash)
-    row = pl.multiple_of(l * ps, ps)
+def _paged_kernel(pages_ref, last_ref, q_ref, pos_ref, k_hbm, v_hbm, o_ref,
+                  k_scr, v_scr, sems, *few_scr, steps, groups, lp, ps, n,
+                  trash, scale, heads, d):
+    # grid (B, lane groups), walked in order; a step is one row's share
+    # of one lane group.  The pools are in HBM: a step starts the page
+    # copies of the NEXT step's blocks into the other half of the
+    # scratch (the first step its own too), then takes its own blocks
+    # in order, each once its pages are in.  A table slot inside a
+    # visible block that holds no visible page — the trash page (the
+    # reference's tmask), the slots behind the row's last visible page,
+    # the slots a table short of whole blocks lacks — is zeroed, not
+    # copied: its weights are exactly 0 after the -inf mask, but 0 x NaN
+    # is NaN, so it may not keep what an earlier row left.  The blocks
+    # behind the last visible one are never read where the queries are
+    # few; a prefill bucket reads the whole table and zeroes them.
+    from jax.experimental.pallas import tpu as pltpu
 
-    @pl.when(live)
-    def _copy():
-        k_scr[pl.ds(row, ps), :] = k_ref[0]
-        v_scr[pl.ds(row, ps), :] = v_ref[0]
+    b, g = pl.program_id(0), pl.program_id(1)
+    step = b * groups + g
+    half = step % 2
+    bs = n * ps
+    slots, lanes = k_scr.shape[1:]
+    n_blk = slots // bs
+    length = lp * ps
+    whole = lanes == k_hbm.shape[2]
 
-    @pl.when(jnp.logical_and(jnp.logical_not(live),
-                             l <= jnp.maximum(last, filled)))
-    def _zero():
-        k_scr[pl.ds(row, ps), :] = jnp.zeros_like(k_ref[0])
-        v_scr[pl.ds(row, ps), :] = jnp.zeros_like(v_ref[0])
+    def page_rows(slot):
+        return pl.ds(pl.multiple_of(slot * ps, ps), ps)
 
-    def attend(q, kk, vv):
-        # the reference gather path's math on (R, lanes) queries against
-        # (L, lanes) keys, including its dtype promotion: scores round
-        # to the promoted operand dtype exactly where the reference
-        # einsum does (bf16 x bf16 scores are bf16 there), then the same
-        # -inf validity mask, f32 softmax and cache-dtype weighted sum.
-        # The MXU accumulates in f32 (Mosaic refuses a narrower
-        # accumulator: "Expected matmul acc to be 32-bit"), which is
-        # also what XLA's bf16 dot does before it rounds — so the
-        # rounding point, not the accumulator, is what the parity gate
-        # pins.  A query that is zero outside its own head's lanes adds
-        # exact zeros to that sum.
+    def block_rows(j):
+        return pl.ds(pl.multiple_of(j * bs, bs), bs)
+
+    def page(row, grp, to, slot):
+        """(whether table slot ``slot`` of row ``row`` holds a page, its
+        K and V copies into half ``to`` of the scratch: lane group
+        ``grp``'s share)."""
+        at = pages_ref[row, jnp.minimum(slot, lp - 1)]
+
+        def src(pool):
+            if whole:
+                return pool.at[at]
+            return pool.at[at, :, pl.ds(pl.multiple_of(grp * lanes, 128),
+                                        lanes)]
+        return at != trash, [pltpu.make_async_copy(
+            src(pool), scr.at[to, page_rows(slot)], sems.at[to, slot // n])
+            for pool, scr in ((k_hbm, k_scr), (v_hbm, v_scr))]
+
+    def blocks(row):
+        return last_ref[row] // n + 1
+
+    def start(k, carry):
+        # every page of step ``step + k`` up to its row's last visible
+        nxt = step + k
+        row, grp = (nxt, 0) if groups == 1 else (nxt // groups,
+                                                 nxt % groups)
+
+        def turn(slot, carry):
+            live, copies = page(row, grp, nxt % 2, slot)
+
+            @pl.when(live)
+            def _start():
+                for c in copies:
+                    c.start()
+            return carry
+        return jax.lax.fori_loop(0, last_ref[row] + 1, turn, carry)
+
+    # the next step's copies, and on the first step its own before them
+    jax.lax.fori_loop(jnp.minimum(step, 1),
+                      jnp.where(step + 1 < steps, 2, 1), start, 0)
+
+    def arrive(j):
+        def turn(i, carry):
+            slot = j * n + i
+            live, copies = page(b, g, half, slot)
+            live = jnp.logical_and(live, slot <= last_ref[b])
+
+            @pl.when(live)
+            def _wait():
+                for c in copies:
+                    c.wait()
+
+            @pl.when(jnp.logical_not(live))
+            def _zero():
+                for scr in (k_scr, v_scr):
+                    scr[half, page_rows(slot), :] = jnp.zeros(
+                        (ps, lanes), scr.dtype)
+            return carry
+        jax.lax.fori_loop(0, n, turn, 0)
+
+    def scores(q, kk, first):
+        # the reference gather path's scores of (R, lanes) queries
+        # against (L, lanes) keys from slot ``first`` on, including its
+        # dtype promotion: they round to the promoted operand dtype
+        # exactly where the reference einsum does (bf16 x bf16 scores
+        # are bf16 there), then the same -inf validity mask.  The MXU
+        # accumulates in f32 (Mosaic refuses a narrower accumulator:
+        # "Expected matmul acc to be 32-bit"), which is also what XLA's
+        # bf16 dot does before it rounds — so the rounding point, not
+        # the accumulator, is what the parity gate pins.  A query that
+        # is zero outside its own head's lanes adds exact zeros to that
+        # sum.
         s = jax.lax.dot_general(q, kk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s.astype(jnp.result_type(q.dtype, kk.dtype)) * scale
-        lidx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(lidx <= pos_ref[0], s, -jnp.inf)   # pos (R, 1)
-        w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
+        lidx = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = lidx <= pos_ref[0]                        # pos (R, 1)
+        if slots > length:
+            seen = jnp.logical_and(seen, lidx < length)
+        return jnp.where(seen, s, -jnp.inf).astype(jnp.float32)
+
+    def weighted(w, vv):
+        # cache-dtype weights into an f32 sum, as the reference's
         return jax.lax.dot_general(
             w.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+            preferred_element_type=jnp.float32)
+
+    if few_scr:
+        # few queries: one product a block against every lane of the
+        # group.  A block's scores in the turn that took it (the blocks
+        # behind it are arriving), then the f32 softmax of the whole
+        # visible row — the maximum, the exponentials summed lane by
+        # lane in the order of the slots and across the lanes once, the
+        # division — and the weighted sum block by block into f32
+        s_scr, o_scr = few_scr
+        q = q_ref[0, 0]
+        lane = math.gcd(bs, 128)
+
+        def score(j, top):
+            arrive(j)
+            s = scores(q, k_scr[half, block_rows(j), :], j * bs)
+            s_scr[j] = s
+            return jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
+        top = jax.lax.fori_loop(
+            0, blocks(b), score,
+            jnp.full((q.shape[0], 1), -jnp.inf, jnp.float32))
+
+        def exps(j, total):
+            e = jnp.exp(s_scr[j] - top)
+            s_scr[j] = e
+            for c in range(0, bs, lane):
+                total = total + e[:, c:c + lane]
+            return total
+        total = jnp.sum(jax.lax.fori_loop(
+            0, blocks(b), exps,
+            jnp.zeros((q.shape[0], lane), jnp.float32)),
+            axis=-1, keepdims=True)
+
+        def weigh(j, carry):
+            o_scr[...] += weighted(s_scr[j] / total,
+                                   v_scr[half, block_rows(j), :])
+            return carry
+        o_scr[...] = jnp.zeros_like(o_scr)
+        jax.lax.fori_loop(0, blocks(b), weigh, 0)
+        o_ref[0, 0] = o_scr[...].astype(o_ref.dtype)
+        return
+
+    # a prefill bucket: the row's blocks in, then the whole table head
+    # by head.  The blocks behind the walk must read as zero: whoever
+    # last filled this half of the scratch (two steps back; nobody on
+    # its first use) left its own walk's blocks there.
+    def take(j, carry):
+        arrive(j)
+        return carry
+    jax.lax.fori_loop(0, blocks(b), take, 0)
+    filled = jnp.where(step < 2, n_blk,
+                       blocks(jnp.maximum(step - 2, 0) // groups))
+
+    def stale(j, carry):
+        for scr in (k_scr, v_scr):
+            scr[half, block_rows(j), :] = jnp.zeros((bs, lanes), scr.dtype)
+        return carry
+    jax.lax.fori_loop(blocks(b), filled, stale, 0)
+
+    def attend(q, kk, vv):
+        w = jax.nn.softmax(scores(q, kk, 0), axis=-1)
+        return weighted(w, vv).astype(o_ref.dtype)
 
     n_chunks, _, chunk = q_ref.shape[1:]
 
@@ -942,28 +1100,25 @@ def _paged_kernel(pages_ref, last_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
         c = t // heads
         q = q_ref[0, c]
         if n_chunks == 1:
-            kk, vv = k_scr[...], v_scr[...]
+            kk, vv = k_scr[half, :length, :], v_scr[half, :length, :]
         else:
-            lanes = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-            kk, vv = k_scr[:, lanes], v_scr[:, lanes]
+            at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            kk, vv = k_scr[half, :length, at], v_scr[half, :length, at]
         if heads == 1:
-            # one head a chunk, or the rows form (each row zero outside
-            # its head's lanes already): one product
+            # one head a chunk: one product
             o_ref[0, c] = attend(q, kk, vv)
             return carry
-        # a prefill bucket: head by head, the others' lanes of the
-        # query zeroed, the head's own lanes of the product kept (every
-        # lane of the chunk is some head's) — the merged (S, heads x D)
-        # order the output projection wants
-        lane = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1) // d
-        own = lane == t % heads
+        # head by head, the others' lanes of the query zeroed, the
+        # head's own lanes of the product kept (every lane of the chunk
+        # is some head's) — the merged (S, heads x D) order the output
+        # projection wants
+        own = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1) // d \
+            == t % heads
         o_ref[0, c] = jnp.where(own, attend(jnp.where(own, q, 0), kk, vv),
                                 o_ref[0, c])
         return carry
 
-    @pl.when(l == lp - 1)
-    def _compute():
-        jax.lax.fori_loop(0, n_chunks * heads, one_head, 0)
+    jax.lax.fori_loop(0, n_chunks * heads, one_head, 0)
 
 
 def paged_attention(q, k_pool, v_pool, pages, positions, scale,
@@ -976,34 +1131,40 @@ def paged_attention(q, k_pool, v_pool, pages, positions, scale,
     write-redirect trash page, ``pages`` (B, Lp) int32 host page table,
     ``positions`` (B, S) — key slot ``l`` visible to row token ``s`` iff
     ``l <= positions[b, s]`` (the decode validity predicate).
-    ``num_kv_heads``: ``Hkv``, by default ``H``.  A grid step moves one
-    pool page, ``ps x W`` contiguous bytes (or its lane group's share
-    where a row does not fit VMEM: ``_paged_tiling``), and none for the
-    table slots past the row's last visible key.  GQA shares a KV head
+    ``num_kv_heads``: ``Hkv``, by default ``H``.  A grid step is one
+    row: it copies the row's pages, ``ps x W`` contiguous bytes each (or
+    its lane group's share where a row does not fit VMEM:
+    ``_paged_tiling``), a BLOCK of ``paged_block_pages`` at a time while
+    the row before it is attended to, and none for the table slots past
+    the block of the row's last visible key.  GQA shares a KV head
     among the ``group`` query heads ``[j*group, (j+1)*group)`` (kv head
     = h // group, as ``expand_kv_heads``).  Returns (B, H, S, D) in the
     cache dtype — bit-parity with the ``apply_decode_pages`` gather
-    path is the acceptance gate."""
+    path's math is the acceptance gate."""
     (_, h, s, d), hkv = q.shape, num_kv_heads or q.shape[1]
     ps, _ = paged_pool_dims(k_pool)
     # what the call depends on beside its operands' shapes is settled
     # here and passed as static: the layers of a model then share ONE
     # trace and one lowering of everything below
-    tiling = _paged_tiling(hkv, h // hkv, s, pages.shape[1] * ps, d, ps,
+    lp = pages.shape[1]
+    tiling = _paged_tiling(hkv, h // hkv, s, lp * ps, d, ps,
                            jnp.dtype(k_pool.dtype).itemsize)
+    walk = paged_block_pages(ps, lp), _paged_few(hkv, h // hkv, s, lp * ps)
     return _paged_call(q, k_pool, v_pool, jnp.asarray(pages, jnp.int32),
                        jnp.asarray(positions, jnp.int32),
                        scale=float(scale), hkv=hkv, tiling=tiling,
-                       interpret=_interpret())
+                       walk=walk, interpret=_interpret())
 
 
 @functools.partial(jax.jit, inline=True,
-                   static_argnames=("scale", "hkv", "tiling", "interpret"))
+                   static_argnames=("scale", "hkv", "tiling", "walk",
+                                    "interpret"))
 def _paged_call(q, k_pool, v_pool, pages, positions, *, scale, hkv, tiling,
-                interpret):
-    """``paged_attention`` at a settled ``tiling`` (``_paged_tiling``):
-    the queries laid out as the kernel takes them, the call, and each
-    head's own lanes of what it returns."""
+                walk, interpret):
+    """``paged_attention`` at a settled ``tiling`` (``_paged_tiling``)
+    and ``walk`` (pages a block, whether the queries are few): the
+    queries laid out as the kernel takes them, the call, and each head's
+    own lanes of what it returns."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
@@ -1011,9 +1172,11 @@ def _paged_call(q, k_pool, v_pool, pages, positions, *, scale, hkv, tiling,
     group = h // hkv
     trash = k_pool.shape[0] - 1
     lp = pages.shape[1]
-    length = lp * ps
     dtype = k_pool.dtype
     groups, chunk, rows, vmem_limit = tiling
+    block, few = walk
+    n_blk = -(-lp // block)
+    slots = n_blk * block * ps          # key slots of a row's scratch
     per = width // groups // chunk      # chunks a lane group
     dp = d if hkv > 1 else width        # lanes a head takes in the pool
     hp = width // dp                    # heads the width has room for
@@ -1038,40 +1201,47 @@ def _paged_call(q, k_pool, v_pool, pages, positions, *, scale, hkv, tiling,
             .reshape(b, hp // hc, group * s, chunk)
         pos = jnp.tile(positions[:, None], (1, group, 1))
     n_rows = qm.shape[2]
-    kern = functools.partial(_paged_kernel, lp=lp, ps=ps, trash=trash,
+    lanes = per * chunk
+    kern = functools.partial(_paged_kernel, steps=b * groups, groups=groups,
+                             lp=lp, ps=ps, n=block, trash=trash,
                              scale=scale,
                              heads=1 if rows else chunk // dp, d=dp)
 
-    def q_block(bi, gi, li, pg, la):
+    def q_block(bi, gi, pg, la):
         return bi, gi, 0, 0
 
-    def page_block(bi, gi, li, pg, la):
-        return pg[bi, jnp.minimum(li, la[bi])], 0, gi
-
+    # the K and V scratch of two rows: the one at work and the next one
+    # arriving; a copy semaphore a block of each
+    scratch = [pltpu.VMEM((2, slots, lanes), dtype),
+               pltpu.VMEM((2, slots, lanes), dtype),
+               pltpu.SemaphoreType.DMA((2, n_blk))]
+    if few:
+        # the row's f32 scores block by block, and its f32 output
+        scratch += [pltpu.VMEM((n_blk, n_rows, block * ps), jnp.float32),
+                    pltpu.VMEM((n_rows, lanes), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, groups, lp),
+        grid=(b, groups),
         in_specs=[
             pl.BlockSpec((1, per, n_rows, chunk), q_block),
             # positions ride as a (B, rows, 1) column so every block's
             # last two dims are the array's own and the kernel needs no
             # lane-to-sublane relayout
-            pl.BlockSpec((1, n_rows, 1), lambda bi, gi, li, pg, la:
-                         (bi, 0, 0)),
-            pl.BlockSpec((1, ps, per * chunk), page_block),
-            pl.BlockSpec((1, ps, per * chunk), page_block),
+            pl.BlockSpec((1, n_rows, 1), lambda bi, gi, pg, la: (bi, 0, 0)),
+            # the pools stay where they are: the kernel copies pages
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, per, n_rows, chunk), q_block),
-        scratch_shapes=[pltpu.VMEM((length, per * chunk), dtype),
-                        pltpu.VMEM((length, per * chunk), dtype)],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qm.shape, dtype),
         compiler_params=pltpu.CompilerParams(
-            # in order: a step relies on what the one before it left
-            dimension_semantics=("arbitrary",) * 3,
+            # in order: a step takes what the one before it started
+            dimension_semantics=("arbitrary",) * 2,
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="paged_attention",

@@ -508,8 +508,10 @@ class ContinuousGenerator:
         # do reads go through the paged-attention kernel?  The layer
         # decides that from the backend; read once, for the spans'
         # walk counters and stats()
-        from bigdl_tpu.ops.attention import paged_attention_enabled
+        from bigdl_tpu.ops.attention import (paged_attention_enabled,
+                                             paged_block_pages)
         self._kernel_reads = paged_attention_enabled()
+        self._walk_block = paged_block_pages(ps, lp)   # pages a block
         self._pending: Optional[GenRequest] = None
 
         self.slots = SlotManager(n, self.max_len, self.seq_ladder.max,
@@ -1787,11 +1789,15 @@ class ContinuousGenerator:
         of each kernel row, those up to the page of its last visible key
         (``last_visible``, one position a row) are walked, the rest move
         no data.  Their running ratio is the gauge ``serve.paged walk
-        share``."""
+        share``.  ``blocks_walked`` and ``blocks_table``: the same walk
+        in the kernel's own steps, blocks of ``paged_block_pages``
+        pages: the loop turns a row costs over those its table has."""
         if not (self._kernel_reads and run_ledger.enabled()):
             return {}
         last = np.asarray(last_visible).reshape(-1) // self._alloc.page_size
-        walked = int((np.clip(last, 0, self._lp - 1) + 1).sum())
+        last = np.clip(last, 0, self._lp - 1)
+        block = self._walk_block
+        walked = int((last + 1).sum())
         table = int(last.size * self._lp)
         self._pages_walked += walked
         self._pages_table += table
@@ -1799,7 +1805,9 @@ class ContinuousGenerator:
             self.metrics.set("serve.paged walk share",
                              self._pages_walked / self._pages_table,
                              unit="scalar")
-        return {"pages_walked": walked, "pages_table": table}
+        return {"pages_walked": walked, "pages_table": table,
+                "blocks_walked": int((last // block + 1).sum()),
+                "blocks_table": int(last.size * -(-self._lp // block))}
 
     def _state_attrs(self, emitted, pos) -> dict:
         """What a ``serve.decode`` span says of the per-slot state and

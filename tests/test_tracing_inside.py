@@ -217,14 +217,21 @@ def test_decode_spans_carry_context_and_pages(serve_records):
     assert prefills and all("rid" in r["attrs"] for r in prefills)
 
 
+@pytest.mark.parametrize("block", [8, 128])
 def test_walk_counters_equal_a_replay_of_the_positions(tmp_path,
-                                                       monkeypatch):
+                                                       monkeypatch, block):
     """``pages_walked`` / ``pages_table`` on the spans of the calls that
     hold the paged kernel equal numpy's count over the positions the
     slots had when the call was made, and ``stats()`` shows their
-    running ratio."""
+    running ratio; ``blocks_walked`` / ``blocks_table`` count the same
+    walk in the kernel's blocks (``block`` key slots each: 2 pages of 4
+    and 32 of them, the kernel's own)."""
+    from bigdl_tpu.ops import attention
     monkeypatch.setenv("BIGDL_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(attention, "_PAGED_BLOCK_SLOTS", block)
     ps, lp = 4, 64 // 4
+    n = min(block // ps, lp)            # pages a block
+    assert attention.paged_block_pages(ps, lp) == n
     run_ledger.set_run_dir(str(tmp_path))
     try:
         g = _gen()
@@ -249,12 +256,16 @@ def test_walk_counters_equal_a_replay_of_the_positions(tmp_path,
     for a, pos in zip(decodes, seen):
         assert a["pages_walked"] == int((pos // ps + 1).sum())
         assert a["pages_table"] == pos.size * lp == a["active"] * lp
+        assert a["blocks_walked"] == int((pos // ps // n + 1).sum())
+        assert a["blocks_table"] == pos.size * -(-lp // n)
     prefills = [r["attrs"] for r in spans if r["name"] == "serve.prefill"]
     assert len(prefills) == 3
     for a in prefills:
         # a prefill's queries run to the end of its bucket
         last = a["shared_tokens"] + a["bucket"] - 1
         assert a["pages_walked"] == last // ps + 1 and a["pages_table"] == lp
+        assert a["blocks_walked"] == last // ps // n + 1
+        assert a["blocks_table"] == -(-lp // n)
     walked = sum(a["pages_walked"] for a in decodes + prefills)
     table = sum(a["pages_table"] for a in decodes + prefills)
     assert share == pytest.approx(walked / table) and 0 < share < 0.5

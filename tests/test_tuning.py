@@ -454,17 +454,23 @@ def _gather(q, kp, vp, pages, pos, scale, hkv):
 def _shaped(q, kp, vp, pages, pos, scale, hkv, *, f32_scores=False,
             zero_trash=True):
     """The gather path's math, operation for operation, in products of
-    the kernel's SHAPES: the bit-exact oracle.  XLA's CPU backend picks
-    its dot emitter by shape, so `_gather`'s per-head products over
-    ``d`` lanes sum the same terms in another order than the kernel's;
-    here a head's query is zero outside its own lanes of the chunk (a
-    prefill bucket; one wide head), or every head of a lane group is a
+    the kernel's SHAPES and in its ORDER: the bit-exact oracle.  XLA's
+    CPU backend picks its dot emitter by shape, so `_gather`'s per-head
+    products over ``d`` lanes sum the same terms in another order than
+    the kernel's; here a head's query is zero outside its own lanes of
+    the chunk (a prefill bucket), or every head of a lane group is a
     row of one block-diagonal product over the group's lanes (few
-    queries a head), as `_paged_tiling` says — written with plain
-    indexing, not with the wrapper's reshapes.  ``f32_scores`` and
-    ``zero_trash=False`` plant the two faults the parity gate exists
-    for: scores left in float32 where the reference's einsum rounds to
-    the promoted dtype, and a trash page read as it is."""
+    queries a head; a lone wide head is its own row), as `_paged_tiling`
+    says.  Few queries are scored BLOCK by block (`paged_block_pages`
+    pages each) up to the block of the row's last visible page; the
+    softmax is the whole visible row's — the maximum over its blocks,
+    the exponentials summed lane by lane in slot order and once across
+    lanes, the division — and the weighted sum adds a block at a time
+    into float32.  Written with plain indexing, not with the wrapper's
+    reshapes.  ``f32_scores`` and ``zero_trash=False`` plant the two
+    faults the parity gate exists for: scores left in float32 where the
+    reference's einsum rounds to the promoted dtype, and a trash page
+    read as it is."""
     from bigdl_tpu.ops import attention as A
     b, h, s, d = q.shape
     ps, width = A.paged_pool_dims(kp)
@@ -472,16 +478,27 @@ def _shaped(q, kp, vp, pages, pos, scale, hkv, *, f32_scores=False,
     length = lp * ps
     groups, chunk, rows, _ = A._paged_tiling(
         hkv, group, s, length, d, ps, jnp.dtype(kp.dtype).itemsize)
-    tmask = jnp.repeat(pages == trash, ps, axis=1)[..., None]
+    few = A._paged_few(hkv, group, s, length)
+    n = A.paged_block_pages(ps, lp)
+    bs = n * ps
+    slots = -(-lp // n) * bs            # a row's key slots: whole blocks
+    # the last page any query of a row sees; what lies behind it is not
+    # copied, and reads as zero like a trash page
+    last = np.clip(np.asarray(pos).max(axis=1) // ps, 0, lp - 1)
+    dead = np.arange(lp)[None] > last[:, None]
+    if zero_trash:
+        dead = dead | (np.asarray(pages) == trash)
+    dead = jnp.asarray(np.repeat(dead, ps, axis=1))[..., None]
 
     def view(pool):
-        v = pool[pages].reshape(b, length, width)
-        return jnp.where(tmask, 0, v) if zero_trash else v
+        v = jnp.where(dead, 0, pool[pages].reshape(b, length, width))
+        return jnp.pad(v, ((0, 0), (0, slots - length), (0, 0)))
 
     kk, vv = view(kp), view(vp)
-    valid = jnp.arange(length)[None, None, :] <= pos[:, :, None]
+    at = jnp.arange(slots)[None, None, :]
+    valid = (at <= pos[:, :, None]) & (at < length)
 
-    def attend(qr, k2, v2, ok):
+    def scores(qr, k2, ok):
         # the accumulator is float32 on either path (a CPU's bf16 dot
         # upcasts, the MXU accumulates so); the ROUNDING of the scores
         # to the promoted dtype is the reference's
@@ -489,11 +506,34 @@ def _shaped(q, kp, vp, pages, pos, scale, hkv, *, f32_scores=False,
                         preferred_element_type=jnp.float32)
         if not f32_scores:
             sc = sc.astype(jnp.result_type(qr.dtype, k2.dtype))
-        sc = jnp.where(ok, sc * scale, -jnp.inf)
-        w = jax.nn.softmax(sc.astype(jnp.float32), axis=-1)
+        return jnp.where(ok, sc * scale, -jnp.inf).astype(jnp.float32)
+
+    def weighted(w, v2):
         return jnp.einsum("rl,lc->rc", w.astype(v2.dtype), v2,
-                          preferred_element_type=jnp.float32
-                          ).astype(kp.dtype)
+                          preferred_element_type=jnp.float32)
+
+    def attend(qr, k2, v2, ok):
+        # a prefill bucket: the whole table in one product a head
+        w = jax.nn.softmax(scores(qr, k2[:length], ok[:, :length]), axis=-1)
+        return weighted(w, v2[:length]).astype(kp.dtype)
+
+    def attend_few(qr, k2, v2, ok, blocks):
+        cols = [slice(j * bs, (j + 1) * bs) for j in range(blocks)]
+        sc = [scores(qr, k2[c], ok[:, c]) for c in cols]
+        top = jnp.full((qr.shape[0], 1), -jnp.inf, jnp.float32)
+        for x in sc:
+            top = jnp.maximum(top, jnp.max(x, axis=-1, keepdims=True))
+        lane = math.gcd(bs, 128)
+        total = jnp.zeros((qr.shape[0], lane), jnp.float32)
+        ex = [jnp.exp(x - top) for x in sc]
+        for e in ex:
+            for c in range(0, bs, lane):
+                total = total + e[:, c:c + lane]
+        total = jnp.sum(total, axis=-1, keepdims=True)
+        out = jnp.zeros((qr.shape[0], k2.shape[1]), jnp.float32)
+        for e, c in zip(ex, cols):
+            out = out + weighted(e / total, v2[c])
+        return out.astype(kp.dtype)
 
     dp = d if hkv > 1 else width        # lanes a head takes in the pool
     hp = width // dp
@@ -501,6 +541,7 @@ def _shaped(q, kp, vp, pages, pos, scale, hkv, *, f32_scores=False,
                  ((0, 0), (0, hp - hkv), (0, 0), (0, dp - d)))
     out = [[None] * hp for _ in range(b)]
     for i in range(b):
+        blocks = int(last[i]) // n + 1
         if rows:
             hg = hp // groups
             ok = jnp.tile(valid[i], (hg * group, 1))
@@ -509,8 +550,8 @@ def _shaped(q, kp, vp, pages, pos, scale, hkv, *, f32_scores=False,
                 qr = jnp.zeros((hg, group * s, hg, dp), q.dtype)
                 for j in range(hg):
                     qr = qr.at[j, :, j].set(qh[i, g * hg + j])
-                o = attend(qr.reshape(hg * group * s, hg * dp),
-                           kk[i, :, lanes], vv[i, :, lanes], ok)
+                o = attend_few(qr.reshape(hg * group * s, hg * dp),
+                               kk[i, :, lanes], vv[i, :, lanes], ok, blocks)
                 o = o.reshape(hg, group * s, hg, dp)
                 for j in range(hg):
                     out[i][g * hg + j] = o[j, :, j]
@@ -522,8 +563,10 @@ def _shaped(q, kp, vp, pages, pos, scale, hkv, *, f32_scores=False,
             lanes = slice(c * chunk, (c + 1) * chunk)
             qr = jnp.zeros((group * s, hc, dp), q.dtype).at[:, t].set(
                 qh[i, j])
-            o = attend(qr.reshape(group * s, chunk), kk[i, :, lanes],
-                       vv[i, :, lanes], ok)
+            qr = qr.reshape(group * s, chunk)
+            o = attend_few(qr, kk[i, :, lanes], vv[i, :, lanes], ok,
+                           blocks) if few else \
+                attend(qr, kk[i, :, lanes], vv[i, :, lanes], ok)
             out[i][j] = o.reshape(group * s, hc, dp)[:, t]
     out = jnp.stack([jnp.stack(r) for r in out])    # (B, hp, group x S, dp)
     return out[:, :hkv, :, :d].reshape(b, h, s, d)
@@ -552,19 +595,25 @@ def _close(want, got):
 
 
 class TestPagedAttention:
-    # (B, H, Hkv, S, D, table slots): pages of 16, the shapes the cells
-    # run.  A row's context is drawn so that its last page is partial
-    # and the rest of its table trash.
+    # (B, H, Hkv, S, D, table slots, key slots a block): pages of 16,
+    # the shapes the cells run.  A row's context is drawn so that its
+    # last page is partial and the rest of its table trash.  Blocks of
+    # 3 pages cut these short tables into two or three, the last one
+    # short of pages where the table is no multiple (4 and 8 slots).
     SHAPES = {
         # GPT-2 XL's heads: an odd count, the width padded 1,600 -> 1,664
-        "gpt2xl_decode": (3, 25, 25, 1, 64, 4),
-        "gpt2xl_verify": (2, 25, 25, 4, 64, 4),
-        "gpt2xl_prefill": (1, 25, 25, 64, 64, 8),
-        "gqa4_decode": (3, 8, 2, 1, 64, 4),
-        "gqa4_prefill": (1, 8, 2, 48, 64, 6),
+        "gpt2xl_decode": (3, 25, 25, 1, 64, 4, 48),
+        "gpt2xl_verify": (2, 25, 25, 4, 64, 4, 48),
+        "gpt2xl_prefill": (1, 25, 25, 64, 64, 8, 48),
+        "gqa4_decode": (3, 8, 2, 1, 64, 4, 48),
+        "gqa4_prefill": (1, 8, 2, 48, 64, 6, 48),
         # the latent pool: ONE head of 576 read by 32 query rows
-        "latent_decode": (2, 1, 1, 32, 576, 8),
-        "head128_verify": (2, 4, 4, 4, 128, 4),
+        "latent_decode": (2, 1, 1, 32, 576, 8, 48),
+        "head128_verify": (2, 4, 4, 4, 128, 4, 48),
+        # the block the cells run with, 8 pages = 128 key slots, over a
+        # table of two and a half of them
+        "gpt2xl_decode_blocks": (3, 25, 25, 1, 64, 20, 128),
+        "latent_decode_blocks": (2, 1, 1, 32, 576, 20, 128),
     }
 
     def _case(self, monkeypatch, shape, dtype):
@@ -574,7 +623,9 @@ class TestPagedAttention:
         The scale is a Python float, as the layer's: a numpy scalar is
         no weak type and would promote bf16 scores to float32."""
         from bigdl_tpu.ops import attention as A
-        b, h, hkv, s, d, lp = self.SHAPES[shape]
+        b, h, hkv, s, d, lp, block = self.SHAPES[shape]
+        monkeypatch.setattr(A, "_PAGED_BLOCK_SLOTS", block)
+        assert -(-lp // A.paged_block_pages(16, lp)) > 1
         if shape.endswith("prefill"):
             # a bucket of 256 over a table of 1,024 has 26 MB of scores;
             # this small one takes the same form under a smaller bound
@@ -609,9 +660,11 @@ class TestPagedAttention:
         (_, h, s, d), hkv, lp = args[0].shape, args[-1], args[3].shape[1]
         rows = A._paged_tiling(hkv, h // hkv, s, lp * 16, d, 16,
                                jnp.dtype(dtype).itemsize)[2]
-        # few queries a head: every head a row of one product
-        assert rows == (shape.endswith(("decode", "verify"))
-                        and hkv > 1)
+        # few queries a head: scored block by block, every head a row
+        # of one product
+        few = not shape.endswith("prefill")
+        assert A._paged_few(hkv, h // hkv, s, lp * 16) == few
+        assert rows == (few and hkv > 1)
         got = A.paged_attention(*args[:-1], num_kv_heads=hkv)
         assert np.isfinite(np.asarray(got, np.float32)).all()
         ref = _shaped(*args)
@@ -679,8 +732,10 @@ class TestPagedAttention:
         assert _bits(outs["shaped"], outs["kernel"])
         assert _close(outs["gather"], outs["shaped"])
 
-    # first query position of each row, in the grid's order; tables of
-    # 4 pages of 16.  A row's table maps the pages its queries reach.
+    # first query position of each row (``ctx``) or its last (``end``),
+    # in the grid's order; tables of 4 pages of 16 in blocks of 2 pages
+    # (32 key slots) unless the case says otherwise.  A row's table maps
+    # the pages its queries reach.
     WALKS = {
         # the shortest on its first page only, the longest filling its
         # table, in one call
@@ -695,6 +750,23 @@ class TestPagedAttention:
         # own output is NaN on both paths (0 x NaN), the short rows
         # after it must stay finite
         "stale_nan": dict(ctx=[50, 2, 1], nan_after=0),
+        # a context that ends on a block's last slot, and one that ends
+        # on the next block's first
+        "block_edges": dict(end=[31, 32, 63, 0]),
+        # a table that is no multiple of the block: 5 slots in blocks
+        # of 2, the third block one page short
+        "table_not_whole_blocks": dict(end=[79, 3, 66], lp=5),
+        # a full table (nothing to skip) beside a one-token row
+        "full_beside_one_token": dict(end=[63, 0, 63]),
+        # a whole block of NaN keys and values behind the first row's
+        # last visible slot (the first of its second block): the rows
+        # after it, the third in the same half of the scratch, hold
+        # their first block only and must not read it
+        "stale_nan_block": dict(end=[32, 2, 1, 5], nan_after=0,
+                                nan_keys=True),
+        # trash holes in the middle of a block of 4 pages, 6 slots
+        "trash_mid_block": dict(end=[90, 60], lp=6, block=64,
+                                hole={0: 2, 1: 1}),
     }
 
     @pytest.mark.parametrize("s,variant", [
@@ -705,13 +777,15 @@ class TestPagedAttention:
                                    case, s, variant):
         """The bounded walk against the gather path: GQA group 2 over
         an ODD number of KV heads (three of 64 in a width of 256),
-        pages of 16, a decode step and a 5-token verify; every head a
-        row of one product over the whole width, the width split into
-        two lane groups (a group's scratch then follows its own row's,
-        not the previous row's), and chunk by chunk, head by head, in
-        one lane group and in two."""
+        pages of 16 in blocks of 2, a decode step and a 5-token verify;
+        every head a row of one product over the whole width, the width
+        split into two lane groups (a group's scratch then follows the
+        step two before it, its own row's or the previous row's), and
+        chunk by chunk, head by head, in one lane group and in two."""
         from bigdl_tpu.ops import attention as A
-        hkv, d, ps, lp = 3, 64, 16, 4
+        spec = self.WALKS[case]
+        hkv, d, ps, lp = 3, 64, 16, spec.get("lp", 4)
+        monkeypatch.setattr(A, "_PAGED_BLOCK_SLOTS", spec.get("block", 32))
         limit = A._PAGED_VMEM[1]
         groups = 2 if variant.endswith("groups") else 1
         rows = variant.startswith("rows")
@@ -719,13 +793,18 @@ class TestPagedAttention:
             monkeypatch.setattr(A, "_PAGED_BATCHED_SCORES", 0)
         if groups == 2:
             # room for one chunk (two heads of 64) a step, not for two
+            block = A.paged_block_pages(ps, lp) * ps
             monkeypatch.setattr(A, "_PAGED_VMEM", (A._paged_step_bytes(
-                128, 2, 2, s, lp * ps, ps, 4, rows), limit))
+                128, 2, 2, s, -(-lp * ps // block) * block, 4, rows, rows),
+                limit))
         h = 2 * hkv
         assert A._paged_tiling(hkv, 2, s, lp * ps, d, ps, 4) == (
             groups, 256 // groups if rows else 128, rows, limit)
-        spec = self.WALKS[case]
-        ctx = np.asarray(spec["ctx"])
+        assert A._paged_few(hkv, 2, s, lp * ps) == rows
+        if "end" in spec:
+            ctx = np.maximum(np.asarray(spec["end"]) - (s - 1), 0)
+        else:
+            ctx = np.asarray(spec["ctx"])
         b = len(ctx)
         rng = np.random.RandomState(3)
         p = b * lp
@@ -737,12 +816,15 @@ class TestPagedAttention:
             used = min(lp, pos[i, -1] // ps + 1)
             pages[i, :used] = np.arange(i * lp, i * lp + used)
         for i, slot in spec.get("hole", {}).items():
+            assert slot < pos[i, -1] // ps
             pages[i, slot] = p
         if "nan_after" in spec:
             i = spec["nan_after"]
             page, off = divmod(int(pos[i, -1]) + 1, ps)
             assert 0 < off and page == pos[i, -1] // ps
             vp = vp.at[pages[i, page], off:, :hkv * d].set(jnp.nan)
+            if spec.get("nan_keys"):
+                kp = kp.at[pages[i, page], off:, :hkv * d].set(jnp.nan)
         pages, pos = jnp.asarray(pages), jnp.asarray(pos, jnp.int32)
         args = q, kp, vp, pages, pos, 1.0 / math.sqrt(d), hkv
         got = np.asarray(A.paged_attention(*args[:-1], num_kv_heads=hkv))
@@ -755,10 +837,15 @@ class TestPagedAttention:
 
     @pytest.mark.parametrize("s", [1, 256, 768])
     def test_grid_moves_whole_pages(self, s):
-        """Structural guard at GPT-2 XL's shapes: a grid step moves ONE
-        contiguous page of the pool, 16 tokens x 1,664 lanes with every
-        KV head in it, so the grid is rows x 1 x table slots, the pool
-        reaches the kernel as it is and nothing has the heads' extent."""
+        """Structural guard at GPT-2 XL's shapes: ONE kernel call, the
+        pools reach it as they are and stay in HBM (no block of theirs
+        is cut out for a step: the kernel copies whole pages, 16 tokens
+        x 1,664 lanes with every KV head in them, itself).  A grid step
+        is one row, so the grid is rows x 1 lane group; a row's walk is
+        8 BLOCKS of 8 pages (a copy semaphore each, in the two halves
+        of a two-row scratch), not 64 pages, and nothing has the heads'
+        extent."""
+        from jax.experimental import pallas as pl
         from bigdl_tpu.ops import attention as A
         b, h, d, ps, lp = (10 if s == 1 else 1), 25, 64, 16, 64
         width = A.paged_pool_width(h, d)
@@ -772,6 +859,7 @@ class TestPagedAttention:
         calls = [e for e in jaxpr.jaxpr.eqns
                  if e.primitive.name == "pallas_call"]
         assert len(calls) == 1
+        assert calls[0].params["name"] == "paged_attention"
         # the pools go from the arguments into the call untouched
         assert [v for v in calls[0].invars
                 if v in jaxpr.jaxpr.invars[1:3]] == jaxpr.jaxpr.invars[1:3]
@@ -779,14 +867,29 @@ class TestPagedAttention:
         groups, chunk, rows, limit = A._paged_tiling(h, 1, s, lp * ps, d,
                                                      ps, 2)
         # a decode step scores every head as a row over the whole
-        # width; a prefill bucket goes pair by pair of heads
+        # width, block by block; a prefill bucket goes pair by pair of
+        # heads over the whole table
         assert (groups, chunk, rows) == ((1, width, True) if s == 1
                                          else (1, 128, False))
+        assert A._paged_few(h, 1, s, lp * ps) == (s == 1)
         assert limit == A._PAGED_VMEM[1]
-        assert tuple(mapping.grid) == (b, 1, lp)
-        blocks = [tuple(getattr(n, "block_size", n) for n in m.block_shape)
-                  for m in mapping.block_mappings]
-        assert blocks.count((1, ps, width)) == 2
+        block = A.paged_block_pages(ps, lp)
+        assert block * ps == 128 and lp // block == 8
+        assert tuple(mapping.grid) == (b, 1)
+        pools = [m.block_aval for m in mapping.block_mappings
+                 if m.array_aval.shape == pool.shape]
+        assert len(pools) == 2 and all(
+            a.shape == pool.shape and a.memory_space == pl.ANY
+            for a in pools)
+        scratch = [getattr(v.aval, "shape", None)
+                   for v in calls[0].params["jaxpr"].invars[
+                       -mapping.num_scratch_operands:]]
+        assert scratch[:3] == [(2, lp * ps, width), (2, lp * ps, width),
+                               (2, lp // block)]
+        # few queries keep the row's scores block by block, and its
+        # float32 output
+        assert scratch[3:] == ([(lp // block, 26, block * ps), (26, width)]
+                               if s == 1 else [])
         assert h not in mapping.grid
 
     def test_decode_pages_kernel_on_off_bit_equal(self, interpret_mode,
