@@ -3,9 +3,12 @@ names its sequence mixer and its feed-forward part, and the model's
 serving state follows from the mixers it holds.
 
 * mixers: ``"kda"`` (:class:`~bigdl_tpu.nn.DeltaAttention`: delta-rule
-  linear attention, a fixed float32 state per sequence) and ``"mla"``
+  linear attention, a fixed float32 state per sequence), ``"mla"``
   (:class:`~bigdl_tpu.nn.LatentAttention`: softmax attention over a paged
-  pool of latents);
+  pool of latents), and ``"swa"`` and ``"full"``
+  (:class:`~bigdl_tpu.nn.GroupedQueryAttention` with and without a
+  window: a ring of ``window`` keys and values per sequence, or pages of
+  them; the window layers carry the rope and the full layers none);
 * feed-forward parts: ``"dense"`` (:class:`~bigdl_tpu.nn.GatedMLP`) and
   ``"experts"`` (sigmoid group-limited routing over ALL the layer's
   experts, the grouped product over the ``experts_held`` this chip holds
@@ -36,7 +39,8 @@ from bigdl_tpu.parallel.expert import (held_experts_apply,
                                        sigmoid_group_route)
 
 _F32 = jnp.float32
-MIXERS = ("kda", "mla")
+MIXERS = ("kda", "mla", "swa", "full")
+PAGED = ("mla", "full")     # mixers whose cache is pages; the others' a slot's
 ROUTER_GAIN = 4.0           # standard deviation of a fresh router's logits
 EXPERT_GAIN = 0.1           # a routed expert's output, of unit gain
 FFNS = ("dense", "experts")
@@ -64,7 +68,11 @@ class HybridLM(Module):
                  latent_dim: int = 512, rope_dim: int = 64,
                  nope_dim: int = 128, v_dim: int = 128,
                  rope_theta: float = 10000.0, conv_taps: int = 4,
-                 decay_floor: float = -5.0, norm_eps: float = 1e-6):
+                 decay_floor: float = -5.0, norm_eps: float = 1e-6,
+                 num_kv_heads: Optional[int] = None,
+                 window: Optional[int] = None,
+                 router_gain: float = ROUTER_GAIN,
+                 expert_gain: float = EXPERT_GAIN):
         super().__init__()
         layers = [tuple(l) for l in layers]
         if len(layers) != num_layers:
@@ -89,29 +97,44 @@ class HybridLM(Module):
         self.experts_held = num_experts if experts_held is None \
             else int(experts_held)
         self.expert_offset = int(expert_offset)
+        self.router_gain, self.expert_gain = router_gain, expert_gain
         if not 0 <= self.expert_offset \
                 <= num_experts - self.experts_held:
             raise ValueError(
                 f"experts [{self.expert_offset}, {self.expert_offset} + "
                 f"{self.experts_held}) are not a share of {num_experts}")
+        #: a window layer's keys a sequence (declared: the generator's
+        #: decode spans then count what each kind of layer read)
+        self.window = window
         self.norm = nn.RMSNorm(embed_dim, norm_eps)
-        self.mixers = [
-            nn.DeltaAttention(embed_dim, num_heads, head_dim, conv_taps,
-                              decay_floor, norm_eps) if m == "kda"
-            else nn.LatentAttention(embed_dim, num_heads, latent_dim,
-                                    rope_dim, nope_dim, v_dim, rope_theta,
-                                    norm_eps)
-            for m, _ in layers]
+        if not window and any(m == "swa" for m, _ in layers):
+            raise ValueError("a pattern with 'swa' layers states its window")
+
+        def mixer(kind):
+            if kind == "kda":
+                return nn.DeltaAttention(embed_dim, num_heads, head_dim,
+                                         conv_taps, decay_floor, norm_eps)
+            if kind == "mla":
+                return nn.LatentAttention(embed_dim, num_heads, latent_dim,
+                                          rope_dim, nope_dim, v_dim,
+                                          rope_theta, norm_eps)
+            return nn.GroupedQueryAttention(
+                embed_dim, num_heads, num_kv_heads or num_heads, head_dim,
+                window if kind == "swa" else None, kind == "swa",
+                rope_theta, norm_eps)
+
+        self.mixers = [mixer(m) for m, _ in layers]
         self.dense = nn.GatedMLP(embed_dim, ffn_dim)
         self.shared = nn.GatedMLP(embed_dim, expert_dim)
 
     #: what the generator is told, for EVERY pattern: the serving tree is
     #: ``{"pages", "slots"}``, addressed by slot beside the page table, and
     #: a prefill is the prompt whole from position 0.  A ``kda`` layer needs
-    #: that for its state; an ``mla`` layer because a longer input attends
-    #: over its own tokens only (``LatentAttention``'s contract), so a
-    #: pattern without ``kda`` layers must not be handed a shared prefix
-    #: or a verify pass either (its ``slots`` entries are empty: 0 bytes).
+    #: that for its state and a ``swa`` layer for its ring; an ``mla`` or a
+    #: ``full`` layer because a longer input attends over its own tokens
+    #: only (their contract), so a pattern of paged layers alone must not
+    #: be handed a shared prefix or a verify pass either (its ``slots``
+    #: entries are empty: 0 bytes).
     recurrent_state = True
 
     # -- parameters ------------------------------------------------------------
@@ -130,7 +153,7 @@ class HybridLM(Module):
         # moves the logits less than the rounding itself does.
         return {
             "router": jax.random.normal(kr, (self.num_experts, e))
-            * ROUTER_GAIN * e ** -0.5,
+            * self.router_gain * e ** -0.5,
             "bias": jnp.zeros((self.num_experts,), _F32),
             # (in, out) per expert, as the grouped product reads them;
             # gate and up side by side
@@ -138,7 +161,7 @@ class HybridLM(Module):
                 "w_gate_up": jax.random.normal(kg, (g, e, 2 * f))
                 * e ** -0.5,
                 "w_down": jax.random.normal(kd, (g, f, e))
-                * EXPERT_GAIN * f ** -0.5},
+                * self.expert_gain * f ** -0.5},
             "shared": self.shared.init_params(ks),
         }
 
@@ -167,16 +190,30 @@ class HybridLM(Module):
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32, num_slots: int = 1):
         """``{"pages": [...], "slots": [...]}``, a list entry a layer:
-        the latent pool of an ``mla`` layer, ``(num_pages + 1, page_size,
-        W)`` with the trash page last (``{}`` for a layer without one),
-        and the per-slot state of a ``kda`` layer (likewise)."""
+        the pools of an ``mla`` or a ``full`` layer, ``(num_pages + 1,
+        page_size, W)`` with the trash page last (``{}`` for a layer
+        without one), and what a ``kda`` or a ``swa`` layer keeps per slot,
+        its state or its ring (likewise)."""
         return {
             "pages": [m.init_paged_cache(num_pages, page_size, dtype)
-                      if kind == "mla" else {}
+                      if kind in PAGED else {}
                       for (kind, _), m in zip(self.layers, self.mixers)],
             "slots": [m.init_slot_state(num_slots, dtype)
-                      if kind == "kda" else {}
+                      if kind not in PAGED else {}
                       for (kind, _), m in zip(self.layers, self.mixers)]}
+
+    def state_bytes(self, cache) -> dict:
+        """Bytes of ONE page and of ONE slot's state in ``cache``, by kind
+        of mixer: ``{"page": {"full": ...}, "slot": {"swa": ...}}``."""
+        out = {"page": {}, "slot": {}}
+        for name, entries in (("page", cache["pages"]),
+                              ("slot", cache["slots"])):
+            for (kind, _), entry in zip(self.layers, entries):
+                n = sum(a.size // a.shape[0] * a.dtype.itemsize
+                        for a in jax.tree_util.tree_leaves(entry))
+                if n:
+                    out[name][kind] = out[name].get(kind, 0) + n
+        return out
 
     # -- the layers --------------------------------------------------------------
 
@@ -221,7 +258,7 @@ class HybridLM(Module):
             with jax.named_scope(f"block_{i}"):
                 h = self.norm.apply(p["norm1"], {}, x)[0]
                 with jax.named_scope(kind):
-                    if kind == "mla":
+                    if kind in PAGED:
                         y, new_pages[i] = mixer.apply_decode_pages(
                             p["mixer"], h, cache["pages"][i], pages, pos,
                             active)

@@ -7,7 +7,8 @@ reference maps 1:1 onto this package.
 
 from bigdl_tpu.core.module import (Container, Criterion, Module,
                                    flatten_params, unflatten_params)
-from bigdl_tpu.nn.attention import LatentAttention, MultiHeadAttention
+from bigdl_tpu.nn.attention import (GroupedQueryAttention, LatentAttention,
+                                    MultiHeadAttention)
 from bigdl_tpu.nn.linear_attention import DeltaAttention
 from bigdl_tpu.parallel.expert import MixtureOfExperts
 from bigdl_tpu.nn.activation import (ELU, Abs, Clamp, Exp, GradientReversal,

@@ -134,6 +134,42 @@ def _write_rows(pool, new, phys, offs):
     return jax.lax.cond(jnp.all(offs[:, 0] == 0), by_pages, by_tokens, pool)
 
 
+def _paged_read(q, ck, cv, pages, positions, scale, num_kv_heads):
+    """Attention of ``q`` (B, H, S, D) over the rows' pages of the pools
+    ``ck``, ``cv`` through their tables ``pages`` (B, Lp), key slot ``l``
+    visible to a token at ``positions`` (B, S) iff ``l <= position``:
+    the paged-attention kernel where it is on, else the gather path,
+    which is also the kernel's oracle.  (B, H, S, D)."""
+    from bigdl_tpu.ops.attention import (expand_kv_heads, paged_attention,
+                                         paged_attention_enabled,
+                                         paged_pool_dims)
+    if paged_attention_enabled():
+        # r14: gather + masked attention in ONE Pallas kernel — the
+        # page table rides in as a scalar-prefetch operand and the
+        # index map does the gather, so the contiguous (B, H, L, D)
+        # view below never exists in HBM.  Same math operation for
+        # operation (trash zeroing, validity mask, f32 softmax):
+        # parity with the gather path is regression-gated.
+        with jax.named_scope("attn.paged"):
+            return paged_attention(q, ck, cv, pages, positions, scale,
+                                   num_kv_heads=num_kv_heads)
+    # gather the row's pages into a contiguous (B, H, L, D) view
+    # (L = Lp * ps), trash-mapped positions zeroed — the jnp fallback
+    # (non-Pallas backends) and the kernel's parity oracle
+    head_dim = q.shape[-1]
+    lp, ps = pages.shape[1], paged_pool_dims(ck)[0]
+    with jax.named_scope("attn.paged"):
+        kk = pages_view(ck, pages, num_kv_heads, head_dim)
+        vv = pages_view(cv, pages, num_kv_heads, head_dim)
+    kk, vv = expand_kv_heads(q, kk, vv)         # (B, H, L, D)
+    scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
+    valid = (jnp.arange(lp * ps)[None, None, :]
+             <= positions[:, :, None])          # (B, S, L)
+    scores = jnp.where(valid[:, None], scores, -jnp.inf)
+    w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    return jnp.einsum("bhsl,bhld->bhsd", w.astype(vv.dtype), vv)
+
+
 class MultiHeadAttention(Module):
     """Multi-head self-attention over (batch, seq, embed) inputs.
 
@@ -316,14 +352,10 @@ class MultiHeadAttention(Module):
         if self.rope:
             q = apply_rope(q, positions, self.rope_theta)
             k = apply_rope(k, positions, self.rope_theta)
-        from bigdl_tpu.ops.attention import (expand_kv_heads,
-                                             paged_attention,
-                                             paged_attention_enabled,
-                                             paged_pool_dims)
+        from bigdl_tpu.ops.attention import paged_pool_dims
         ps, _ = paged_pool_dims(cache["k"])
         trash = cache["k"].shape[0] - 1
         pages = jnp.asarray(pages, jnp.int32)
-        lp = pages.shape[1]
 
         phys, offs = _page_slots(pages, positions, ps, trash, active)
 
@@ -332,34 +364,8 @@ class MultiHeadAttention(Module):
         with jax.named_scope("kv.write"):
             ck = _write_rows(cache["k"], k, phys, offs)
             cv = _write_rows(cache["v"], v, phys, offs)
-        scale = 1.0 / math.sqrt(self.head_dim)
-        if paged_attention_enabled():
-            # r14: gather + masked attention in ONE Pallas kernel — the
-            # page table rides in as a scalar-prefetch operand and the
-            # index map does the gather, so the contiguous (B, H, L, D)
-            # view below never exists in HBM.  Same math operation for
-            # operation (trash zeroing, validity mask, f32 softmax):
-            # parity with the gather path is regression-gated.
-            with jax.named_scope("attn.paged"):
-                o = paged_attention(q, ck, cv, pages, positions, scale,
-                                    num_kv_heads=self.num_kv_heads)
-        else:
-            # gather the row's pages into a contiguous (B, H, L, D)
-            # view (L = Lp * ps), trash-mapped positions zeroed — the
-            # jnp fallback (non-Pallas backends) and the kernel's
-            # parity oracle
-            with jax.named_scope("attn.paged"):
-                kk = pages_view(ck, pages, self.num_kv_heads,
-                                self.head_dim)
-                vv = pages_view(cv, pages, self.num_kv_heads,
-                                self.head_dim)
-            kk, vv = expand_kv_heads(q, kk, vv)         # (B, H, L, D)
-            scores = jnp.einsum("bhsd,bhld->bhsl", q, kk) * scale
-            valid = (jnp.arange(lp * ps)[None, None, :]
-                     <= positions[:, :, None])          # (B, S, L)
-            scores = jnp.where(valid[:, None], scores, -jnp.inf)
-            w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            o = jnp.einsum("bhsl,bhld->bhsd", w.astype(vv.dtype), vv)
+        o = _paged_read(q, ck, cv, pages, positions,
+                        1.0 / math.sqrt(self.head_dim), self.num_kv_heads)
         y = _proj(self._merge(o), params["wo"],
                   params["bo"] if self.with_bias else None)
         return y, {"k": ck, "v": cv}
@@ -607,3 +613,243 @@ class LatentAttention(Module):
         y = _proj(o.transpose(0, 2, 1, 3).reshape(b, s, -1)
                   .astype(input.dtype), params["wo"])
         return y, state
+
+
+def _band_attention(q, k, v, scale, window: int,
+                    score_bytes: int = 1 << 27):
+    """Exact causal grouped-query attention inside a WINDOW of a call's
+    tokens over themselves, ``q`` (B, H, T, D) against ``k``, ``v`` (B,
+    Hkv, T, D) (query head ``a`` reads KV head ``a // (H / Hkv)``): token
+    ``i`` sees the keys ``j <= i`` with ``i - j < window``.  A block of
+    query rows at a time, each multiplying only the ``block + window``
+    keys its band touches (one ``(B, H, block, block + window)`` float32
+    score tile live, at most ``score_bytes``); float32 softmax."""
+    b, h, t, d = q.shape
+    hkv = k.shape[1]
+    block = next(c for c in (256, 128, 64, 32, 16, 8, 4, 2, 1)
+                 if t % c == 0
+                 and (b * h * c * (c + window) * 4 <= score_bytes or c == 1))
+    span = block + window
+    qg = q.reshape(b, hkv, h // hkv, t, d)
+    # keys in front of position 0: masked, there so that every block
+    # slices the same number of keys
+    k, v = (jnp.pad(a, ((0, 0), (0, 0), (window, 0), (0, 0)))
+            for a in (k, v))
+
+    def rows(i):
+        qs = jax.lax.dynamic_slice_in_dim(qg, i * block, block, axis=3)
+        ks, vs = (jax.lax.dynamic_slice_in_dim(a, i * block, span, axis=2)
+                  for a in (k, v))
+        at = i * block + jnp.arange(block)[:, None]          # query positions
+        key = i * block - window + jnp.arange(span)[None]
+        seen = (key <= at) & (at - key < window) & (key >= 0)
+        s = jnp.einsum("bkgqd,bksd->bkgqs", qs, ks,
+                       preferred_element_type=jnp.float32) * scale
+        w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bkgqs,bksd->bkgqd", w.astype(vs.dtype), vs)
+
+    out = jax.lax.map(rows, jnp.arange(t // block))   # (N, B, Hkv, G, blk, D)
+    return out.transpose(1, 2, 3, 0, 4, 5).reshape(b, h, t, d)
+
+
+class GroupedQueryAttention(Module):
+    """Grouped-query softmax attention with a STATED head size, one module
+    for the two kinds of layer a window/full pattern model mixes.  Per
+    token, ``x`` the normed input: ``q = Wq x`` as ``H`` heads of ``D``,
+    ``k = Wk x``, ``v = Wv x`` as ``Hkv`` heads of ``D``; an RMS norm
+    over each head's ``D`` query and key channels (one weight vector of
+    ``D`` each, shared by the heads); with ``rope`` the rotary
+    embedding by half-split pairs ``(i, i + D/2)`` on q and k; scores
+    ``q_i . k_j / sqrt(D)`` where ``j <= i`` and, with a ``window``,
+    ``i - j < window`` (a token and the ``window - 1`` before it);
+    softmax in float32; query head ``a`` reads KV head ``a // (H /
+    Hkv)``; ``Wo`` on the heads' weighted values.  No biases.
+
+    What is kept between calls follows from the kind.  WITHOUT a window
+    the layer serves through the page pool exactly as
+    ``MultiHeadAttention`` does (``init_paged_cache``,
+    ``apply_decode_pages``: the same write, the same paged-attention
+    kernel at ``Hkv`` heads).  WITH a window it holds a RING a slot
+    (``init_slot_state``, ``apply_slots``): K and V ``(slots, window,
+    Hkv x D)``, token ``p`` at ring row ``p % window`` — keys are stored
+    roped, so their order in the ring does not matter — and maps no
+    page, whatever the row's length.  Either way an input longer than
+    one token is a prefill FROM POSITION 0 that attends over its own
+    tokens: inside the band (``_band_attention``) or, without a window,
+    through ``ops.fused_attention`` (on a TPU the streaming kernel: an
+    8,192-token prompt's scores, 17 GB in float32, never exist)."""
+
+    def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int = 128, window: Optional[int] = None,
+                 rope: bool = True, rope_theta: float = 1e6,
+                 eps: float = 1e-5):
+        super().__init__()
+        assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.embed_dim = embed_dim
+        self.num_heads = num_heads
+        self.num_kv_heads = num_kv_heads
+        self.head_dim = head_dim
+        self.window = None if window is None else int(window)
+        self.rope = rope
+        self.rope_theta = float(rope_theta)
+        self.eps = eps
+        self.scale = 1.0 / math.sqrt(head_dim)
+
+    def init_params(self, rng):
+        ks = jax.random.split(rng, 4)
+        e, d = self.embed_dim, self.head_dim
+
+        def w(k, out, fan_in):
+            return jax.random.normal(k, (out, fan_in)) * fan_in ** -0.5
+
+        return {"wq": w(ks[0], self.num_heads * d, e),
+                "wk": w(ks[1], self.num_kv_heads * d, e),
+                "wv": w(ks[2], self.num_kv_heads * d, e),
+                "wo": w(ks[3], e, self.num_heads * d),
+                "q_norm": {"weight": jnp.ones((d,), jnp.float32)},
+                "k_norm": {"weight": jnp.ones((d,), jnp.float32)}}
+
+    # -- what the layer keeps -------------------------------------------------
+
+    def init_paged_cache(self, num_pages: int, page_size: int,
+                         dtype=jnp.float32):
+        """A full layer's page pools, ``MultiHeadAttention``'s layout:
+        ``(num_pages + 1, page_size, W)`` K and V, the trash page last."""
+        from bigdl_tpu.ops.attention import paged_pool_width
+        shape = (num_pages + 1, page_size,
+                 paged_pool_width(self.num_kv_heads, self.head_dim))
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def init_slot_state(self, num_slots: int, dtype=jnp.float32):
+        """A window layer's rings: K and V ``(num_slots, window, Hkv x
+        D)``, a token's heads side by side as in a pool's row."""
+        shape = (num_slots, self.window, self.num_kv_heads * self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    # -- the layer --------------------------------------------------------------
+
+    def _head_norm(self, x, weight):
+        xf = x.astype(jnp.float32)
+        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                + self.eps) * weight.astype(jnp.float32)
+        return xf.astype(x.dtype)
+
+    def _qkv(self, params, x, positions):
+        """q (B, H, S, D), k and v (B, Hkv, S, D) of the tokens of ``x``
+        at ``positions`` (B, S): normed and roped as the layer says."""
+        b, s, _ = x.shape
+        d = self.head_dim
+        q = _proj(x, params["wq"]).reshape(b, s, self.num_heads, d)
+        k = _proj(x, params["wk"]).reshape(b, s, self.num_kv_heads, d)
+        v = _proj(x, params["wv"]).reshape(b, s, self.num_kv_heads, d)
+        q = self._head_norm(q, params["q_norm"]["weight"])
+        k = self._head_norm(k, params["k_norm"]["weight"])
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        if self.rope:
+            q = apply_rope(q, positions, self.rope_theta)
+            k = apply_rope(k, positions, self.rope_theta)
+        return q, k, v
+
+    def _own_tokens(self, q, k, v):
+        """Causal attention of a call's tokens over themselves."""
+        if self.window is not None:
+            return _band_attention(q, k, v, self.scale, self.window)
+        from bigdl_tpu.ops import fused_attention
+        return fused_attention(q, k, v, causal=True, scale=self.scale,
+                               needs_backward=False)
+
+    def _out(self, params, o):
+        b, h, s, d = o.shape
+        with jax.named_scope("out"):
+            return _proj(o.transpose(0, 2, 1, 3).reshape(b, s, h * d),
+                         params["wo"])
+
+    def apply_decode_pages(self, params, x_t, cache, pages, pos, active):
+        """A FULL layer: ``MultiHeadAttention.apply_decode_pages``'s
+        contract (writes of inactive rows and unmapped positions go to
+        the trash page; one token reads its row's pages through the
+        kernel or the gather).  Returns (y (B, S, E), cache')."""
+        from bigdl_tpu.ops.attention import paged_pool_dims
+        s = x_t.shape[1]
+        positions = jnp.asarray(pos)[:, None] + jnp.arange(s)    # (B, S)
+        with jax.named_scope("qkv"):
+            q, k, v = self._qkv(params, x_t, positions)
+        ps, trash = paged_pool_dims(cache["k"])[0], cache["k"].shape[0] - 1
+        pages = jnp.asarray(pages, jnp.int32)
+        phys, offs = _page_slots(pages, positions, ps, trash, active)
+        with jax.named_scope("kv.write"):
+            ck = _write_rows(cache["k"], k, phys, offs)
+            cv = _write_rows(cache["v"], v, phys, offs)
+        if s > 1:
+            with jax.named_scope("attn.prefill"):
+                o = self._own_tokens(q, k, v)
+        else:
+            o = _paged_read(q, ck, cv, pages, positions, self.scale,
+                            self.num_kv_heads)
+        return self._out(params, o.astype(x_t.dtype)), {"k": ck, "v": cv}
+
+    def apply_slots(self, params, x, st, pos, active, lengths=None):
+        """A WINDOW layer: ``x`` (B, S, E) at positions ``[pos_b, pos_b +
+        S)`` against the rows' rings ``st`` (``init_slot_state``'s tree, B
+        rows).  One token is written at ring row ``pos % window`` and
+        attends over the ring's rows ``j <= pos`` (all of them once the
+        ring has wrapped).  A longer input is a prefill from position 0:
+        it attends inside the band over its own tokens and leaves the
+        last ``min(n, window)`` of its ``n = lengths_b`` REAL tokens in
+        the ring (a bucket's padding never reaches it).  An inactive
+        row's ring comes back bit for bit.  Returns (y, st')."""
+        b, s, _ = x.shape
+        w, hkv, d = self.window, self.num_kv_heads, self.head_dim
+        pos = jnp.asarray(pos, jnp.int32)
+        positions = pos[:, None] + jnp.arange(s)
+        keep = jnp.asarray(active)
+        with jax.named_scope("qkv"):
+            q, k, v = self._qkv(params, x, positions)
+        dt = st["k"].dtype
+        rk, rv = (a.transpose(0, 2, 1, 3).reshape(b, s, hkv * d).astype(dt)
+                  for a in (k, v))
+        if s == 1:
+            with jax.named_scope("kv.write"):
+                # an inactive row's index lies past the ring: dropped
+                at = jnp.where(keep, pos % w, w)
+                ring_k, ring_v = (
+                    ring.at[jnp.arange(b), at].set(new[:, 0], mode="drop")
+                    for ring, new in ((st["k"], rk), (st["v"], rv)))
+            with jax.named_scope("attn.ring"):
+                qg = q[:, :, 0].reshape(b, hkv, self.num_heads // hkv, d)
+                sc = jnp.einsum("bkgd,bskd->bkgs", qg,
+                                ring_k.reshape(b, w, hkv, d),
+                                preferred_element_type=jnp.float32) \
+                    * self.scale
+                seen = jnp.arange(w)[None] <= pos[:, None]       # (B, W)
+                wt = jax.nn.softmax(
+                    jnp.where(seen[:, None, None], sc, -jnp.inf), axis=-1)
+                o = jnp.einsum("bkgs,bskd->bkgd", wt.astype(dt),
+                               ring_v.reshape(b, w, hkv, d),
+                               preferred_element_type=jnp.float32)
+                o = o.reshape(b, self.num_heads, 1, d)
+        else:
+            n = jnp.full((b,), s, jnp.int32) if lengths is None \
+                else jnp.asarray(lengths, jnp.int32)
+            with jax.named_scope("kv.write"):
+                # ring row r holds the last real position congruent to it
+                r = jnp.arange(w)[None]
+                src = r + w * ((n[:, None] - 1 - r) // w)        # (B, W)
+                held = (r < n[:, None]) & keep[:, None]
+                ring_k, ring_v = (
+                    jnp.where(held[..., None], jnp.take_along_axis(
+                        new, jnp.clip(src, 0, s - 1)[..., None], axis=1),
+                        ring)
+                    for ring, new in ((st["k"], rk), (st["v"], rv)))
+            with jax.named_scope("attn.prefill"):
+                o = self._own_tokens(q, k, v)
+        return self._out(params, o.astype(x.dtype)), \
+            {"k": ring_k, "v": ring_v}
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        """A whole sequence from position 0; nothing kept."""
+        b, s, _ = input.shape
+        q, k, v = self._qkv(params, input,
+                            jnp.broadcast_to(jnp.arange(s), (b, s)))
+        return self._out(params, self._own_tokens(q, k, v)
+                         .astype(input.dtype)), state

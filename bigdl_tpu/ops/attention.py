@@ -843,6 +843,13 @@ _PAGED_BATCHED_SCORES = 4 * 1024 * 1024
 # the pool has one layout, so nothing else of it lives in VMEM, and the
 # declaration is a limit, not an allocation.
 _PAGED_VMEM = (40 * 1024 * 1024, 48 * 1024 * 1024)
+# A second pair for the call that the first would split into lane groups
+# (long tables of wide rows: 640 pages of 8 KV heads of 128 hold 84 MB of
+# K and V scratch for two rows).  A page copied in lane-group slices costs
+# as many copies again as there are groups, each of short rows (512 B at
+# four groups) where the whole page is one contiguous 32 KB, and the copies
+# are what a decode step's walk costs; a v5e's VMEM is 128 MiB.
+_PAGED_VMEM_WIDE = (104 * 1024 * 1024, 112 * 1024 * 1024)
 # key slots of one block of the walk: one MXU tile of keys
 _PAGED_BLOCK_SLOTS = 128
 
@@ -905,13 +912,20 @@ def _paged_tiling(hkv, group, queries, length, d, ps, itemsize):
     slots = -(-length // bs) * bs
     few = _paged_few(hkv, group, queries, length)
     rows = few and hkv > 1
-    budget, limit = _PAGED_VMEM
-    for groups in range(1, n_chunks + 1):
-        per = n_chunks // groups
-        if n_chunks % groups == 0 and _paged_step_bytes(
-                per * chunk, per * heads, group, queries, slots, itemsize,
-                rows, few) <= budget:
-            break
+
+    def fewest(budget):
+        return next(g for g in range(1, n_chunks + 1)
+                    if n_chunks % g == 0 and (g == n_chunks
+                    or _paged_step_bytes(
+                        n_chunks // g * chunk, n_chunks // g * heads, group,
+                        queries, slots, itemsize, rows, few) <= budget))
+
+    groups, limit = fewest(_PAGED_VMEM[0]), _PAGED_VMEM[1]
+    if groups > 1:
+        wide = fewest(_PAGED_VMEM_WIDE[0])
+        if wide < groups:
+            groups, limit = wide, _PAGED_VMEM_WIDE[1]
+    per = n_chunks // groups
     return groups, (per * chunk if rows else chunk), rows, limit
 
 

@@ -370,6 +370,19 @@ class MixtureOfExperts(Module):
 #: multiplications it adds stay under the time of that read up to about
 #: 240 tokens at these widths on a v5e)
 DENSE_TOKENS = 128
+#: past this many elements in the sorted pairs' gathered inputs (T x k x E)
+#: the grouped product goes BLOCK by block of sorted pairs and stops at the
+#: last pair routed here: a long prefill of wide tokens (8,192 x 8 x 6,144)
+#: would otherwise hold every pair's input, hidden and output row at once
+#: (3.2 GB) when an eighth of them belong to experts held here.  Below it
+#: the whole-sorted product stays, because the blocked one's loop and
+#: scatter-add cost more than the rows they spare: each alone on a v5e
+#: (PR 33) the blocked one is 4-19% SLOWER at 2^24.3-2^24.6 elements
+#: (5.25 -> 5.46, 5.72 -> 6.04, 4.71 -> 5.59 ms) and 11-28% faster from
+#: 2^25.3 up (7.60 -> 6.75, 9.34 -> 6.98, 15.5 -> 11.1, 26.9 -> 19.8 ms)
+PAIR_ELEMENTS = 1 << 26
+PAIR_BLOCK = 4096           # sorted pairs a block
+
 
 def sigmoid_group_route(scores, bias, k: int, n_group: int, topk_group: int,
                         scale: float = 1.0):
@@ -433,6 +446,10 @@ def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
                     preferred_element_type=jnp.float32)
         return y.astype(x.dtype), counters
     order = jnp.argsort(key, stable=True)
+    if t * k * x.shape[1] > PAIR_ELEMENTS:
+        return _held_pairs_blocked(x, order, sizes, n_here,
+                                   jnp.where(here, gates, 0.0), w_gate_up,
+                                   w_down), counters
     xs = x[order // k]                                       # sorted pairs
     h = lax.ragged_dot(xs, w_gate_up, sizes,
                        preferred_element_type=jnp.float32)
@@ -445,3 +462,40 @@ def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
     y = ys[jnp.argsort(order)].reshape(t, k, -1)             # unsort
     y = jnp.sum(y * jnp.where(here, gates, 0.0)[..., None], axis=1)
     return y.astype(x.dtype), counters
+
+
+def _held_pairs_blocked(x, order, sizes, n_here, gates, w_gate_up, w_down):
+    """``held_experts_apply``'s grouped product over the FIRST ``n_here``
+    of the pairs sorted by expert (``order``; the pairs of absent experts
+    and of padding sort behind them), `PAIR_BLOCK` pairs a turn: a block's
+    inputs gathered, multiplied group by group with the part of each
+    expert's group that falls inside it, gated and added onto their
+    tokens' rows of a float32 sum.  The turns are as many as the pairs
+    routed here need, so neither the time nor the temporaries follow the
+    pairs routed elsewhere.  ``order`` is padded to whole blocks: a pad
+    lies past ``n_here`` and adds nothing."""
+    t, k = gates.shape
+    f = w_down.shape[1]
+    order = jnp.pad(order, (0, -(t * k) % PAIR_BLOCK))
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    flat = gates.reshape(-1)
+
+    def turn(i, y):
+        lo = i * PAIR_BLOCK
+        pair = jax.lax.dynamic_slice_in_dim(order, lo, PAIR_BLOCK)
+        token = pair // k
+        part = (jnp.clip(ends, lo, lo + PAIR_BLOCK)
+                - jnp.clip(starts, lo, lo + PAIR_BLOCK)).astype(jnp.int32)
+        h = lax.ragged_dot(x[token], w_gate_up, part,
+                           preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+        ys = lax.ragged_dot(h, w_down, part,
+                            preferred_element_type=jnp.float32)
+        live = lo + jnp.arange(PAIR_BLOCK) < n_here
+        ys = jnp.where(live[:, None], ys * flat[pair][:, None], 0.0)
+        return y.at[token].add(ys)
+
+    y = jax.lax.fori_loop(0, -(-n_here // PAIR_BLOCK), turn,
+                          jnp.zeros((t, x.shape[1]), jnp.float32))
+    return y.astype(x.dtype)

@@ -590,6 +590,14 @@ class ContinuousGenerator:
         # recurrent state of ONE slot
         self._state_bytes = _row_bytes(self._cache["slots"]) \
             if self._recurrent else 0
+        # the same two by kind of layer, where the model can tell (a
+        # window layer's ring and a recurrent state are both a slot's)
+        by_kind = getattr(model, "state_bytes", None)
+        self._bytes_by_kind = by_kind(self._cache) if by_kind else None
+        # a model that mixes window and full attention layers says how
+        # many keys a window layer keeps
+        self._window = getattr(model, "window", None) \
+            if self._recurrent else None
         self._moe_pairs = 0
         self._moe_hit = 0
         self._chunks = 0
@@ -1815,12 +1823,22 @@ class ContinuousGenerator:
         ``state_rows``, the row-steps that updated a state, and
         ``latent_tokens``, the context tokens an attention layer read
         over them (a row at position p reads p + 1), from the positions
-        the chunk started at (``pos`` is where it ended)."""
+        the chunk started at (``pos`` is where it ended).  A model that
+        declares a ``window`` gets the same sum by kind of layer:
+        ``full_tokens`` (p + 1 a row-step, what a full layer read) and
+        ``window_tokens`` (``min(p + 1, window)``, a window layer's)."""
         if not (self._recurrent and run_ledger.enabled()):
             return {}
         n = emitted.sum(axis=0).astype(np.int64)         # steps a row ran
-        ctx = n * (pos.astype(np.int64) - n + 1) + n * (n - 1) // 2
-        return {"state_rows": int(n.sum()), "latent_tokens": int(ctx.sum())}
+        first = pos.astype(np.int64) - n                 # where a row began
+        ctx = n * (first + 1) + n * (n - 1) // 2
+        out = {"state_rows": int(n.sum()), "latent_tokens": int(ctx.sum())}
+        if self._window:
+            steps = first[None] + np.arange(emitted.shape[0])[:, None]
+            seen = np.minimum(steps + 1, self._window)
+            out.update(full_tokens=out["latent_tokens"],
+                       window_tokens=int(seen[steps < pos[None]].sum()))
+        return out
 
     def _counter_attrs(self, counts) -> dict:
         """The model's counters of one program run as span attributes;
@@ -2063,10 +2081,13 @@ class ContinuousGenerator:
         the pool would show as its size here), the lanes of a token's
         row and the bytes of the pools as they lie on the device (trash
         page and padding lanes included)."""
-        return {"program_temp_bytes": dict(self._program_temp),
-                "pool_width": self._pool_width,
-                "pool_padded_bytes":
-                    (self._alloc.num_pages + 1) * self._page_bytes}
+        out = {"program_temp_bytes": dict(self._program_temp),
+               "pool_width": self._pool_width,
+               "pool_padded_bytes":
+                   (self._alloc.num_pages + 1) * self._page_bytes}
+        if self._bytes_by_kind is not None:
+            out["bytes_by_kind"] = self._bytes_by_kind
+        return out
 
     def _evict(self, slot: int, status: str) -> None:
         """Finish the request in ``slot`` and free it for the next
@@ -2221,6 +2242,9 @@ class ContinuousGenerator:
             out["state"] = {
                 "bytes_per_slot": self._state_bytes,
                 "bytes": self.slots.num_slots * self._state_bytes}
+            if self._bytes_by_kind is not None:
+                out["state"]["bytes_per_slot_by_kind"] = \
+                    self._bytes_by_kind["slot"]
         out["prefix"] = (self._prefix.stats()
                          if self._prefix is not None else None)
         with self._lock:

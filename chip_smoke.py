@@ -18,6 +18,11 @@ seed:
                  and 1,024 decode steps, LOG-PROBS against the plain float32
                  reference, the router against the reference's, and two
                  controls (bf16 state, bf16 router) that have to fail
+3c. **window**   the same drive for a window layer's ring beside a full
+                 layer's pages, at the published widths of
+                 ``k_exaone_236b``: a 200-token prefill (the ring wrapped
+                 once) and 300 decode steps (twice more), and the control
+                 of a window off by one
 4. **kernels**   every Pallas kernel a TPU backend switches on without an
                  opt-in variable, compiled and compared with its jnp
                  reference inside the tolerances below
@@ -34,6 +39,7 @@ run off the chip.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import os
@@ -319,18 +325,42 @@ def _rounded(fn, index):
     return wrapped
 
 
+def _bf16_state(model):
+    """The control of the hybrid phase: the delta-rule state rounded to
+    bf16 after every update."""
+    from bigdl_tpu.nn import linear_attention
+    stack = contextlib.ExitStack()
+    for name in ("kda_step", "kda_chunked"):
+        stack.enter_context(mock.patch.object(
+            linear_attention, name,
+            _rounded(getattr(linear_attention, name), 1)))
+    return stack
+
+
+def _window_off_by_one(model):
+    """The control of the window phase: every window layer keeps and reads
+    one key more than the configuration's window."""
+    stack = contextlib.ExitStack()
+    for m in model.mixers:
+        if getattr(m, "window", None):
+            stack.enter_context(mock.patch.object(m, "window", m.window + 1))
+    return stack
+
+
 def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
                  overrides=None, reference_kw=None, prompt_len=512, steps=1024,
                  slots=8, max_len=2048, buckets=(512,), compiled=True,
                  tolerance=HYBRID_TOLERANCE,
-                 router_tolerance=ROUTER_TOLERANCE, dtype="bfloat16") -> str:
+                 router_tolerance=ROUTER_TOLERANCE, dtype="bfloat16",
+                 control=("bf16_state", _bf16_state)) -> str:
     """Prefill of ``prompt_len`` tokens, then ``steps`` decode steps, the
     way ``ContinuousGenerator``'s two programs call the model (slot-
     addressed prefill from position 0 with its real length; whole-batch
     decode steps with the other rows inactive), against the plain
     reference's full forward over the same tokens, on LOG-PROBS; the
     router against the reference's on one hidden state; the same request
-    through the generator itself; and the two controls."""
+    through the generator itself; and the two controls (``control``: the
+    name and the patch of the one that the per-slot state has to fail)."""
     import importlib
 
     import jax
@@ -338,7 +368,6 @@ def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
     import numpy as np
 
     from bigdl_tpu.models import hybrid
-    from bigdl_tpu.nn import linear_attention
     from bigdl_tpu.serving.scheduler import ContinuousGenerator
 
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -422,12 +451,11 @@ def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
     tight = gap(params, logp, toks)
     check(inside(tight, tolerance), f"served log-probs (median, max) "
           f"{tight} std from the reference's (tolerance {tolerance})")
-    with mock.patch.object(linear_attention, "kda_step",
-                           _rounded(linear_attention.kda_step, 1)), \
-            mock.patch.object(linear_attention, "kda_chunked",
-                              _rounded(linear_attention.kda_chunked, 1)):
+    control_name, patched = control
+    with patched(model):
         state_bf16 = gap(params, *served(params))
-    check(not inside(state_bf16, tolerance), f"the bf16-state control "
+    check(not inside(state_bf16, tolerance),
+          f"the {control_name.replace('_', '-')} control "
           f"passed the tolerance {tolerance}: {state_bf16}")
 
     # 2. the router against the reference's, one hidden state
@@ -483,12 +511,41 @@ def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
           f"paged kernel compiled={_paged_kernel_compiled()}")
     fmt = "({:.4f}, {:.4f})".format
     return (f"prompt={prompt_len} steps={steps} gap_median_max="
-            f"{fmt(*tight)} (tolerance {tolerance}) bf16_state="
+            f"{fmt(*tight)} (tolerance {tolerance}) {control_name}="
             f"{fmt(*state_bf16)} "
             f"router_agree={agree:.4f} gates_off={gates_off:.2e} "
             f"(tolerance {router_tolerance}) bf16_router=({agree_bf16:.4f}, "
             f"{gates_bf16:.2e}) state_bytes_per_slot="
             f"{st['state']['bytes_per_slot']}")
+
+
+# The window phase's tolerance, read as the hybrid phase's: (median,
+# maximum) over 301 positions of max |served - reference| log-prob over
+# the std of the reference's logits.  Two layers at the published widths
+# of ``k_exaone_236b`` (a window layer and a full one, each with its 16
+# held experts), bf16 around a float32 router.  Readings on a v5e (PR 33):
+# served (0.0205, 0.1801), the control in which every window layer keeps
+# and reads 129 keys (0.0625, 0.4032); the tolerance lies between (the
+# geometric means), and the control has to FAIL it.
+WINDOW_TOLERANCE = (0.036, 0.27)
+
+
+def phase_window(config="benchmark/configs/k_exaone_236b.json", vocab=None,
+                 overrides=None, reference_kw=None, prompt_len=200, steps=300,
+                 slots=8, max_len=1024, buckets=(256,), compiled=True,
+                 tolerance=WINDOW_TOLERANCE, dtype="bfloat16") -> str:
+    """``phase_hybrid``'s drive on a two-layer ``swa`` + ``full`` pattern
+    at the published widths: the prompt wraps the ring once, the decode
+    steps twice more, so every ring row has been overwritten in place
+    before the last position is compared."""
+    two = {"num_layers": 2, "layers": [["swa", "experts"],
+                                       ["full", "experts"]]}
+    return phase_hybrid(
+        config, vocab, dict(two, **(overrides or {})),
+        dict({"layer_types": ("sliding_attention", "full_attention")},
+             **(reference_kw or {})),
+        prompt_len, steps, slots, max_len, buckets, compiled, tolerance,
+        dtype=dtype, control=("window_off_by_one", _window_off_by_one))
 
 
 # -- phase 4: kernels ---------------------------------------------------------
@@ -933,6 +990,7 @@ def main() -> int:
                         ("train", phase_train),
                         ("serve", phase_serve),
                         ("hybrid", phase_hybrid),
+                        ("window", phase_window),
                         ("kernels", phase_kernels),
                         ("multichip", phase_multichip)):
         t0, (c0, h0, m0) = time.time(), meter.snapshot()

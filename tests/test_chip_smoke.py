@@ -159,3 +159,22 @@ def test_phase_hybrid_toy(interpret_mode):
     assert "bf16_state=" in line and "router_agree=1.0000" in line
     with pytest.raises(chip_smoke.SmokeFailure, match="bf16-state control"):
         chip_smoke.phase_hybrid(steps=4, tolerance=(9.0, 9.0), **kw)
+
+
+def test_phase_window_toy(interpret_mode):
+    """The window phase at toy widths in float32: a ring of 8 wrapped once
+    by the prompt and five times more by the decode steps agrees with the
+    reference to rounding, and a window off by one fails; a tolerance that
+    control passes fails the phase."""
+    toy = dict(max_len=128, embed_dim=64, num_heads=4, num_kv_heads=2,
+               head_dim=16, ffn_dim=96, expert_dim=24, num_experts=16,
+               experts_per_token=4, experts_held=8, window=8)
+    kw = dict(vocab=97, overrides=toy,
+              reference_kw=dict(num_experts_per_tok=4, sliding_window=8),
+              prompt_len=13, slots=3, max_len=128, buckets=(16,),
+              compiled=False, dtype="float32")
+    line = chip_smoke.phase_window(steps=40, tolerance=(2e-4, 2e-3), **kw)
+    assert "window_off_by_one=" in line and "router_agree=1.0000" in line
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="window-off-by-one control"):
+        chip_smoke.phase_window(steps=4, tolerance=(9.0, 9.0), **kw)

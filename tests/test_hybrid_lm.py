@@ -476,7 +476,8 @@ def test_stats_and_budget_tell_pages_from_slot_state(toy):
         st = gen.stats()
         per_slot = 2 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 4)   # two KDA layers
         assert st["state"] == {"bytes_per_slot": per_slot,
-                               "bytes": 3 * per_slot}
+                               "bytes": 3 * per_slot,
+                               "bytes_per_slot_by_kind": {"kda": per_slot}}
         # pages stay pages: two latent pools of 16 tokens a page, their
         # 40 lanes padded to a whole tile, float32
         assert paged_pool_width(1, 40) == 128

@@ -794,9 +794,12 @@ class TestPagedAttention:
         if groups == 2:
             # room for one chunk (two heads of 64) a step, not for two
             block = A.paged_block_pages(ps, lp) * ps
-            monkeypatch.setattr(A, "_PAGED_VMEM", (A._paged_step_bytes(
+            small = (A._paged_step_bytes(
                 128, 2, 2, s, -(-lp * ps // block) * block, 4, rows, rows),
-                limit))
+                limit)
+            monkeypatch.setattr(A, "_PAGED_VMEM", small)
+            # (and no wide plan to take the row whole after all)
+            monkeypatch.setattr(A, "_PAGED_VMEM_WIDE", small)
         h = 2 * hkv
         assert A._paged_tiling(hkv, 2, s, lp * ps, d, ps, 4) == (
             groups, 256 // groups if rows else 128, rows, limit)
