@@ -122,6 +122,18 @@ def build_report(records: List[dict]) -> dict:
             0.0, dur - child_time.get((sp["_pid"], sp.get("span")), 0.0))
         if sp.get("error"):
             p["errors"] += 1
+        if "ahead" in sp.get("attrs", {}):
+            # the trainers' h2d: puts started while a step was in flight
+            # (``optim/batch_ahead.py``), so the device did not wait
+            p["ahead"] = p.get("ahead", 0) + bool(sp["attrs"]["ahead"])
+    h2d = phases.get("h2d", {})
+    if "ahead" in h2d:
+        # every step takes one put, and a put in the open is taken at
+        # once: the steps that did not take one had their input ahead
+        # (the put after a run's last step is dropped and feeds none)
+        h2d["steps"] = phases.get("train.step", {}).get("count", 0)
+        h2d["steps_ahead"] = max(
+            0, h2d["steps"] - (h2d["count"] - h2d["ahead"]))
 
     # -- coverage: top-level main-thread span time inside each complete
     # run's window, over the summed window lengths
@@ -841,9 +853,13 @@ def render_report(rep: dict) -> str:
     for name, p in sorted(rep["phases"].items(),
                           key=lambda kv: -kv[1]["exclusive_s"]):
         err = f"  errors={p['errors']}" if p["errors"] else ""
+        ahead = (f"  input ahead of the device in {p['steps_ahead']}/"
+                 f"{p['steps']} steps "
+                 f"({p['steps_ahead'] / max(p['steps'], 1) * 100:.1f}%)"
+                 if "steps_ahead" in p else "")
         L.append(f"  {name:<28} {p['exclusive_s']:9.3f}s "
                  f"({p['exclusive_s'] / wall * 100:5.1f}%)  "
-                 f"x{p['count']}{err}")
+                 f"x{p['count']}{err}{ahead}")
     s = rep["steps"]
     L.append("")
     L.append("-- steps --")

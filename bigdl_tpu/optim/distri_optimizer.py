@@ -31,6 +31,7 @@ import math
 import os
 import time
 from contextlib import nullcontext as _nullcontext
+from functools import partial
 from typing import Optional
 
 import jax
@@ -42,10 +43,9 @@ from bigdl_tpu.engine import Engine
 from bigdl_tpu.observability import costs
 from bigdl_tpu.observability import ledger as run_ledger
 from bigdl_tpu.observability import tracer
-from bigdl_tpu.optim.local_optimizer import (LocalOptimizer,
-                                             _base_dataset,
-                                             _host_nbytes,
-                                             _sync_shuffles)
+from bigdl_tpu.optim.batch_ahead import (BatchAhead, _base_dataset,
+                                         _sync_shuffles)
+from bigdl_tpu.optim.local_optimizer import LocalOptimizer
 from bigdl_tpu.parallel import mesh as mesh_mod
 from bigdl_tpu.parallel.allreduce import (make_distri_eval_fn,
                                           make_distri_eval_from_shard,
@@ -56,6 +56,16 @@ from bigdl_tpu.resilience.watchdog import Watchdog
 logger = logging.getLogger("bigdl_tpu.optim")
 
 _SHARDING_MODES = ("auto", "flat", "spec")
+
+
+def _host_pair(batch):
+    """``(data, labels)`` of a MiniBatch as host arrays.  A batch the
+    ingest already put on the device (``ShardedDataSet(staging=True,
+    sharding=...)``, ``PrefetchToDevice``) passes as it is: ``np.asarray``
+    would force it BACK to the host."""
+    if jax.process_count() == 1 and isinstance(batch.data, jax.Array):
+        return batch.data, batch.labels
+    return np.asarray(batch.data), np.asarray(batch.labels)
 
 
 def _fetch_global(arr) -> np.ndarray:
@@ -279,11 +289,11 @@ class DistriOptimizer(LocalOptimizer):
             return None
         return self.dataset.shard_iterators(train=True)
 
-    def _global_batch(self, data_iter, n):
-        """Assemble one globally-sharded batch from the per-shard iterators
-        (the ZippedPartitionsWithLocalityRDD role: each mesh slot consumes
+    @staticmethod
+    def _global_batch(batches):
+        """Assemble one global batch from one batch of every shard (the
+        ZippedPartitionsWithLocalityRDD role: each mesh slot consumes
         its own partition)."""
-        batches = [next(it) for it in data_iter]
         if not hasattr(batches[0], "data"):
             raise TypeError(
                 "distributed dataset shards must yield MiniBatches — add a "
@@ -292,6 +302,68 @@ class DistriOptimizer(LocalOptimizer):
         labels = np.concatenate([np.atleast_1d(b.labels) for b in batches],
                                 axis=0)
         return data, labels
+
+    def _epoch_stream(self, by_shard: bool):
+        """One epoch's stream of ``(data, labels)`` host batches: the
+        per-shard iterators zipped into global batches when ``by_shard``
+        and the dataset has them, the dataset's own stream otherwise."""
+        shard_iters = self._shard_iterators() if by_shard else None
+        if shard_iters:
+            return map(self._global_batch, zip(*shard_iters))
+        return map(_host_pair, self.dataset.data(train=True))
+
+    def _batch_ahead(self, n: int, ds_size: int, by_shard: bool):
+        """The SPMD loops' input (``BatchAhead``): batches checked
+        against the ring and put batch-sharded over the mesh."""
+        nproc = jax.process_count()
+        sharding = mesh_mod.batch_sharding(self.mesh)
+        local_rows = None
+
+        def records_of(data) -> int:
+            nonlocal local_rows
+            if nproc > 1:
+                # every process must contribute the same number of rows
+                # per step or the global shapes diverge and the next
+                # collective hangs — fail fast locally instead
+                if local_rows is None:
+                    local_rows = data.shape[0]
+                elif data.shape[0] != local_rows:
+                    raise ValueError(
+                        f"multihost local batch changed {local_rows} -> "
+                        f"{data.shape[0]}; use drop_last batching so "
+                        "every process feeds fixed-size batches")
+            bs = data.shape[0] * nproc      # global batch
+            if bs % n != 0:
+                raise ValueError(
+                    f"global batch size {bs} must be a multiple of the "
+                    f"dp shard count {n} (data x fsdp axes; the reference "
+                    f"enforces batch % nodeNumber == 0 the same way)")
+            return bs
+
+        def put(data, labels):
+            if nproc == 1:
+                # a no-op view for a staged batch whose sharding matches
+                batch = jax.device_put((data, labels), sharding)
+            else:
+                # true multi-host: each process contributes ONLY its
+                # local rows; the global array is assembled without any
+                # host holding (or shipping) the full batch — the
+                # per-host ingest locality the reference got from
+                # partition-zipped RDDs
+                batch = tuple(
+                    jax.make_array_from_process_local_data(
+                        sharding, a, (a.shape[0] * nproc,) + a.shape[1:])
+                    for a in (data, labels))
+            # attribute H2D honestly: ahead of the step it is time the
+            # host would have spent in the running step's sync
+            return jax.block_until_ready(batch)
+
+        return BatchAhead(
+            self.dataset, partial(self._epoch_stream, by_shard),
+            records_of, put, self.metrics,
+            epoch=self.state.get("epoch", 1),
+            records_done=self.state.get("recordsProcessedThisEpoch", 0),
+            epoch_records=ds_size)
 
     def _compute_dtype(self):
         """``set_mixed_precision`` reaches the SPMD steps as their
@@ -725,88 +797,21 @@ class DistriOptimizer(LocalOptimizer):
             # resume: replay completed epochs' shuffles so the fresh dataset's
             # permutation stream matches the interrupted run's
             _sync_shuffles(self.dataset, self.state.get("epoch", 1) - 1)
-            shard_iters = self._shard_iterators()
-            flat_iter = None if shard_iters else self.dataset.data(train=True)
-            nproc = jax.process_count()
             # per-process datasets hold this host's records only; epoch
             # accounting runs on global counts
-            ds_size = self.dataset.size() * nproc
-            data_sharding = mesh_mod.batch_sharding(mesh)
+            ds_size = self.dataset.size() * jax.process_count()
+            feed = self._batch_ahead(n, ds_size, by_shard=True)
         wall_start = time.time()
 
-        # resume fast-forward: fresh iterators restart the epoch stream, so
-        # skip the records already trained this epoch — the resumed run
-        # then consumes exactly the batches an uninterrupted run would
-        records_to_skip = count_this_epoch
-        local_bs = None
         cost_done = False          # one cost.analysis per optimize()
         while not self.end_when(self.state):
-            # elastic membership poll BEFORE the batch is consumed: a
+            # elastic membership poll BEFORE the batch is taken: a
             # committed generation change aborts exactly at a step
-            # boundary (no half-consumed batch, no step in a stale world)
+            # boundary (no step in a stale world; the batch in flight
+            # from the old stream is dropped with this loop's feed)
             self._elastic_step_boundary()
-            with tracer.span("data.next"):
-                if shard_iters:
-                    data, labels = self._global_batch(shard_iters, n)
-                else:
-                    b = next(flat_iter)
-                    if nproc == 1 and isinstance(b.data, jax.Array):
-                        # staged ingest (ShardedDataSet(staging=True,
-                        # sharding=...)) already uploaded this batch —
-                        # np.asarray would force it BACK to host; the
-                        # device_put below is a no-op view when the
-                        # sharding matches
-                        data, labels = b.data, b.labels
-                    else:
-                        data, labels = (np.asarray(b.data),
-                                        np.asarray(b.labels))
-            if records_to_skip >= data.shape[0] * nproc:
-                records_to_skip -= data.shape[0] * nproc
-                continue
-            if records_to_skip > 0:
-                raise ValueError(
-                    f"resume skip remainder {records_to_skip} is smaller "
-                    f"than the global batch ({data.shape[0] * nproc}): "
-                    "the batch size changed since the snapshot; resume "
-                    "with the same batching to keep the exact-resume "
-                    "contract")
-            if nproc > 1:
-                # every process must contribute the same number of rows
-                # per step or the global shapes diverge and the next
-                # collective hangs — fail fast locally instead
-                if local_bs is None:
-                    local_bs = data.shape[0]
-                elif data.shape[0] != local_bs:
-                    raise ValueError(
-                        f"multihost local batch changed {local_bs} -> "
-                        f"{data.shape[0]}; use drop_last batching so "
-                        "every process feeds fixed-size batches")
-            bs = data.shape[0] * nproc      # global batch
-            if bs % n != 0:
-                raise ValueError(
-                    f"global batch size {bs} must be a multiple of the "
-                    f"data-axis size {n} (the reference enforces batch % "
-                    f"nodeNumber == 0 the same way)")
+            data, labels, bs = feed.take()
             t0 = time.time()
-            with tracer.span("h2d", records=bs,
-                             bytes=_host_nbytes(data, labels)):
-                if nproc > 1:
-                    # true multi-host: each process contributes ONLY its
-                    # local rows; the global array is assembled without
-                    # any host holding (or shipping) the full batch — the
-                    # per-host ingest locality the reference got from
-                    # partition-zipped RDDs
-                    data = jax.make_array_from_process_local_data(
-                        data_sharding, data, (bs,) + data.shape[1:])
-                    labels = jax.make_array_from_process_local_data(
-                        data_sharding, labels, (bs,) + labels.shape[1:])
-                else:
-                    data = jax.device_put(data, data_sharding)
-                    labels = jax.device_put(labels, data_sharding)
-                # attribute H2D honestly
-                jax.block_until_ready((data, labels))
-            t1 = time.time()
-            put_ns = (t1 - t0) * 1e9
             self._rng, sub = jax.random.split(self._rng)
             clr_val = self._current_clr()
             clr = jnp.asarray(clr_val, jnp.float32)
@@ -824,25 +829,14 @@ class DistriOptimizer(LocalOptimizer):
                             model_state, data, labels, sub,
                             jnp.asarray(stepno, jnp.int32), clr,
                             kind=type(self).__name__, sharding="flat")
-            with tracer.span("train.step", step=stepno, n=n), \
-                    Watchdog(self.step_timeout,
-                             label=f"train step {stepno} (SPMD, n={n})"):
-                if FaultInjector.should("grad.nan", stepno):
-                    # inside the span: the poison (first use compiles
-                    # full_like) is step work, not an inter-span hole in
-                    # the coverage accounting
-                    data = jnp.full_like(data, jnp.nan)  # NaN fwd -> grads
-                with tracer.span("train.dispatch"):
-                    wshard, opt_shard, model_state, loss = step(
-                        wshard, opt_shard, model_state, data, labels, sub,
-                        jnp.asarray(stepno, jnp.int32), clr)
-                # blocks: whole fused step (compute + comm) — the hang
-                # point the watchdog guards (a wedged host stalls every
-                # other host's collective exactly here)
-                with tracer.span("train.sync"):
-                    loss = float(loss)
-            compute_ns = (time.time() - t1) * 1e9
-            dt = time.time() - t0   # full iteration, for throughput
+            (wshard, opt_shard, model_state), loss = self._run_step(
+                feed, stepno, f"train step {stepno} (SPMD, n={n})", data,
+                lambda data: step(
+                    wshard, opt_shard, model_state, data, labels, sub,
+                    jnp.asarray(stepno, jnp.int32), clr),
+                n=n)
+            # the whole iteration but the first put, for throughput
+            dt = time.time() - t0
 
             # Reference metric names (DistriOptimizer.scala:115-119,
             # 148-151, 180-182, 214).  The fused XLA step has no separate
@@ -851,15 +845,14 @@ class DistriOptimizer(LocalOptimizer):
             # whole step lands under "computing time"; use
             # utils.profiler.trace for the intra-step breakdown.
             # host-side loop tail span-attributed too (see the
-            # LocalOptimizer loop): counters, logging, epoch
-            # rollover, snapshot/validation triggers
+            # LocalOptimizer loop): counters, logging, snapshot and
+            # validation triggers
             with tracer.span("loop.bookkeeping"):
                 costs.sample_hbm(step=stepno)
                 if self.skip_nonfinite and math.isnan(loss):
                     self._check_drop_budget(self._record_skipped_step())
-                self.metrics.add("computing time average", compute_ns)
-                self.metrics.add("computing time for each node", compute_ns)
-                self.metrics.add("put data into device", put_ns)
+                self.metrics.add("computing time average", dt * 1e9)
+                self.metrics.add("computing time for each node", dt * 1e9)
                 self.metrics.set("loss", loss, unit="scalar")
                 count_this_epoch += bs
                 self.state["neval"] += 1
@@ -877,11 +870,6 @@ class DistriOptimizer(LocalOptimizer):
                     self.state["epoch"] += 1
                     count_this_epoch = 0
                     self.state["recordsProcessedThisEpoch"] = 0
-                    _sync_shuffles(self.dataset, self.state["epoch"] - 1)
-                    if shard_iters:
-                        shard_iters = self._shard_iterators()
-                    else:
-                        flat_iter = self.dataset.data(train=True)
 
                 if self.sharded_checkpoint_trigger and \
                         self.sharded_checkpoint_path and \
@@ -982,7 +970,6 @@ class DistriOptimizer(LocalOptimizer):
             self._emit_mesh_event(
                 "spec", registry.traffic(self.model.params, mesh))
             n = mesh_mod.dp_size(mesh)
-            data_sharding = mesh_mod.batch_sharding(mesh)
 
             count_this_epoch = self.state.get("recordsProcessedThisEpoch", 0)
 
@@ -1041,39 +1028,15 @@ class DistriOptimizer(LocalOptimizer):
                     self._emit_elastic_restore(last, prev_neval, "spec")
 
             _sync_shuffles(self.dataset, self.state.get("epoch", 1) - 1)
-            data_iter = self.dataset.data(train=True)
             ds_size = self.dataset.size()
+            feed = self._batch_ahead(n, ds_size, by_shard=False)
         wall_start = time.time()
 
-        records_to_skip = count_this_epoch
         cost_done = False          # one cost.analysis per optimize()
         while not self.end_when(self.state):
             self._elastic_step_boundary()
-            with tracer.span("data.next"):
-                batch = next(data_iter)
-            if records_to_skip >= batch.size():
-                records_to_skip -= batch.size()
-                continue
-            if records_to_skip > 0:
-                raise ValueError(
-                    f"resume skip remainder {records_to_skip} is smaller "
-                    f"than the batch ({batch.size()}): the batch size "
-                    "changed since the snapshot; resume with the same "
-                    "batching to keep the exact-resume contract")
-            bs = batch.size()
-            if bs % n != 0:
-                raise ValueError(
-                    f"global batch size {bs} must be a multiple of the "
-                    f"dp shard count {n} (data x fsdp axes)")
+            data, labels, bs = feed.take()
             t0 = time.time()
-            with tracer.span("h2d", records=bs,
-                             bytes=_host_nbytes(batch.data, batch.labels)):
-                data = jax.device_put(np.asarray(batch.data),
-                                      data_sharding)
-                labels = jax.device_put(np.asarray(batch.labels),
-                                        data_sharding)
-                jax.block_until_ready((data, labels))
-            t1 = time.time()
             self._rng, sub = jax.random.split(self._rng)
             clr_val = self._current_clr()
             clr = jnp.asarray(clr_val, jnp.float32)
@@ -1088,27 +1051,19 @@ class DistriOptimizer(LocalOptimizer):
                             model_state, data, labels, sub,
                             jnp.asarray(stepno, jnp.int32), clr,
                             kind=type(self).__name__, sharding="spec")
-            with tracer.span("train.step", step=stepno, n=n,
-                             sharding="spec"), \
-                    Watchdog(self.step_timeout,
-                             label=f"train step {stepno} (spec, n={n})"):
-                if FaultInjector.should("grad.nan", stepno):
-                    data = jnp.full_like(data, jnp.nan)
-                with tracer.span("train.dispatch"):
-                    params, opt_state, model_state, loss = step(
-                        params, opt_state, model_state, data, labels, sub,
-                        jnp.asarray(stepno, jnp.int32), clr)
-                with tracer.span("train.sync"):
-                    loss = float(loss)
-            compute_ns = (time.time() - t1) * 1e9
+            (params, opt_state, model_state), loss = self._run_step(
+                feed, stepno, f"train step {stepno} (spec, n={n})", data,
+                lambda data: step(
+                    params, opt_state, model_state, data, labels, sub,
+                    jnp.asarray(stepno, jnp.int32), clr),
+                n=n, sharding="spec")
             dt = time.time() - t0
 
             with tracer.span("loop.bookkeeping"):
                 costs.sample_hbm(step=stepno)
                 if self.skip_nonfinite and math.isnan(loss):
                     self._check_drop_budget(self._record_skipped_step())
-                self.metrics.add("computing time average", compute_ns)
-                self.metrics.add("put data into device", (t1 - t0) * 1e9)
+                self.metrics.add("computing time average", dt * 1e9)
                 self.metrics.set("loss", loss, unit="scalar")
                 count_this_epoch += bs
                 self.state["neval"] += 1
@@ -1125,8 +1080,6 @@ class DistriOptimizer(LocalOptimizer):
                     self.state["epoch"] += 1
                     count_this_epoch = 0
                     self.state["recordsProcessedThisEpoch"] = 0
-                    _sync_shuffles(self.dataset, self.state["epoch"] - 1)
-                    data_iter = self.dataset.data(train=True)
 
                 if self.sharded_checkpoint_trigger and \
                         self.sharded_checkpoint_path and \

@@ -31,6 +31,7 @@ from bigdl_tpu.core.precision import training_loss
 from bigdl_tpu.observability import costs
 from bigdl_tpu.observability import ledger as run_ledger
 from bigdl_tpu.observability import tracer
+from bigdl_tpu.optim.batch_ahead import BatchAhead, _sync_shuffles
 from bigdl_tpu.optim.metrics import Metrics
 from bigdl_tpu.optim.optim_method import SGD, Default, OptimMethod
 from bigdl_tpu.optim.trigger import Trigger
@@ -57,29 +58,6 @@ def _default_step_timeout() -> Optional[float]:
         raise ValueError(
             f"BIGDL_TPU_STEP_TIMEOUT={raw!r} is not a number of seconds")
     return t if t > 0 else None
-
-
-def _base_dataset(dataset):
-    """The underlying dataset of a (possibly chained) transformer
-    wrapper — the object that owns the shuffle stream."""
-    base = dataset
-    while hasattr(base, "base"):
-        base = base.base
-    return base
-
-
-def _sync_shuffles(dataset, epochs_completed: int) -> None:
-    """Bring the dataset's shuffle stream to ``epochs_completed`` total
-    shuffles.  The per-dataset seeded RNG makes shuffle replay
-    deterministic, so a freshly constructed dataset on resume reproduces
-    the permutation the interrupted run was iterating; a dataset already
-    driven by a previous optimize() is left untouched."""
-    base = _base_dataset(dataset)    # count on the underlying dataset so
-    done = getattr(base, "_shuffles_done", 0)  # wrappers share a stream
-    while done < epochs_completed:
-        dataset.shuffle()
-        done += 1
-    base._shuffles_done = done
 
 
 class LocalOptimizer:
@@ -269,10 +247,12 @@ class LocalOptimizer:
 
     def _put_batch(self, array):
         """Host batch -> device: batch-sharded over the mesh's dp axes
-        when ``set_mesh`` is active, plain transfer otherwise."""
-        if self._data_sharding is not None:
-            return jax.device_put(np.asarray(array), self._data_sharding)
-        return jnp.asarray(array)
+        when ``set_mesh`` is active, plain transfer otherwise (only
+        enqueued: the step that reads it waits for it).  A batch the
+        ingest pipeline already staged on the device passes through."""
+        if self._data_sharding is None or isinstance(array, jax.Array):
+            return jnp.asarray(array)
+        return jax.device_put(np.asarray(array), self._data_sharding)
 
     # -- the jitted step -----------------------------------------------------
 
@@ -476,6 +456,39 @@ class LocalOptimizer:
 
     # -- main loop -----------------------------------------------------------
 
+    def _run_step(self, feed: BatchAhead, stepno: int, label: str, data,
+                  dispatch, **attrs):
+        """One step of any of the trainer loops, the next batch started
+        under it: ``dispatch(data)`` enqueues the step program and
+        returns ``(*new_state, loss)``; ``feed.start()`` then fetches and
+        puts batch N+1 while the device runs step N; the sync on the
+        loss comes last.  Returns ``(new_state, float(loss))``.
+
+        The order matters: a put BEFORE the dispatch would delay the
+        step by the time the host spends in it (the SPMD loops block on
+        the copy); after the sync nothing overlaps it.  The watchdog
+        guards the dispatch and the sync, each with the whole timeout,
+        and not the fetch between them: a slow decode is not a hung
+        step."""
+        watchdog = partial(Watchdog, self.step_timeout, label=label)
+        with tracer.span("train.step", step=stepno, **attrs):
+            with watchdog():
+                if FaultInjector.should("grad.nan", stepno):
+                    # inside the span: the poison (first use compiles
+                    # full_like) is step work, not an inter-span hole in
+                    # the coverage accounting
+                    data = jnp.full_like(data, jnp.nan)  # NaN fwd -> grads
+                # the call returns once the program is enqueued; the
+                # wait for it is the sync's
+                with tracer.span("train.dispatch"):
+                    *new_state, loss = dispatch(data)
+            feed.start()
+            # blocks: the whole fused step (compute + collectives) — the
+            # hang point the watchdog guards (a wedged host stalls every
+            # other host's collective exactly here)
+            with watchdog(), tracer.span("train.sync"):
+                return new_state, float(loss)
+
     def optimize(self):
         self._run_start()
         with tracer.span("init", optimizer=type(self).__name__):
@@ -497,41 +510,19 @@ class LocalOptimizer:
             # resume: replay the shuffles of completed epochs so the fresh
             # dataset's permutation stream matches the interrupted run's
             _sync_shuffles(self.dataset, self.state.get("epoch", 1) - 1)
-            data_iter = self.dataset.data(train=True)
             ds_size = self.dataset.size()
+            feed = BatchAhead(
+                self.dataset, partial(self.dataset.data, train=True),
+                records_of=lambda data: data.shape[0],
+                put=lambda data, labels: (self._put_batch(data),
+                                          self._put_batch(labels)),
+                metrics=self.metrics, epoch=self.state.get("epoch", 1),
+                records_done=count_this_epoch, epoch_records=ds_size)
         wall_start = time.time()
 
-        # resume fast-forward: a fresh iterator restarts the epoch stream;
-        # skip the records already trained so the resumed run consumes
-        # exactly the batches an uninterrupted run would
-        records_to_skip = count_this_epoch
         cost_done = False          # one cost.analysis per optimize()
         while not self.end_when(self.state):
-            with tracer.span("data.next"):
-                batch = next(data_iter)
-            if records_to_skip >= batch.size():
-                records_to_skip -= batch.size()
-                continue
-            if records_to_skip > 0:
-                raise ValueError(
-                    f"resume skip remainder {records_to_skip} is smaller "
-                    f"than the batch ({batch.size()}): the batch size "
-                    "changed since the snapshot; resume with the same "
-                    "batching to keep the exact-resume contract")
-            # a staged ingest pipeline (ShardedDataSet(staging=True))
-            # yields device-resident batches: asarray is then a no-op
-            # view, and the span records that H2D was absorbed by the
-            # ingest ring (run-report shows ingest.h2d instead)
-            with tracer.span("h2d",
-                             staged=isinstance(batch.data, jax.Array),
-                             bytes=_host_nbytes(batch.data, batch.labels)):
-                if self._data_sharding is not None and \
-                        not isinstance(batch.data, jax.Array):
-                    data = self._put_batch(batch.data)
-                    labels = self._put_batch(batch.labels)
-                else:
-                    data, labels = (jnp.asarray(batch.data),
-                                    jnp.asarray(batch.labels))
+            data, labels, bs = feed.take()
             self._rng, sub = jax.random.split(self._rng)
 
             stepno = self.state["neval"]
@@ -551,29 +542,17 @@ class LocalOptimizer:
                             model_state, data, labels, sub,
                             jnp.asarray(stepno, jnp.int32), clr,
                             kind=type(self).__name__)
-            with tracer.span("train.step", step=stepno), \
-                    Watchdog(self.step_timeout,
-                             label=f"train step {stepno}"):
-                if FaultInjector.should("grad.nan", stepno):
-                    # inside the span: the poison (first use compiles
-                    # full_like) is step work, not an inter-span hole in
-                    # the coverage accounting
-                    data = jnp.full_like(data, jnp.nan)  # NaN fwd -> grads
-                # the call returns once the program is enqueued; the
-                # wait for it (and for the batch h2d only enqueued) is
-                # the sync's
-                with tracer.span("train.dispatch"):
-                    params, opt_state, model_state, loss = step(
-                        params, opt_state, model_state, data, labels, sub,
-                        jnp.asarray(stepno, jnp.int32), clr)
-                with tracer.span("train.sync"):
-                    loss = float(loss)    # the hang point guarded
+            (params, opt_state, model_state), loss = self._run_step(
+                feed, stepno, f"train step {stepno}", data,
+                lambda data: step(
+                    params, opt_state, model_state, data, labels, sub,
+                    jnp.asarray(stepno, jnp.int32), clr))
             dt = time.time() - t0
             # everything after the step itself — metrics/ledger/summary
-            # bookkeeping, logging, epoch rollover (shuffle + fresh
-            # iterator), validation and checkpoint triggers — is span-
-            # attributed too, so the run-report breakdown accounts for
-            # the loop's host-side time, not just its device time
+            # bookkeeping, logging, the epoch's counters, validation and
+            # checkpoint triggers — is span-attributed too, so the
+            # run-report breakdown accounts for the loop's host-side
+            # time, not just its device time
             with tracer.span("loop.bookkeeping"):
                 self.metrics.add("computing time average", dt * 1e9)
                 # HBM high-watermark sample (mem.hbm; no-op on backends
@@ -582,7 +561,6 @@ class LocalOptimizer:
                 if self.skip_nonfinite and math.isnan(loss):
                     self._record_skipped_step()
 
-                bs = batch.size()
                 count_this_epoch += bs
                 self.state["neval"] += 1
                 # persisted so a mid-epoch state snapshot resumes the
@@ -603,8 +581,6 @@ class LocalOptimizer:
                     self.state["epoch"] += 1
                     count_this_epoch = 0
                     self.state["recordsProcessedThisEpoch"] = 0
-                    _sync_shuffles(self.dataset, self.state["epoch"] - 1)
-                    data_iter = self.dataset.data(train=True)
 
                 # keep the facade fields fresh for triggers/validation
                 self.model.params, self.model.state = params, model_state
@@ -664,14 +640,6 @@ class LocalOptimizer:
             File.save({"state": dict(self.state), "opt_state": opt_state,
                        "rng": np.asarray(self._rng)},
                       f"{self.checkpoint_path}/state{suffix}", True)
-
-
-def _host_nbytes(data, labels) -> int:
-    """Bytes ``h2d`` has to copy: those of the batch's arrays still on the
-    host (0 for a batch the ingest ring staged on the device)."""
-    return sum(int(getattr(a, "nbytes", 0))
-               for a in jax.tree_util.tree_leaves((data, labels))
-               if not isinstance(a, jax.Array))
 
 
 def _evaluate(model, dataset, methods):

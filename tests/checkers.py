@@ -87,3 +87,17 @@ def graftlint_clean(*paths):
     assert not res.findings, "graftlint findings:\n" + "\n".join(
         f.render() for f in res.findings)
     return res
+
+
+def record_steps(opt):
+    """``(stepno, data)`` of every step a trainer dispatches from here on:
+    the batch its step program is handed, as the loop holds it (on the
+    device).  Wraps the one place all three trainer loops run a step."""
+    seen, run_step = [], opt._run_step
+
+    def recording(feed, stepno, label, data, dispatch, **attrs):
+        seen.append((stepno, data))
+        return run_step(feed, stepno, label, data, dispatch, **attrs)
+
+    opt._run_step = recording
+    return seen

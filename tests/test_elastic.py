@@ -41,6 +41,7 @@ from bigdl_tpu.resilience.elastic import (ElasticCoordinator,
                                           reshape_for_world)
 from bigdl_tpu.resilience.watchdog import Watchdog
 from bigdl_tpu.utils import checkpoint as ckpt
+from tests.checkers import record_steps
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -533,6 +534,7 @@ def _elastic_world_change_run(tmp_path, sharding):
         opt.set_sharded_checkpoint(str(tmp_path / "ckpt"),
                                    Trigger.several_iteration(2))
         opt.set_elastic(coord)
+        taken = record_steps(opt)
 
         def peer():
             while _lease_step(root, "a") < 3:
@@ -554,7 +556,7 @@ def _elastic_world_change_run(tmp_path, sharding):
     finally:
         run_ledger.set_run_dir(None)
     assert opt.state["neval"] == 14
-    return m, run_dir, coord
+    return m, run_dir, coord, taken
 
 
 def _uninterrupted_reference(sharding):
@@ -577,7 +579,20 @@ def _flat_weights(m):
 
 
 def _assert_world_change_run(tmp_path, sharding):
-    m, run_dir, coord = _elastic_world_change_run(tmp_path, sharding)
+    m, run_dir, coord, taken = _elastic_world_change_run(tmp_path,
+                                                        sharding)
+    # every step, the replayed ones too, trained the batch the
+    # uninterrupted order gives it: the batch in flight when a world
+    # change aborted an attempt was dropped, not trained, and the restore
+    # replayed the shuffle stream to the snapshot's epoch
+    x = np.stack([s.feature for s in _corpus()])
+    rng, perm = np.random.RandomState(1), np.arange(64)
+    rows = list(perm.reshape(8, 8).copy())   # epoch 1: as stored
+    rng.shuffle(perm)                        # epoch 2: the first shuffle
+    rows += list(perm.reshape(8, 8))
+    assert {n for n, _ in taken} == set(range(14))
+    for stepno, data in taken:
+        np.testing.assert_array_equal(data, x[rows[stepno]])
     # the fleet saw: bootstrap (gen 1) -> join (gen 2) -> loss (gen 3)
     final = coord._read_generation()
     assert final.gen >= 3 and final.hosts == ("a",)

@@ -259,6 +259,12 @@ def test_distri_smoke_produces_parseable_ledger(tmp_path):
     # the distri-only seams made it into the breakdown
     for phase in ("h2d", "init", "allreduce.init_shards"):
         assert phase in rep["phases"], sorted(rep["phases"])
+    # one put in the open (step 0), one under each step (the last feeds
+    # no step): three of the four steps found their input on the device
+    h2d = rep["phases"]["h2d"]
+    assert (h2d["count"], h2d["ahead"], h2d["steps_ahead"]) == (5, 4, 3)
+    assert "input ahead of the device in 3/4 steps (75.0%)" in \
+        render_report(rep)
     Engine.reset()
 
 

@@ -2,6 +2,7 @@
 spans, the scope names the device trace carries, and the program's spans
 on the profile's own timeline."""
 
+from functools import partial
 import math
 import threading
 import time
@@ -21,6 +22,7 @@ from bigdl_tpu.optim import DistriOptimizer, LocalOptimizer, SGD, Trigger
 from bigdl_tpu.serving.errors import SlotCapacityError
 from bigdl_tpu.serving.scheduler.continuous import (ContinuousGenerator,
                                                     Timeline)
+from tests.checkers import record_steps
 
 STEPS = 4                   # steps_per_sync of the toy generator
 
@@ -325,10 +327,59 @@ def test_dispatch_and_sync_are_children_of_train_step(tmp_path, kind):
             p = steps[r["parent"]]
             assert p["mono"] <= r["mono"]
             assert r["mono"] + r["dur_s"] <= p["mono"] + p["dur_s"] + 1e-3
-    h2d = [r for r in spans if r["name"] == "h2d"]
-    assert len(h2d) == 3
+    # the input is one batch ahead (``optim/batch_ahead.py``): step 0's
+    # batch is put in the open, then one more under every step, between
+    # its dispatch and its sync; the put under the last step feeds none,
+    # so three steps make FOUR puts
+    by_start = lambda name: sorted(
+        (r for r in spans if r["name"] == name), key=lambda r: r["mono"])
+    h2d, fetches = by_start("h2d"), by_start("data.next")
+    assert len(h2d) == len(fetches) == 4
     assert all(r["attrs"]["bytes"] == 8 * 4 * 4 + 8 * 4 for r in h2d)
+    assert [r["attrs"]["ahead"] for r in h2d] == [False, True, True, True]
+    assert "parent" not in h2d[0] and "parent" not in fetches[0]
+    end = lambda r: r["mono"] + r["dur_s"]
+    for n, (dispatch, sync) in enumerate(zip(by_start("train.dispatch"),
+                                             by_start("train.sync"))):
+        fetch, put = fetches[n + 1], h2d[n + 1]
+        assert fetch["parent"] == put["parent"] == dispatch["parent"]
+        assert end(dispatch) <= fetch["mono"] <= end(fetch) <= put["mono"]
+        assert end(put) <= sync["mono"] + 1e-3
     assert [r["type"] for r in records].count("clock") == 1
+
+
+@pytest.mark.parametrize("kind", ["local", "distri_flat", "distri_spec"])
+def test_a_batch_already_on_the_device_is_not_copied_again(tmp_path, kind):
+    """A staged pipeline (``ShardedDataSet(staging=True)``,
+    ``PrefetchToDevice``) yields ``jax.Array``s: the look-ahead passes
+    them through, and its ``h2d`` spans say so."""
+    from bigdl_tpu.engine import Engine
+    from bigdl_tpu.parallel.mesh import batch_sharding
+    run_ledger.set_run_dir(str(tmp_path))
+    try:
+        opt = _toy_trainer(kind).set_optim_method(SGD(learning_rate=0.1))
+        place = jnp.asarray if kind == "local" else partial(
+            jax.device_put, device=batch_sharding(opt.mesh))
+        staged = [MiniBatch(place(b.data), place(b.labels))
+                  for b in opt.dataset.buffer]
+        opt.dataset = DataSet.array(staged)
+        taken = record_steps(opt)
+        opt.optimize()
+        run_ledger.flush()
+    finally:
+        run_ledger.set_run_dir(None)
+        Engine.reset()
+    records, _ = load_ledger(str(tmp_path))
+    h2d = [r["attrs"] for r in records
+           if r.get("type") == "span" and r["name"] == "h2d"]
+    assert len(h2d) == 4
+    assert all(a["staged"] and a["bytes"] == 0 for a in h2d)
+    # the step was handed the dataset's own device buffers
+    buffers = lambda a: tuple(s.data.unsafe_buffer_pointer()
+                              for s in a.addressable_shards)
+    assert len(taken) == 3
+    assert {buffers(a) for _, a in taken} <= \
+        {buffers(b.data) for b in staged}
 
 
 # -- C. the names the device trace carries ------------------------------------------
