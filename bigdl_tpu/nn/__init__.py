@@ -10,6 +10,7 @@ from bigdl_tpu.core.module import (Container, Criterion, Module,
 from bigdl_tpu.nn.attention import (GroupedQueryAttention, LatentAttention,
                                     MultiHeadAttention)
 from bigdl_tpu.nn.linear_attention import DeltaAttention
+from bigdl_tpu.nn.state_space import Mamba2Mixer
 from bigdl_tpu.parallel.expert import MixtureOfExperts
 from bigdl_tpu.nn.activation import (ELU, Abs, Clamp, Exp, GradientReversal,
                                      HardShrink, HardTanh, LeakyReLU, Log,

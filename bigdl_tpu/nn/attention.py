@@ -656,9 +656,10 @@ class GroupedQueryAttention(Module):
     """Grouped-query softmax attention with a STATED head size, one module
     for the two kinds of layer a window/full pattern model mixes.  Per
     token, ``x`` the normed input: ``q = Wq x`` as ``H`` heads of ``D``,
-    ``k = Wk x``, ``v = Wv x`` as ``Hkv`` heads of ``D``; an RMS norm
-    over each head's ``D`` query and key channels (one weight vector of
-    ``D`` each, shared by the heads); with ``rope`` the rotary
+    ``k = Wk x``, ``v = Wv x`` as ``Hkv`` heads of ``D``; with
+    ``head_norm`` an RMS norm over each head's ``D`` query and key channels
+    (one weight vector of ``D`` each, shared by the heads; a layer without
+    it holds no such weights); with ``rope`` the rotary
     embedding by half-split pairs ``(i, i + D/2)`` on q and k; scores
     ``q_i . k_j / sqrt(D)`` where ``j <= i`` and, with a ``window``,
     ``i - j < window`` (a token and the ``window - 1`` before it);
@@ -682,9 +683,10 @@ class GroupedQueryAttention(Module):
     def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
                  head_dim: int = 128, window: Optional[int] = None,
                  rope: bool = True, rope_theta: float = 1e6,
-                 eps: float = 1e-5):
+                 eps: float = 1e-5, head_norm: bool = True):
         super().__init__()
         assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.head_norm = head_norm
         self.embed_dim = embed_dim
         self.num_heads = num_heads
         self.num_kv_heads = num_kv_heads
@@ -702,12 +704,15 @@ class GroupedQueryAttention(Module):
         def w(k, out, fan_in):
             return jax.random.normal(k, (out, fan_in)) * fan_in ** -0.5
 
-        return {"wq": w(ks[0], self.num_heads * d, e),
-                "wk": w(ks[1], self.num_kv_heads * d, e),
-                "wv": w(ks[2], self.num_kv_heads * d, e),
-                "wo": w(ks[3], e, self.num_heads * d),
-                "q_norm": {"weight": jnp.ones((d,), jnp.float32)},
-                "k_norm": {"weight": jnp.ones((d,), jnp.float32)}}
+        params = {"wq": w(ks[0], self.num_heads * d, e),
+                  "wk": w(ks[1], self.num_kv_heads * d, e),
+                  "wv": w(ks[2], self.num_kv_heads * d, e),
+                  "wo": w(ks[3], e, self.num_heads * d)}
+        if self.head_norm:
+            params.update(
+                q_norm={"weight": jnp.ones((d,), jnp.float32)},
+                k_norm={"weight": jnp.ones((d,), jnp.float32)})
+        return params
 
     # -- what the layer keeps -------------------------------------------------
 
@@ -728,7 +733,7 @@ class GroupedQueryAttention(Module):
 
     # -- the layer --------------------------------------------------------------
 
-    def _head_norm(self, x, weight):
+    def _norm_heads(self, x, weight):
         xf = x.astype(jnp.float32)
         xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
                                 + self.eps) * weight.astype(jnp.float32)
@@ -742,8 +747,9 @@ class GroupedQueryAttention(Module):
         q = _proj(x, params["wq"]).reshape(b, s, self.num_heads, d)
         k = _proj(x, params["wk"]).reshape(b, s, self.num_kv_heads, d)
         v = _proj(x, params["wv"]).reshape(b, s, self.num_kv_heads, d)
-        q = self._head_norm(q, params["q_norm"]["weight"])
-        k = self._head_norm(k, params["k_norm"]["weight"])
+        if self.head_norm:
+            q = self._norm_heads(q, params["q_norm"]["weight"])
+            k = self._norm_heads(k, params["k_norm"]["weight"])
         q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         if self.rope:
             q = apply_rope(q, positions, self.rope_theta)

@@ -408,20 +408,35 @@ def sigmoid_group_route(scores, bias, k: int, n_group: int, topk_group: int,
     return ids, gates
 
 
+#: an expert's form, by what its first matrix's columns are: gate and up
+#: side by side, ``silu(x Wg) * (x Wu)``, or up alone, ``relu(x Wu)^2``
+EXPERT_FORMS = ("swiglu", "relu2")
+
+
+def _expert_hidden(h, f: int, form: str):
+    """An expert's hidden row of ``f`` columns from the first product's."""
+    if form == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    return jax.nn.silu(h[..., :f]) * h[..., f:]
+
+
 def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
-                       expert_offset: int = 0):
+                       expert_offset: int = 0, form: str = "swiglu"):
     """What the experts held here add for ``x`` (T, E): ``ids``/``gates``
     (T, k) from the router over ALL experts, ``valid`` (T,) masks padding
     and inactive rows, ``w_gate_up`` (held, E, 2F) and ``w_down`` (held,
     F, E) the held experts' SwiGLU weights (``silu(x Wg) * (x Wu)``
-    through ``Wd``).  Pairs whose expert is absent (or whose token is not
+    through ``Wd``); with ``form`` ``"relu2"`` the experts are not gated
+    and ``w_gate_up`` is ``Wu`` alone, (held, E, F): ``relu(x Wu)^2``
+    through ``Wd``.  Pairs whose expert is absent (or whose token is not
     valid) sort behind every held group and contribute zero.
 
     Returns (y (T, E) in ``x``'s dtype, counters): ``pairs`` routed to
     held experts, ``hit`` held experts with at least one, ``max`` the
     most loaded one's — int32 scalars."""
+    assert form in EXPERT_FORMS, form
     t, k = ids.shape
-    held, _, f2 = w_gate_up.shape
+    held, f = w_gate_up.shape[0], w_down.shape[1]
     local = ids - expert_offset
     here = (local >= 0) & (local < held) & valid[:, None]
     key = jnp.where(here, local, held).reshape(-1)           # (T*k,)
@@ -439,21 +454,20 @@ def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
             jnp.where(here, gates, 0.0))
         h = jnp.einsum("te,gef->tgf", x, w_gate_up,
                        preferred_element_type=jnp.float32)
-        h = jax.nn.silu(h[..., :f2 // 2]) * h[..., f2 // 2:] \
-            * dense[..., None]
+        h = _expert_hidden(h, f, form) * dense[..., None]
         y = jnp.dot(h.astype(x.dtype).reshape(t, -1),
-                    w_down.reshape(held * (f2 // 2), -1),
+                    w_down.reshape(held * f, -1),
                     preferred_element_type=jnp.float32)
         return y.astype(x.dtype), counters
     order = jnp.argsort(key, stable=True)
     if t * k * x.shape[1] > PAIR_ELEMENTS:
         return _held_pairs_blocked(x, order, sizes, n_here,
                                    jnp.where(here, gates, 0.0), w_gate_up,
-                                   w_down), counters
+                                   w_down, form), counters
     xs = x[order // k]                                       # sorted pairs
     h = lax.ragged_dot(xs, w_gate_up, sizes,
                        preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(h[:, :f2 // 2]) * h[:, f2 // 2:]).astype(x.dtype)
+    h = _expert_hidden(h, f, form).astype(x.dtype)
     ys = lax.ragged_dot(h, w_down, sizes,
                         preferred_element_type=jnp.float32)
     # rows past the last held group belong to no group: whatever the
@@ -464,7 +478,8 @@ def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
     return y.astype(x.dtype), counters
 
 
-def _held_pairs_blocked(x, order, sizes, n_here, gates, w_gate_up, w_down):
+def _held_pairs_blocked(x, order, sizes, n_here, gates, w_gate_up, w_down,
+                        form: str = "swiglu"):
     """``held_experts_apply``'s grouped product over the FIRST ``n_here``
     of the pairs sorted by expert (``order``; the pairs of absent experts
     and of padding sort behind them), `PAIR_BLOCK` pairs a turn: a block's
@@ -489,7 +504,7 @@ def _held_pairs_blocked(x, order, sizes, n_here, gates, w_gate_up, w_down):
                 - jnp.clip(starts, lo, lo + PAIR_BLOCK)).astype(jnp.int32)
         h = lax.ragged_dot(x[token], w_gate_up, part,
                            preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+        h = _expert_hidden(h, f, form).astype(x.dtype)
         ys = lax.ragged_dot(h, w_down, part,
                             preferred_element_type=jnp.float32)
         live = lo + jnp.arange(PAIR_BLOCK) < n_here

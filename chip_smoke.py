@@ -23,6 +23,13 @@ seed:
                  ``k_exaone_236b``: a 200-token prefill (the ring wrapped
                  once) and 300 decode steps (twice more), and the control
                  of a window off by one
+3d. **ssm**      the same drive for a state-space layer's float32 state
+                 beside an attention layer's pages, at the published widths
+                 of ``nemotron3_super_120b`` (a Mamba-2, a latent-expert and
+                 an attention block): a 300-token prefill and 2,048 decode
+                 steps, and the control of the gate after the norm; before
+                 it the recurrence alone against its definition, and the
+                 control of a state kept in bf16
 4. **kernels**   every Pallas kernel a TPU backend switches on without an
                  opt-in variable, compiled and compared with its jnp
                  reference inside the tolerances below
@@ -326,15 +333,37 @@ def _rounded(fn, index):
 
 
 def _bf16_state(model):
-    """The control of the hybrid phase: the delta-rule state rounded to
-    bf16 after every update."""
-    from bigdl_tpu.nn import linear_attention
+    """The control of the hybrid and the ssm phase: the recurrent state
+    (the delta rule's, the state-space layer's) rounded to bf16 after every
+    update."""
+    from bigdl_tpu.nn import linear_attention, state_space
     stack = contextlib.ExitStack()
-    for name in ("kda_step", "kda_chunked"):
-        stack.enter_context(mock.patch.object(
-            linear_attention, name,
-            _rounded(getattr(linear_attention, name), 1)))
+    for module, names in ((linear_attention, ("kda_step", "kda_chunked")),
+                          (state_space, ("ssd_step", "ssd_chunked"))):
+        for name in names:
+            stack.enter_context(mock.patch.object(
+                module, name, _rounded(getattr(module, name), 1)))
     return stack
+
+
+def _gate_after_norm(model):
+    """A control of the ssm phase: ``rmsnorm_group(y) * silu(z)`` where the
+    configuration states ``rmsnorm_group(y * silu(z))``."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn.state_space import Mamba2Mixer
+
+    def swapped(self, params, o, z):
+        b, s, inner = o.shape
+        o = o.reshape(b, s, self.groups, -1)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + self.eps)
+        return o.reshape(b, s, inner) \
+            * params["norm"]["weight"].astype(jnp.float32) \
+            * jax.nn.silu(z.astype(jnp.float32))
+
+    return mock.patch.object(Mamba2Mixer, "_gate_norm", swapped)
 
 
 def _window_off_by_one(model):
@@ -352,15 +381,16 @@ def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
                  slots=8, max_len=2048, buckets=(512,), compiled=True,
                  tolerance=HYBRID_TOLERANCE,
                  router_tolerance=ROUTER_TOLERANCE, dtype="bfloat16",
-                 control=("bf16_state", _bf16_state)) -> str:
+                 controls=(("bf16_state", _bf16_state),)) -> str:
     """Prefill of ``prompt_len`` tokens, then ``steps`` decode steps, the
     way ``ContinuousGenerator``'s two programs call the model (slot-
     addressed prefill from position 0 with its real length; whole-batch
     decode steps with the other rows inactive), against the plain
     reference's full forward over the same tokens, on LOG-PROBS; the
     router against the reference's on one hidden state; the same request
-    through the generator itself; and the two controls (``control``: the
-    name and the patch of the one that the per-slot state has to fail)."""
+    through the generator itself; and the controls (``controls``: the name
+    and the patch of each that the per-slot state has to fail, beside the
+    router's own)."""
     import importlib
 
     import jax
@@ -451,15 +481,17 @@ def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
     tight = gap(params, logp, toks)
     check(inside(tight, tolerance), f"served log-probs (median, max) "
           f"{tight} std from the reference's (tolerance {tolerance})")
-    control_name, patched = control
-    with patched(model):
-        state_bf16 = gap(params, *served(params))
-    check(not inside(state_bf16, tolerance),
-          f"the {control_name.replace('_', '-')} control "
-          f"passed the tolerance {tolerance}: {state_bf16}")
+    failed = {}
+    for control_name, patched in controls:
+        with patched(model):
+            failed[control_name] = gap(params, *served(params))
+        check(not inside(failed[control_name], tolerance),
+              f"the {control_name.replace('_', '-')} control "
+              f"passed the tolerance {tolerance}: {failed[control_name]}")
 
     # 2. the router against the reference's, one hidden state
-    layer = next(b["ffn"] for b in params["blocks"] if "router" in b["ffn"])
+    layer = next(b["ffn"] for b in params["blocks"]
+                 if "router" in b.get("ffn", {}))
     x = jax.random.normal(jax.random.PRNGKey(28),
                           (prompt_len, model.embed_dim)).astype(dtype)
     published = {**reference.PUBLISHED, **ref_kw}
@@ -511,9 +543,9 @@ def phase_hybrid(config="benchmark/configs/ling3_flash_vl.json", vocab=None,
           f"paged kernel compiled={_paged_kernel_compiled()}")
     fmt = "({:.4f}, {:.4f})".format
     return (f"prompt={prompt_len} steps={steps} gap_median_max="
-            f"{fmt(*tight)} (tolerance {tolerance}) {control_name}="
-            f"{fmt(*state_bf16)} "
-            f"router_agree={agree:.4f} gates_off={gates_off:.2e} "
+            f"{fmt(*tight)} (tolerance {tolerance}) "
+            + "".join(f"{k}={fmt(*v)} " for k, v in failed.items())
+            + f"router_agree={agree:.4f} gates_off={gates_off:.2e} "
             f"(tolerance {router_tolerance}) bf16_router=({agree_bf16:.4f}, "
             f"{gates_bf16:.2e}) state_bytes_per_slot="
             f"{st['state']['bytes_per_slot']}")
@@ -545,7 +577,100 @@ def phase_window(config="benchmark/configs/k_exaone_236b.json", vocab=None,
         dict({"layer_types": ("sliding_attention", "full_attention")},
              **(reference_kw or {})),
         prompt_len, steps, slots, max_len, buckets, compiled, tolerance,
-        dtype=dtype, control=("window_off_by_one", _window_off_by_one))
+        dtype=dtype, controls=(("window_off_by_one", _window_off_by_one),))
+
+
+# The ssm phase's tolerance, read as the hybrid phase's: (median, maximum)
+# over 2,049 positions of max |served - reference| log-prob over the std of
+# the reference's logits.  Three blocks at the published widths of
+# ``nemotron3_super_120b`` (a Mamba-2 block, a latent-expert block with its
+# 128 held experts, the attention block), bf16 around a float32 state and a
+# float32 router.  Readings on a v5e (PR 35): served (0.0199, 0.1171), the
+# control with the gate after the norm (1.2202, 1.8514); the tolerance is
+# three times the served reading, and that control has to FAIL it.  The
+# state rounded to bf16 after every update reads (0.0201, 0.1066) there, as
+# the served path does: at the row's own decay ranges a head remembers about
+# a dozen tokens (1 / (dt A), geometric mean), so 2,048 roundings of 2^-9
+# never add up past the bf16 activations' own.  What holds the state to
+# float32 on the chip is the check on the recurrence itself, below.
+SSM_TOLERANCE = (0.06, 0.35)
+# The recurrence alone, at the published head sizes with SLOW heads (A = -1,
+# dt in [0.001, 0.01]: memories of 100 to 1,000 tokens): the state after a
+# chunked prefill and one-token steps against the float32 definition token
+# by token, as the largest difference over the largest entry.  Readings on
+# a v5e (PR 35): 1.13e-4 (the chunked form's products at the highest
+# precision against element-wise float32), the state rounded to bf16 after
+# every update 4.18e-2; the tolerance is their geometric mean, and the
+# control has to FAIL it, as a matmul that rounds its operands to bf16 (a
+# TPU's default float32 product) would.
+SSM_STATE_TOLERANCE = 2e-3
+
+
+def _ssm_recurrence(heads=128, head_dim=64, state=128, groups=8, chunk=128,
+                    prefill=2048, steps=256, tolerance=SSM_STATE_TOLERANCE):
+    """(sound, bf16-state control): ``ssd_chunked`` then ``ssd_step``s
+    against ``ssd_naive`` over the same tokens, relative error of the final
+    state and of the outputs (the larger)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops import ssd
+    k = jax.random.split(jax.random.PRNGKey(35), 5)
+    t = prefill + steps
+    x = jax.random.normal(k[0], (1, t, heads, head_dim))
+    dt = jnp.exp(jax.random.uniform(k[1], (1, t, heads), minval=math.log(1e-3),
+                                    maxval=math.log(1e-2)))
+    b, c = (jax.random.normal(kk, (1, t, groups, state)) for kk in k[2:4])
+    a, d = -jnp.ones((heads,)), jnp.ones((heads,))
+    zero = jnp.zeros((1, heads, head_dim, state))
+    want_y, want_s = jax.jit(ssd.ssd_naive)(x, dt, a, b, c, d, zero)
+
+    def served(keep):
+        @jax.jit
+        def run(x, dt, b, c):
+            y0, s = ssd.ssd_chunked(x[:, :prefill], dt[:, :prefill], a,
+                                    b[:, :prefill], c[:, :prefill], d, zero,
+                                    chunk)
+
+            def one(s, v):
+                y, s = ssd.ssd_step(v[0], v[1], a, v[2], v[3], d, s)
+                return keep(s), y
+
+            s, y1 = jax.lax.scan(one, keep(s), tuple(
+                jnp.moveaxis(v[:, prefill:], 1, 0) for v in (x, dt, b, c)))
+            return jnp.concatenate([y0, jnp.moveaxis(y1, 0, 1)], 1), s
+
+        y, s = run(x, dt, b, c)
+        return max(_rel_err(s, want_s), _rel_err(y, want_y))
+
+    sound, control = served(lambda s: s), served(_bf16)
+    check(sound <= tolerance, f"the recurrence on this backend is {sound:.2e} "
+          f"off its definition (tolerance {tolerance})")
+    check(control > tolerance, f"the bf16-state control of the recurrence "
+          f"passed the tolerance {tolerance}: {control:.2e}")
+    return sound, control
+
+
+def phase_ssm(config="benchmark/configs/nemotron3_super_120b.json",
+              vocab=None, overrides=None, reference_kw=None, prompt_len=300,
+              steps=2048, slots=8, max_len=4096, buckets=(512,),
+              compiled=True, tolerance=SSM_TOLERANCE, dtype="bfloat16",
+              recurrence=None) -> str:
+    """The recurrence alone against its definition (``_ssm_recurrence``;
+    ``recurrence`` its sizes), then ``phase_hybrid``'s drive on a ``mamba2``
+    + ``latent_experts`` + ``full`` pattern of one-part blocks at the
+    published widths: the state is prefilled by the chunked scan (two chunks
+    and a part of a third), then updated in place 2,048 times."""
+    sound, control = _ssm_recurrence(**(recurrence or {}))
+    three = {"num_layers": 3, "layers": [["mamba2", None],
+                                         [None, "latent_experts"],
+                                         ["full", None]]}
+    return phase_hybrid(
+        config, vocab, dict(three, **(overrides or {})), reference_kw,
+        prompt_len, steps, slots, max_len, buckets, compiled, tolerance,
+        dtype=dtype, controls=(("gate_after_norm", _gate_after_norm),)) \
+        + f" recurrence_off={sound:.2e} bf16_state={control:.2e} " \
+        f"(tolerance {SSM_STATE_TOLERANCE})"
 
 
 # -- phase 4: kernels ---------------------------------------------------------
@@ -991,6 +1116,7 @@ def main() -> int:
                         ("serve", phase_serve),
                         ("hybrid", phase_hybrid),
                         ("window", phase_window),
+                        ("ssm", phase_ssm),
                         ("kernels", phase_kernels),
                         ("multichip", phase_multichip)):
         t0, (c0, h0, m0) = time.time(), meter.snapshot()

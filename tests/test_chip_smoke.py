@@ -178,3 +178,28 @@ def test_phase_window_toy(interpret_mode):
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="window-off-by-one control"):
         chip_smoke.phase_window(steps=4, tolerance=(9.0, 9.0), **kw)
+
+
+def test_phase_ssm_toy(interpret_mode):
+    """The ssm phase at toy widths in float32: the recurrence agrees with
+    its definition and a state kept in bf16 does not; a state prefilled by
+    the chunked scan (chunks of 8, a prompt of 13) and updated 40 times
+    agrees with the reference to rounding, and the gate after the norm
+    fails; a tolerance that control passes fails the phase."""
+    toy = dict(max_len=128, embed_dim=64, num_heads=4, num_kv_heads=2,
+               head_dim=16, expert_dim=24, num_experts=16,
+               experts_per_token=4, experts_held=4, latent_size=32,
+               shared_dim=48, ssm_heads=8, ssm_head_dim=8, ssm_state=16,
+               ssm_groups=2, ssm_chunk=8)
+    kw = dict(vocab=97, overrides=toy,
+              reference_kw=dict(num_experts_per_tok=4, n_groups=2),
+              prompt_len=13, slots=3, max_len=128, buckets=(16,),
+              compiled=False, dtype="float32",
+              recurrence=dict(heads=8, head_dim=8, state=16, groups=2,
+                              chunk=8, prefill=40, steps=24))
+    line = chip_smoke.phase_ssm(steps=40, tolerance=(1e-4, 5e-4), **kw)
+    assert "gate_after_norm=" in line and "bf16_state=" in line \
+        and "router_agree=1.0000" in line
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="gate-after-norm control"):
+        chip_smoke.phase_ssm(steps=4, tolerance=(9.0, 9.0), **kw)
