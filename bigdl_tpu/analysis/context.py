@@ -10,9 +10,10 @@ The rules need three things no single ``ast.walk`` gives them:
   these regions.
 * **Donation sites** — which callables donate which argument positions
   (``donate_argnums`` / ``donate_argnames``), including the repo's
-  factory idiom where a module-level function *returns* the jitted step
-  (``make_distri_train_step`` → the trainer's ``step``), which a single
-  per-module pass would never connect.
+  factory idiom where a module-level function or a method *returns* the
+  jitted step (``make_distri_train_step`` → the trainer's ``step``,
+  ``LocalOptimizer._build_step``), which a single per-module pass would
+  never connect.
 * **Ordered scope events** — statement-ordered name loads/stores within
   one function scope (nested ``def``/``lambda`` bodies excluded), which
   the use-after-donate and prng-reuse rules replay as a tiny abstract
@@ -482,12 +483,17 @@ class ModuleContext:
         return None
 
     def export_factories(self) -> Dict[str, FactoryReturn]:
-        """Module-level functions that RETURN a jitted-with-donation
-        callable (directly or inside a tuple) — the cross-module seam the
-        per-module donation map cannot see.  Keyed by bare function name;
-        consumed by later modules via the shared factory registry."""
+        """Module-level functions, and methods of module-level classes
+        (``LocalOptimizer._build_step``, called as ``self._build_step()``),
+        that RETURN a jitted-with-donation callable (directly or inside a
+        tuple) — the cross-module seam the per-module donation map cannot
+        see.  Keyed by bare function name; consumed by later modules via
+        the shared factory registry."""
         out: Dict[str, FactoryReturn] = {}
-        for n in self.tree.body:
+        defs = [n for top in self.tree.body
+                for n in (top.body if isinstance(top, ast.ClassDef)
+                          else [top])]
+        for n in defs:
             if not isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
             # names bound to donating jit calls inside this function

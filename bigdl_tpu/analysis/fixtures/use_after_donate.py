@@ -39,6 +39,39 @@ def bad_argnames_read(w, g):
     return w + out                      # BAD: donated via donate_argnames
 
 
+class Trainer:
+    """The factory as a METHOD (``LocalOptimizer._build_step``): the loop
+    gets its donating step from ``self._build_step()``."""
+
+    def _build_step(self):
+        def _step(params, opt_state, data):
+            return params - data, opt_state
+        donate = (0, 1) if self.accelerated else ()
+        step = jax.jit(_step, donate_argnums=donate)
+        return step
+
+    def bad_loop_reads_donated_params(self, batches):
+        params, opt_state = self.params, self.opt_state
+        step = self._build_step()
+        for data in batches:
+            new_params, opt_state = step(params, opt_state, data)
+            self.params = params        # BAD: params' buffer was donated
+            params = new_params
+        return params
+
+    def good_trainer_rebinding(self, batches):
+        params, opt_state = self.params, self.opt_state
+        step = self._build_step()
+        for data in batches:
+            def dispatch(data):
+                nonlocal params, opt_state
+                params, opt_state = step(params, opt_state, data)
+                self.params = params    # OK: the call's result
+                return params, opt_state
+            dispatch(data)
+        return params
+
+
 def good_rebind_same_statement(w, g):
     step = jax.jit(lambda a, b: (a - b, b), donate_argnums=(0,))
     w, _ = step(w, g)

@@ -18,7 +18,8 @@ Detected shapes, per function scope:
 Donating callables are found from direct ``jax.jit`` assignments,
 ``@partial(jax.jit, donate_argnums=...)`` decorators, and the
 cross-module factory registry (``make_distri_train_step``-style functions
-that *return* the jitted step).
+and ``LocalOptimizer._build_step``-style methods that *return* the jitted
+step).
 """
 
 from __future__ import annotations

@@ -60,6 +60,17 @@ def _default_step_timeout() -> Optional[float]:
     return t if t > 0 else None
 
 
+def state_donation(platforms) -> bool:
+    """Whether the trainer's step donates its state on devices of these
+    ``platforms``: the rule of the SPMD steps (``parallel/allreduce.py``,
+    ``parallel/specs.py``).  Donated, XLA writes the new state into the
+    old state's buffers and the call allocates none; on a CPU-only set
+    it does not donate (donated buffers + the persistent compilation
+    cache corrupt the CPU heap, and memory is not the constraint
+    there)."""
+    return not set(platforms) <= {"cpu"}
+
+
 class LocalOptimizer:
 
     def __init__(self, model, criterion, dataset,
@@ -256,14 +267,25 @@ class LocalOptimizer:
 
     # -- the jitted step -----------------------------------------------------
 
+    def _donates_state(self) -> bool:
+        """``state_donation`` for the devices the state lives on: the
+        mesh's with ``set_mesh``, the default backend's otherwise."""
+        devices = self._mesh.devices.flat if self._mesh is not None \
+            else jax.devices()
+        return state_donation({d.platform for d in devices})
+
     def _build_step(self):
+        """The jitted step ``(params, opt_state, model_state, data, labels,
+        rng, stepno, clr) -> (params, opt_state, model_state, loss)``.
+        Where ``_donates_state`` says so it donates its first three
+        arguments, which are dead after the call (``step.donates_state``
+        records the decision)."""
         model, criterion, optim = self.model, self.criterion, self.optim_method
         config = self.config
 
         mixed = self.mixed_precision
         guard = self.skip_nonfinite
 
-        @jax.jit
         def step(params, opt_state, model_state, data, labels, rng,
                  stepno, clr):
             def loss_fn(p):
@@ -294,6 +316,11 @@ class LocalOptimizer:
                     loss = jnp.where(ok, loss, jnp.nan)
             return new_params, new_opt, new_ms, loss
 
+        # the guard's where(ok, new, old) reads the donated inputs inside
+        # the program, which donation allows
+        donate = (0, 1, 2) if self._donates_state() else ()
+        step = jax.jit(step, donate_argnums=donate)
+        step.donates_state = bool(donate)
         return step
 
     def _current_clr(self) -> float:
@@ -369,9 +396,10 @@ class LocalOptimizer:
 
     # -- observability (run ledger + summaries) ------------------------------
 
-    def _run_start(self) -> None:
+    def _run_start(self, **attrs) -> None:
         """Open the run in the ledger (and arm the XLA compile hook) when
-        observability is enabled; free otherwise."""
+        observability is enabled; free otherwise.  ``attrs`` ride on the
+        ``run.start`` record."""
         if not run_ledger.enabled():
             return
         tracer.install_compile_hook()
@@ -386,7 +414,7 @@ class LocalOptimizer:
             device_count=jax.device_count(),
             platform=jax.default_backend(),
             start_step=self.state.get("neval", 0),
-            start_epoch=self.state.get("epoch", 1))
+            start_epoch=self.state.get("epoch", 1), **attrs)
 
     def _close_ingest(self) -> None:
         """Shut down a sharded ingest pipeline's worker pool when the
@@ -457,7 +485,7 @@ class LocalOptimizer:
     # -- main loop -----------------------------------------------------------
 
     def _run_step(self, feed: BatchAhead, stepno: int, label: str, data,
-                  dispatch, **attrs):
+                  dispatch, donates_state: Optional[bool] = None, **attrs):
         """One step of any of the trainer loops, the next batch started
         under it: ``dispatch(data)`` enqueues the step program and
         returns ``(*new_state, loss)``; ``feed.start()`` then fetches and
@@ -469,7 +497,8 @@ class LocalOptimizer:
         the copy); after the sync nothing overlaps it.  The watchdog
         guards the dispatch and the sync, each with the whole timeout,
         and not the fetch between them: a slow decode is not a hung
-        step."""
+        step.  ``donates_state``, where the loop knows it, rides on the
+        ``train.dispatch`` span."""
         watchdog = partial(Watchdog, self.step_timeout, label=label)
         with tracer.span("train.step", step=stepno, **attrs):
             with watchdog():
@@ -480,7 +509,9 @@ class LocalOptimizer:
                     data = jnp.full_like(data, jnp.nan)  # NaN fwd -> grads
                 # the call returns once the program is enqueued; the
                 # wait for it is the sync's
-                with tracer.span("train.dispatch"):
+                with tracer.span("train.dispatch", **(
+                        {} if donates_state is None
+                        else {"donates_state": donates_state})):
                     *new_state, loss = dispatch(data)
             feed.start()
             # blocks: the whole fused step (compute + collectives) — the
@@ -490,7 +521,7 @@ class LocalOptimizer:
                 return new_state, float(loss)
 
     def optimize(self):
-        self._run_start()
+        self._run_start(donates_state=self._donates_state())
         with tracer.span("init", optimizer=type(self).__name__):
             self._maybe_resume()
             if self.model.params is None:
@@ -504,6 +535,13 @@ class LocalOptimizer:
             # and the SAME jitted step below becomes the GSPMD trainer
             params, opt_state = self._place_state(params, opt_state)
             step = self._build_step()
+            if step.donates_state:
+                # the step consumes the state it is given: hand it a copy
+                # of its own, so the trees the caller holds (model.params,
+                # model.state, a resumed opt_state; the placement above
+                # may return them as they are) stay readable
+                params, opt_state, model_state = jax.device_put(
+                    (params, opt_state, model_state), may_alias=False)
 
             count_this_epoch = self.state.get("recordsProcessedThisEpoch",
                                               0)
@@ -542,11 +580,21 @@ class LocalOptimizer:
                             model_state, data, labels, sub,
                             jnp.asarray(stepno, jnp.int32), clr,
                             kind=type(self).__name__)
-            (params, opt_state, model_state), loss = self._run_step(
-                feed, stepno, f"train step {stepno}", data,
-                lambda data: step(
+
+            def dispatch(data):
+                nonlocal params, opt_state, model_state
+                params, opt_state, model_state, loss = step(
                     params, opt_state, model_state, data, labels, sub,
-                    jnp.asarray(stepno, jnp.int32), clr))
+                    jnp.asarray(stepno, jnp.int32), clr)
+                # the call may have consumed the state it was given: from
+                # here on the facade names its result, also when the loop
+                # leaves by an exception before the bookkeeping
+                self.model.params, self.model.state = params, model_state
+                return params, opt_state, model_state, loss
+
+            _, loss = self._run_step(
+                feed, stepno, f"train step {stepno}", data, dispatch,
+                donates_state=step.donates_state)
             dt = time.time() - t0
             # everything after the step itself — metrics/ledger/summary
             # bookkeeping, logging, the epoch's counters, validation and
@@ -582,8 +630,6 @@ class LocalOptimizer:
                     count_this_epoch = 0
                     self.state["recordsProcessedThisEpoch"] = 0
 
-                # keep the facade fields fresh for triggers/validation
-                self.model.params, self.model.state = params, model_state
                 self._maybe_validate()
                 self._maybe_checkpoint(opt_state)
                 self.state["isLastBatchOfEpoch"] = False
@@ -591,7 +637,6 @@ class LocalOptimizer:
                 # crash a relaunch with auto_resume must recover from
                 FaultInjector.fire("train.step", step=self.state["neval"])
 
-        self.model.params, self.model.state = params, model_state
         wall = time.time() - wall_start
         logger.info("Training finished in %.1fs (%d iterations)",
                     wall, self.state["neval"])

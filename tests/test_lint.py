@@ -53,6 +53,7 @@ EXPECTED = {
         ("use-after-donate", "bad_loop_no_rebind"),
         ("use-after-donate", "bad_factory_step"),
         ("use-after-donate", "bad_argnames_read"),
+        ("use-after-donate", "Trainer.bad_loop_reads_donated_params"),
     ]),
     "host_calls.py": sorted([
         ("host-call-in-jit", "bad_print"),
