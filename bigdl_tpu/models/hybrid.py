@@ -53,7 +53,7 @@ from bigdl_tpu.core.module import Module, child_rng
 from bigdl_tpu.models.transformer import _embed_rows
 from bigdl_tpu.nn.attention import _proj
 from bigdl_tpu.parallel.expert import (EXPERT_FORMS, held_experts_apply,
-                                       sigmoid_group_route)
+                                       sigmoid_group_route, streams_hit)
 
 _F32 = jnp.float32
 MIXERS = ("kda", "mla", "swa", "full", "mamba2")
@@ -290,6 +290,27 @@ class HybridLM(Module):
                     out[name][kind] = out[name].get(kind, 0) + n
         return out
 
+    @property
+    def held_experts_total(self) -> int:
+        """Held experts over all the expert layers: what a decode step
+        reads in the dense form."""
+        return self.experts_held * sum(
+            f in ("experts", "latent_experts") for _, f in self.layers)
+
+    def streams_hit_experts(self, params, rows: int) -> bool:
+        """Whether a decode step of ``rows`` rows reads, in every expert
+        layer, only the held experts its rows hit (``held_experts_apply``'s
+        hit form, chosen by the shapes alone): what the step compiles to,
+        so that the generator can tell what share of the held experts'
+        weights a step streams (``experts_hit`` counts them)."""
+        layers = [b["ffn"]["experts"] for (_, f), b
+                  in zip(self.layers, params["blocks"])
+                  if f in ("experts", "latent_experts")]
+        return bool(layers) and all(
+            streams_hit(rows, self.experts_per_token, self.num_experts,
+                        w.get("w_gate_up", w.get("w_up")), w["w_down"])
+            for w in layers)
+
     # -- the layers --------------------------------------------------------------
 
     def _route(self, p, x):
@@ -309,7 +330,8 @@ class HybridLM(Module):
         with jax.named_scope("experts"):
             y, counters = held_experts_apply(
                 x, ids, gates, valid, p["experts"]["w_gate_up"],
-                p["experts"]["w_down"], self.expert_offset)
+                p["experts"]["w_down"], self.expert_offset,
+                n_experts=self.num_experts)
         with jax.named_scope("shared"):
             y = y + self.shared.apply(p["shared"], {}, x)[0]
         return y, counters
@@ -334,7 +356,8 @@ class HybridLM(Module):
             y, counters = held_experts_apply(
                 lat, ids, gates, valid,
                 w["w_gate_up" if self.expert_act == "swiglu" else "w_up"],
-                w["w_down"], self.expert_offset, self.expert_act)
+                w["w_down"], self.expert_offset, self.expert_act,
+                n_experts=self.num_experts)
         with jax.named_scope("latent.up"):
             y = _proj(y, p["latent_up"])
         with jax.named_scope("shared"):

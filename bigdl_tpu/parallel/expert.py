@@ -41,6 +41,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from bigdl_tpu.ops.experts import (EXPERT_FORMS, expert_hidden,
+                                   held_experts_hit, hit_kernel_fits)
+
 
 def top1_route(logits: jnp.ndarray):
     """Softmax router, hard top-1 assignment.
@@ -357,19 +360,39 @@ class MixtureOfExperts(Module):
 # ``[expert_offset, expert_offset + experts_held)`` and computes what
 # those give for the tokens routed to them; what the absent experts would
 # add is left out (their chips add it), the gates are normalised over all
-# of a token's chosen experts wherever they live.  No capacity: the
-# token-expert pairs are sorted by expert and multiplied group by group
-# (``lax.ragged_dot``), so no token is dropped and an expert without
-# tokens costs nothing but its empty group.  A batch of few tokens (a
-# decode step) takes every held expert over every token instead, with a
-# gate of zero where the token was not routed there: the same sum, and a
-# time that does not follow which experts the batch happened to hit.
+# of a token's chosen experts wherever they live.  No capacity, no drops;
+# three forms of one sum, chosen by the call's shapes:
+#
+# * sorted pairs (past `DENSE_TOKENS` tokens: a prefill): the token-expert
+#   pairs sorted by expert and multiplied group by group
+#   (``lax.ragged_dot``), whole or block by block (`PAIR_ELEMENTS`);
+# * every held expert over every token, a gate of zero where the token
+#   was not routed there (a decode step whose tokens hit nearly every
+#   held expert: more than `HIT_PAIRS` pairs a held expert expected): it
+#   reads each held expert's weights once, which is all a step that hits
+#   them all must read, and its time does not follow the routing;
+# * the HIT experts alone (a decode step of fewer pairs a held expert,
+#   where a third or more of the held experts get none): the Pallas
+#   kernel of ``ops/experts.py`` walks the held experts with a pair and
+#   reads no other's weights; its time follows how many a step hits.
 
-#: at most this many tokens go through every held expert (the product
-#: then reads each held expert's weights once whatever the routing; the
-#: multiplications it adds stay under the time of that read up to about
-#: 240 tokens at these widths on a v5e)
+#: at most this many tokens go through every held expert, or through the
+#: hit ones (the product then reads each expert's weights once whatever
+#: its tokens; the multiplications it adds stay under the time of that
+#: read up to about 240 tokens at these widths on a v5e)
 DENSE_TOKENS = 128
+#: a call of at most `DENSE_TOKENS` tokens whose pairs a held expert, under
+#: even routing (tokens x k / the router's experts), are at most this many
+#: reads the hit experts alone: at 1 pair a held expert (``ling3_flash_vl``'s
+#: 64 rows of 8 over 512 experts) 37% of them get none, at 2 pairs 14%.
+#: Each alone on a v5e at that model's widths (128 held experts of 2560 x
+#: 768), dense against hit, ms a layer: 0.5 pairs 2.05 -> 0.92, 1:
+#: 2.10 -> 1.40, 2: 2.09 -> 1.94, 3: 2.07 -> 2.02, 4: 2.11 -> 2.07, 6:
+#: 2.12 -> 2.11.  Past 2 the kernel spares 1-3% and brings back a time
+#: that follows the routing, where the dense form reads every held expert
+#: at its floor (``k_exaone_236b`` at 6 pairs, ``nemotron3_super_120b`` at
+#: 5.5: 83% and 94% of it), so those take the dense form
+HIT_PAIRS = 2
 #: past this many elements in the sorted pairs' gathered inputs (T x k x E)
 #: the grouped product goes BLOCK by block of sorted pairs and stops at the
 #: last pair routed here: a long prefill of wide tokens (8,192 x 8 x 6,144)
@@ -408,20 +431,27 @@ def sigmoid_group_route(scores, bias, k: int, n_group: int, topk_group: int,
     return ids, gates
 
 
-#: an expert's form, by what its first matrix's columns are: gate and up
-#: side by side, ``silu(x Wg) * (x Wu)``, or up alone, ``relu(x Wu)^2``
-EXPERT_FORMS = ("swiglu", "relu2")
+def hit_form(t: int, k: int, n_experts: int) -> bool:
+    """Whether ``held_experts_apply`` takes a call of ``t`` tokens of ``k``
+    choices among the router's ``n_experts`` through the hit experts
+    alone: few tokens (`DENSE_TOKENS`) sending at most `HIT_PAIRS` pairs a
+    held expert under even routing.  A trained router is less even than
+    that, so it hits fewer experts than the estimate: the rule errs
+    towards the dense form."""
+    return t <= DENSE_TOKENS and t * k <= HIT_PAIRS * n_experts
 
 
-def _expert_hidden(h, f: int, form: str):
-    """An expert's hidden row of ``f`` columns from the first product's."""
-    if form == "relu2":
-        return jnp.square(jax.nn.relu(h))
-    return jax.nn.silu(h[..., :f]) * h[..., f:]
+def streams_hit(t: int, k: int, n_experts: int, w_gate_up, w_down) -> bool:
+    """`hit_form` where the kernel serves these weights
+    (``ops.experts.hit_kernel_fits``): what a program of such a call
+    compiles to, and so what a model tells its generator."""
+    return hit_form(t, k, n_experts) \
+        and hit_kernel_fits(t, w_gate_up, w_down)
 
 
 def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
-                       expert_offset: int = 0, form: str = "swiglu"):
+                       expert_offset: int = 0, form: str = "swiglu",
+                       n_experts: Optional[int] = None):
     """What the experts held here add for ``x`` (T, E): ``ids``/``gates``
     (T, k) from the router over ALL experts, ``valid`` (T,) masks padding
     and inactive rows, ``w_gate_up`` (held, E, 2F) and ``w_down`` (held,
@@ -430,6 +460,9 @@ def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
     and ``w_gate_up`` is ``Wu`` alone, (held, E, F): ``relu(x Wu)^2``
     through ``Wd``.  Pairs whose expert is absent (or whose token is not
     valid) sort behind every held group and contribute zero.
+    ``n_experts``: the router's count of experts (by default the held
+    ones are all there are); with the call's shapes it chooses the form
+    (see the comment above `DENSE_TOKENS`).
 
     Returns (y (T, E) in ``x``'s dtype, counters): ``pairs`` routed to
     held experts, ``hit`` held experts with at least one, ``max`` the
@@ -452,9 +485,16 @@ def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
         dense = jnp.zeros((t, held), jnp.float32).at[
             jnp.arange(t)[:, None], jnp.clip(local, 0, held - 1)].add(
             jnp.where(here, gates, 0.0))
+        if streams_hit(t, k, n_experts or held, w_gate_up, w_down):
+            # the held experts with a pair first: their weights alone
+            # come through the kernel
+            hit = jnp.argsort(sizes == 0, stable=True)
+            y = held_experts_hit(x, dense, hit, counters["hit"], w_gate_up,
+                                 w_down, form)
+            return y.astype(x.dtype), counters
         h = jnp.einsum("te,gef->tgf", x, w_gate_up,
                        preferred_element_type=jnp.float32)
-        h = _expert_hidden(h, f, form) * dense[..., None]
+        h = expert_hidden(h, f, form) * dense[..., None]
         y = jnp.dot(h.astype(x.dtype).reshape(t, -1),
                     w_down.reshape(held * f, -1),
                     preferred_element_type=jnp.float32)
@@ -467,7 +507,7 @@ def held_experts_apply(x, ids, gates, valid, w_gate_up, w_down,
     xs = x[order // k]                                       # sorted pairs
     h = lax.ragged_dot(xs, w_gate_up, sizes,
                        preferred_element_type=jnp.float32)
-    h = _expert_hidden(h, f, form).astype(x.dtype)
+    h = expert_hidden(h, f, form).astype(x.dtype)
     ys = lax.ragged_dot(h, w_down, sizes,
                         preferred_element_type=jnp.float32)
     # rows past the last held group belong to no group: whatever the
@@ -504,7 +544,7 @@ def _held_pairs_blocked(x, order, sizes, n_here, gates, w_gate_up, w_down,
                 - jnp.clip(starts, lo, lo + PAIR_BLOCK)).astype(jnp.int32)
         h = lax.ragged_dot(x[token], w_gate_up, part,
                            preferred_element_type=jnp.float32)
-        h = _expert_hidden(h, f, form).astype(x.dtype)
+        h = expert_hidden(h, f, form).astype(x.dtype)
         ys = lax.ragged_dot(h, w_down, part,
                             preferred_element_type=jnp.float32)
         live = lo + jnp.arange(PAIR_BLOCK) < n_here

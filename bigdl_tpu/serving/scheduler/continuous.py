@@ -600,6 +600,15 @@ class ContinuousGenerator:
             if self._recurrent else None
         self._moe_pairs = 0
         self._moe_hit = 0
+        # a model whose decode step may read only the held experts its
+        # rows hit tells so, and how many it holds in all
+        streams = getattr(model, "streams_hit_experts", None)
+        self._moe_streams = bool(
+            streams and "experts_hit" in self._counted
+            and streams(self.params, self.slots.num_slots))
+        self._moe_held = getattr(model, "held_experts_total", 0)
+        self._moe_steps = 0
+        self._moe_streamed = 0
         self._chunks = 0
         self._emitted = 0
         self._completed = 0
@@ -1851,11 +1860,22 @@ class ContinuousGenerator:
                        window_tokens=int(seen[steps < pos[None]].sum()))
         return out
 
-    def _counter_attrs(self, counts) -> dict:
-        """The model's counters of one program run as span attributes;
-        the expert layers' feed two running gauges: pairs per expert
-        that had any, and the most loaded expert's pairs over that."""
+    def _counter_attrs(self, counts, steps: int = 0) -> dict:
+        """The model's counters of one program run (of ``steps`` decode
+        steps; a prefill: 0) as span attributes; the expert layers' feed
+        three running gauges: pairs per expert that had any, the most
+        loaded expert's pairs over that, and the share of the held
+        experts whose weights a decode step read (1 where it reads every
+        one; the hit experts over those held where it reads those
+        alone)."""
         out = {k: int(v) for k, v in counts.items()}
+        if steps and "experts_hit" in out:
+            self._moe_steps += steps
+            self._moe_streamed += out["experts_hit"]
+            self.metrics.set(
+                "serve.moe streamed share",
+                self._moe_streamed / (self._moe_steps * self._moe_held)
+                if self._moe_streams else 1.0, unit="scalar")
         if out.get("experts_hit"):
             self._moe_pairs += out["expert_pairs"]
             self._moe_hit += out["experts_hit"]
@@ -1974,7 +1994,7 @@ class ContinuousGenerator:
                 self._tokens = np.array(tok)
                 self._pos = np.array(pos)    # toks, emitted: (steps, slots)
             sp.set(**self._state_attrs(emitted, pos),
-                   **self._counter_attrs(counts))
+                   **self._counter_attrs(counts, self.steps_per_sync))
         with tracer.span("serve.deliver"):
             now = time.monotonic()  # the chunk's tokens reached the host
             chunk_tokens = int(emitted.sum())

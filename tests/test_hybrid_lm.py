@@ -14,6 +14,7 @@ from benchmark.reference import ling3_flash as reference
 from bigdl_tpu import nn
 from bigdl_tpu.models.hybrid import HybridLM
 from bigdl_tpu.observability import ledger
+from bigdl_tpu.ops import experts as experts_ops
 from bigdl_tpu.ops.delta_rule import kda_chunked, kda_naive, kda_step
 from bigdl_tpu.parallel import expert
 from bigdl_tpu.parallel.expert import (held_experts_apply,
@@ -192,13 +193,18 @@ def _dense_experts(x, ids, gates, wgu, wd, offset=0):
     return out
 
 
-@pytest.fixture(params=["grouped", "every_expert"])
+@pytest.fixture(params=["grouped", "every_expert", "hit_experts"])
 def product(request, monkeypatch):
-    """Both forms of the held experts' product on the same small batches:
-    the sorted grouped one (any number of tokens) and every held expert
-    over every token (what a decode step's few tokens take)."""
+    """The three forms of the held experts' product on the same small
+    batches: the sorted grouped one (any number of tokens), every held
+    expert over every token (a decode step's few tokens that hit nearly
+    every held expert) and the hit experts alone through their kernel (a
+    decode step of fewer pairs a held expert; here in the interpreter)."""
     monkeypatch.setattr(expert, "DENSE_TOKENS",
                         0 if request.param == "grouped" else 128)
+    if request.param == "hit_experts":
+        monkeypatch.setattr(expert, "HIT_PAIRS", 1 << 30)
+        monkeypatch.setattr(experts_ops, "_interpret", lambda: True)
     return request.param
 
 
