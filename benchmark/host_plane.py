@@ -16,11 +16,16 @@ program on the device, and checks causality on the cell's devices: a
 ``*.sync`` span cannot end before the run's last operation ends, and a
 ``*.dispatch`` span cannot start after the run's first operation.  A run
 that breaks either rule by more than ``BREAK_NS`` is printed with the size
-of the break and marked, and the readers that cross the two clocks read
-nothing for the whole slice: the profile misplaces a slice's host plane
-against its device planes as a whole (PR 37 saw one slice of four whose
-every launch read under 0.1 ms), so the runs that keep the rules read a
-clock that cannot be trusted either."""
+of the break and marked, and ``fetch_tails_ms`` reads nothing for the whole
+slice: the profile misplaces a slice's host plane against its device planes
+as a whole (PR 37 saw one slice of four whose every launch read under
+0.1 ms), so the runs that keep the rules read a clock that cannot be
+trusted either.
+
+No per-layer metric reads the fetch tails: a listed metric has to read on
+every traced run, and on a TPU v5 lite from one in four to every traced
+slice of a cell broke causality.  The traced run prints them (``tails``)
+beside its breakdown."""
 
 from __future__ import annotations
 
@@ -189,6 +194,12 @@ def fetch_tails_ms(run, kind: str) -> Optional[float]:
     tails = [(r["sync"][1] - r["last_ns"]) / 1e6 for r in slice_runs
              if r["kind"] == kind]
     return statistics.median(tails) if tails else None
+
+
+def tails(run) -> Dict[str, Optional[float]]:
+    """``fetch_tails_ms`` of every kind of run the slice holds."""
+    return {k: fetch_tails_ms(run, k)
+            for k in sorted({r["kind"] for r in runs(run)})}
 
 
 def in_window(run, mono: float) -> bool:

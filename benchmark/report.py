@@ -7,7 +7,7 @@ import gzip
 import json
 import os
 
-from benchmark import cells, harness
+from benchmark import cells, harness, host_plane
 from benchmark.trace_reduce import top
 
 
@@ -34,6 +34,9 @@ def traced(run, device: dict) -> dict:
                      f"{run.breakdown['device_ops']}")
     harness.say(f"trace: idle seconds by host span "
                      f"{run.breakdown['idle_gaps']}")
+    harness.say(f"trace: fetch tails in ms, a program's last operation to "
+                f"the end of its sync span (none where the slice broke "
+                f"causality) {host_plane.tails(run)}")
     # the extracted event list, for the reader and for cutting fixtures
     with gzip.open(os.path.join(run.out_dir,
                                 f"events.{run.cell.name}.json.gz"),

@@ -34,6 +34,9 @@ def test_top_level_keys_and_limits():
     assert BENCH["command"][:2] == ["python", "benchmark/run.py"]
     assert set(BENCH["paths"]) == {"benchmark", "tests/benchmark_harness"}
     assert 1 <= BENCH["run_seconds"] <= 51
+    assert 1 <= len(BENCH["configs"]) <= 24
+    assert 1 <= len(BENCH["end_to_end"]) <= 16
+    assert 1 <= len(BENCH["per_layer"]) <= 128
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
     # a full check with the full 24 cells fits the driver's allowance
     assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
@@ -114,6 +117,7 @@ def test_every_moves_is_an_end_to_end_metric_of_each_cell_that_reports_it():
 def test_setup_s_everywhere_and_four_chip_cells_within_the_quarter():
     assert "workloads" not in E2E["setup_s"]
     assert E2E["setup_s"]["bound"] <= 0.1
+    assert 1 <= len(BENCH["workloads"]) <= 24
     four = [w for w in BENCH["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
 

@@ -34,15 +34,20 @@ def test_load_cell_finds_the_new_cell_and_its_readers():
     assert cell.chips == 1 and cell.traffic["kind"] == "closed"
     assert cell.config["model"]["args"] == [19200]
     assert {m["name"] for m in cell.end_to_end} \
-        == {"serve_tokens_per_s", "setup_s"}
+        >= {"serve_tokens_per_s", "setup_s"}
     names = {m["name"] for m in cell.per_layer}
-    assert names == {
+    assert names >= {
         "compile_s", "window_compiles", "slot_occupancy_pct",
         "decode_gap_ms", "decode_step_device_ms", "chat_device_idle_pct",
         "chunk_itl_p95_ms", "decode_kv_copy_pct", "prefill_window_share_pct",
-        "gqa_moe_prefill_mfu_pct", "gqa_paged_decode_roofline", "swa_ring_decode_roofline",
-        "held_experts_decode_roofline", "gqa_moe_step_mfu_pct",
-        "gqa_moe_step_hbm_pct"}
+        "gqa_moe_prefill_mfu_pct", "gqa_paged_decode_roofline",
+        "swa_ring_decode_roofline", "held_experts_decode_roofline",
+        "gqa_moe_step_mfu_pct", "gqa_moe_step_hbm_pct",
+        "decode_host_turn_ms"}
+    # the two whose cost function takes embed_dim for the page width, which
+    # is not this pool's (8 KV heads of 128 under a hidden size of 6,144)
+    assert not {"paged_attn_decode_roofline",
+                "paged_attn_prefill_roofline"} & names
     for name in names:
         assert hasattr(cells.load_metric(ROOT, name), "read")
 

@@ -1,5 +1,6 @@
-"""The program's spans on the profile's own clock (``benchmark/host_plane.py``)
-and the six readers of PR 37 on recorded fixtures.
+"""The program's spans on the profile's own clock (``benchmark/host_plane.py``),
+its fetch tails and the three host-clock readers of PR 37 on recorded
+fixtures.
 
 The fixtures ``benchmark/fixtures/*.pr37.*.json.gz`` were cut from this
 PR's traced chip runs (TPU v5 lite): a few runs of the cell's program, of
@@ -28,9 +29,9 @@ FIXTURES = {
 }
 SHIFTED = "inception_v1_local_b256.pr37.shifted.json.gz"
 NEW = {
-    "inception_v1.local_b256": ["train_sync_tail_ms", "train_host_turn_ms"],
-    "gpt2_xl.chat_closed": ["decode_fetch_tail_ms", "decode_host_turn_ms"],
-    "gpt2_xl.score_open": ["prefill_fetch_tail_ms", "prefill_host_prep_ms"],
+    "inception_v1.local_b256": ["train_host_turn_ms"],
+    "gpt2_xl.chat_closed": ["decode_host_turn_ms"],
+    "gpt2_xl.score_open": ["prefill_host_prep_ms"],
 }
 KIND = {"inception_v1.local_b256": "train.step",
         "gpt2_xl.chat_closed": "serve.decode",
@@ -59,6 +60,10 @@ def read(run, metric):
     return cells.load_metric(ROOT, metric).read(run)
 
 
+def tail(run, cell):
+    return host_plane.fetch_tails_ms(run, KIND[cell])
+
+
 CASES = [(c, m) for c in sorted(NEW) for m in NEW[c]]
 
 
@@ -85,6 +90,27 @@ def test_each_reader_is_silent_on_the_parents_program(cell, metric):
 
 
 @pytest.mark.parametrize("cell", sorted(FIXTURES))
+def test_the_fetch_tail_reads_a_number_on_its_fixture(cell):
+    """A clean slice: the tail of its own kind, in the traced run's line
+    of tails, and no per-layer metric that crosses the two clocks."""
+    run = load(cell)
+    value = tail(run, cell)
+    assert value is not None and value > 0.0
+    assert host_plane.tails(run)[KIND[cell]] == value
+    assert not {"train_sync_tail_ms", "decode_fetch_tail_ms",
+                "prefill_fetch_tail_ms"} & {
+        m["name"] for m in cells.load_benchmark(ROOT)["per_layer"]}
+
+
+@pytest.mark.parametrize("cell", sorted(FIXTURES))
+def test_the_fetch_tail_is_silent_on_the_parents_program(cell):
+    run = load(cell)
+    run.host_events = []
+    run.records = [r for r in run.records if r.get("name") not in NEW_SPANS]
+    assert tail(run, cell) is None and host_plane.tails(run) == {}
+
+
+@pytest.mark.parametrize("cell", sorted(FIXTURES))
 def test_every_span_event_joins_its_record_by_id(cell):
     run = load(cell)
     ev = host_plane.joined(run)
@@ -102,7 +128,7 @@ def test_a_shifted_clock_breaks_causality_and_the_slice_reads_none(
         cell, capsys):
     """The profile's host events moved earlier than any sync's tail: every
     sync now ends before its run's last operation; the break is printed
-    and the cross-clock reader reads nothing."""
+    and the fetch tail reads nothing."""
     run = load(cell)
     tail_ns = max(r["sync"][1] - r["last_ns"] for r in host_plane.runs(run))
     shifted = load(cell)
@@ -113,9 +139,10 @@ def test_a_shifted_clock_breaks_causality_and_the_slice_reads_none(
     assert runs and not any(r["ok"] for r in runs)
     assert all(r["breaks"]["sync"] > host_plane.BREAK_NS for r in runs)
     assert "causality broken" in capsys.readouterr().out
-    assert read(shifted, NEW[cell][0]) is None
+    assert tail(shifted, cell) is None
+    assert host_plane.tails(shifted)[KIND[cell]] is None
     # the reader on the host's clock alone does not cross the two
-    assert read(shifted, NEW[cell][1]) == read(run, NEW[cell][1])
+    assert read(shifted, NEW[cell][0]) == read(run, NEW[cell][0])
 
 
 @pytest.mark.parametrize("cell", sorted(FIXTURES))
@@ -153,7 +180,7 @@ def test_a_slice_the_profile_misplaced_is_caught_by_causality(capsys):
     host-to-device alignment moved, not the monotonic clock pair.  The
     offset is the whole slice's: the three steps that keep causality read
     a tail of 2.71 ms against 1.70-1.78 on clean slices.  The breaks are
-    printed and the cross-clock reader reads nothing for the slice."""
+    printed and the fetch tail reads nothing for the slice."""
     run = load("inception_v1.local_b256", SHIFTED)
     off = run.trace.sync["trace_ns"] - run.trace.sync["mono_ns"]
     shift = [abs(e["rec"]["mono"] * 1e9 + off - e["start_ns"]) / 1e6
@@ -170,7 +197,7 @@ def test_a_slice_the_profile_misplaced_is_caught_by_causality(capsys):
     assert capsys.readouterr().out.count("causality broken") == 5
     tails = [(r["sync"][1] - r["last_ns"]) / 1e6 for r in runs if r["ok"]]
     assert sorted(tails)[1] > 2.5           # biased high by the offset
-    assert read(run, "train_sync_tail_ms") is None
+    assert host_plane.fetch_tails_ms(run, "train.step") is None
     # the reader on the host's clock alone still reads
     assert read(run, "train_host_turn_ms") is not None
 

@@ -34,7 +34,7 @@ def test_load_cell_finds_the_new_cell_and_its_readers():
     assert cell.traffic_name == "mixed_closed"
     assert cell.config["model"]["args"] == [32768]
     assert {m["name"] for m in cell.end_to_end} \
-        == {"serve_tokens_per_s", "setup_s"}
+        >= {"serve_tokens_per_s", "setup_s"}
     names = {m["name"] for m in cell.per_layer}
     assert names >= {
         "compile_s", "window_compiles", "slot_occupancy_pct",
@@ -45,11 +45,11 @@ def test_load_cell_finds_the_new_cell_and_its_readers():
         "ssm_moe_prefill_mfu_pct"}
     for name in names:
         assert hasattr(cells.load_metric(ROOT, name), "read")
-    # the benchmark: five configurations, seven cells, one on four chips
+    # the configuration and the cell are in the benchmark by name, wherever
+    # they stand and however many entries a later PR appends
     bench = cells.load_benchmark(ROOT)
-    assert len(bench["configs"]) == 5 and len(bench["workloads"]) == 7
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    assert bench["workloads"][-1]["name"] == CELL
+    assert "nemotron3_super_120b" in {c["name"] for c in bench["configs"]}
+    assert {w["name"]: w["chips"] for w in bench["workloads"]}[CELL] == 1
     assert all(len(w["why"]) <= 200 for w in bench["workloads"])
     assert all(len(c["why"]) <= 200 for c in bench["configs"])
 
