@@ -105,13 +105,16 @@ def _page_slots(pages, positions, ps: int, trash: int, active):
     return phys, positions % ps
 
 
-@functools.partial(jax.jit, inline=True)
-def _write_rows(pool, new, phys, offs):
+@functools.partial(jax.jit, inline=True, static_argnames=("by_page",))
+def _write_rows(pool, new, phys, offs, by_page=True):
     """The cache write: the tokens ``new`` (B, Hkv, S, D) as rows of a
     pool ``(P + 1, ps, W)`` at ``(phys, offs)`` (B, S), the page and the
     offset in it of each token — a scatter whose window is one
     contiguous row of the pool.  Traced once for all the layers of a
-    model (and for K and V): they share its shapes."""
+    model (and for K and V): they share its shapes.  ``by_page=False``
+    keeps to the token rows: inside a loop that carries the pool, the
+    page-by-page choice below (a ``cond`` on the pool) makes the compiler
+    copy the whole pool into each of its branches."""
     from bigdl_tpu.ops.attention import paged_pool_dims
     ps, width = paged_pool_dims(pool)
     b, _, s, _ = new.shape
@@ -120,7 +123,7 @@ def _write_rows(pool, new, phys, offs):
     def by_tokens(c):
         return c.at[phys.reshape(-1), offs.reshape(-1)].set(flat)
 
-    if s % ps:
+    if s % ps or not by_page:
         return by_tokens(pool)
 
     # a whole number of pages (a prefill bucket): where every row starts
@@ -770,28 +773,40 @@ class GroupedQueryAttention(Module):
             return _proj(o.transpose(0, 2, 1, 3).reshape(b, s, h * d),
                          params["wo"])
 
-    def apply_decode_pages(self, params, x_t, cache, pages, pos, active):
+    def apply_decode_pages(self, params, x_t, cache, pages, pos, active,
+                           pool=None):
         """A FULL layer: ``MultiHeadAttention.apply_decode_pages``'s
         contract (writes of inactive rows and unmapped positions go to
         the trash page; one token reads its row's pages through the
-        kernel or the gather).  Returns (y (B, S, E), cache')."""
-        from bigdl_tpu.ops.attention import paged_pool_dims
+        kernel or the gather).  With ``pool`` (an int32 scalar, may be
+        traced) ``cache`` holds a page-major STACK of pools ``(P + 1, N,
+        ps, W)`` and the layer writes and reads pool ``pool`` of it in
+        place (``ops.attention.stacked_pool``).  Returns (y (B, S, E),
+        cache')."""
+        from bigdl_tpu.ops.attention import paged_pool_dims, stacked_pool
         s = x_t.shape[1]
         positions = jnp.asarray(pos)[:, None] + jnp.arange(s)    # (B, S)
         with jax.named_scope("qkv"):
             q, k, v = self._qkv(params, x_t, positions)
-        ps, trash = paged_pool_dims(cache["k"])[0], cache["k"].shape[0] - 1
+        pk, pv = cache["k"], cache["v"]
         pages = jnp.asarray(pages, jnp.int32)
+        if pool is not None:
+            pk, table = stacked_pool(pk, pages, pool)
+            pv, _ = stacked_pool(pv, pages, pool)
+            pages = table
+        ps, trash = paged_pool_dims(pk)[0], pk.shape[0] - 1
         phys, offs = _page_slots(pages, positions, ps, trash, active)
         with jax.named_scope("kv.write"):
-            ck = _write_rows(cache["k"], k, phys, offs)
-            cv = _write_rows(cache["v"], v, phys, offs)
+            ck = _write_rows(pk, k, phys, offs, by_page=pool is None)
+            cv = _write_rows(pv, v, phys, offs, by_page=pool is None)
         if s > 1:
             with jax.named_scope("attn.prefill"):
                 o = self._own_tokens(q, k, v)
         else:
             o = _paged_read(q, ck, cv, pages, positions, self.scale,
                             self.num_kv_heads)
+        if pool is not None:
+            ck, cv = ck.reshape(cache["k"].shape), cv.reshape(cache["v"].shape)
         return self._out(params, o.astype(x_t.dtype)), {"k": ck, "v": cv}
 
     def apply_slots(self, params, x, st, pos, active, lengths=None):

@@ -824,9 +824,9 @@ def paged_pool_width(num_kv_heads: int, head_dim: int) -> int:
 
 
 def paged_pool_dims(pool):
-    """(page size, width) of a page pool ``(P + 1, ps, W)``: axis 0 is
-    the page axis, its last page the write-redirect trash page."""
-    return pool.shape[1], pool.shape[2]
+    """(page size, width) of a page pool ``(P + 1, ps, W)`` (or of a
+    stack ``(P + 1, N, ps, W)``: `stacked_pool`), the trash page last."""
+    return pool.shape[-2], pool.shape[-1]
 
 
 # up to this many bytes of f32 scores, a step has FEW QUERIES (a decode
@@ -1269,3 +1269,20 @@ def _paged_call(q, k_pool, v_pool, pages, positions, *, scale, hkv, tiling,
             .transpose(0, 1, 4, 2, 3, 5)
     return out.reshape(b, hp, group, s, dp)[:, :hkv, ..., :d] \
         .reshape(b, h, s, d)
+
+
+def stacked_pool(stack, pages, index):
+    """Pool ``index`` of a PAGE-MAJOR stack of ``N`` pools ``(P + 1, N,
+    ps, W)`` as one pool that the cache write and `paged_attention` take
+    as they are: the stack's pages in a row, ``((P + 1) x N, ps, W)`` (a
+    reshape of the leading axes: nothing moves), and the page table
+    ``pages`` (B, Lp) of that pool mapped onto the row, page ``p`` of
+    pool ``i`` at ``p x N + i``, every pool's trash page ``P`` at the
+    row's last page (the trash of the pool returned).  ``index`` may be
+    traced: a program that applies one layer body to every pool in a
+    loop addresses pool ``index`` of the stack it carries, and no pool
+    is ever sliced out of it.  Returns (pool, pages)."""
+    p1, n = stack.shape[:2]
+    flat = stack.reshape((p1 * n,) + stack.shape[2:])
+    pages = jnp.asarray(pages, jnp.int32)
+    return flat, jnp.where(pages == p1 - 1, p1 * n - 1, pages * n + index)
