@@ -1,16 +1,17 @@
 """The trainers' input, held one batch ahead of the device.
 
 Every trainer loop (``LocalOptimizer.optimize``, ``DistriOptimizer``'s flat
-and spec loops) syncs on ``float(loss)`` once a step, so nothing the host
-does after that sync overlaps the device.  A host-to-device copy started
-there (154 MB a chip for a batch of 256 float32 images) leaves the device
-idle until it has crossed.  ``BatchAhead`` moves that copy: the loop takes
-the batch of step N, dispatches the step, and calls ``start()`` BEFORE it
-syncs, so batch N+1 is fetched and put while the device runs step N.  One
-thread, one batch in flight, no depth to choose: ``device_put`` is
-asynchronous (a copy enqueued behind a running program crosses beside it;
-measured on a v5e, ``PERF.md`` section 6, PR 34), and where a loop blocks
-on the copy the host would have waited in the sync anyway.
+and spec loops) syncs on a step's ``float(loss)`` once a step.  A
+host-to-device copy started after that sync (154 MB a chip for a batch of
+256 float32 images) would leave the device idle until it had crossed.
+``BatchAhead`` moves that copy: the loop takes the batch of step N,
+dispatches the step, and calls ``start()`` BEFORE it syncs (on step N-1,
+whose loss the loops read one step late), so batch N+1 is fetched and put
+while the device runs.  One thread, one batch in flight, no depth to
+choose: ``device_put`` is asynchronous (a copy enqueued behind a running
+program crosses beside it; measured on a TPU v5e, ``PERF.md`` section 6),
+and where a loop blocks on the copy the host would have waited in the sync
+anyway.
 """
 
 from __future__ import annotations
@@ -95,11 +96,12 @@ class BatchAhead:
 
     def start(self) -> None:
         """Fetch the next batch and start its copy.  Called between a
-        step's dispatch and its sync, outside the step's watchdog (a slow
-        decode is not a hung step).  An error here belongs to the NEXT
-        step: it is held, so the running step's record, validation and
-        checkpoint are written first, and ``take()`` raises it.  The
-        batch started under a run's last step is dropped."""
+        step's dispatch and the sync that follows it, outside the step's
+        watchdog (a slow decode is not a hung step).  An error here
+        belongs to the NEXT step: it is held, so the running step's
+        record, validation and checkpoint are written first, and
+        ``take()`` raises it.  The batch started under a run's last step
+        is dropped."""
         try:
             self._flight = self._fetch_and_put(ahead=True)
         except Exception as e:          # raised by take()

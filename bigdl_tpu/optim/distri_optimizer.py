@@ -27,7 +27,6 @@ per-node multi-core replicas map to the per-device batch dimension.
 from __future__ import annotations
 
 import logging
-import math
 import os
 import time
 from contextlib import nullcontext as _nullcontext
@@ -50,7 +49,6 @@ from bigdl_tpu.parallel import mesh as mesh_mod
 from bigdl_tpu.parallel.allreduce import (make_distri_eval_fn,
                                           make_distri_eval_from_shard,
                                           make_distri_train_step)
-from bigdl_tpu.resilience.fault_injector import FaultInjector
 from bigdl_tpu.resilience.watchdog import Watchdog
 
 logger = logging.getLogger("bigdl_tpu.optim")
@@ -142,13 +140,14 @@ class DistriOptimizer(LocalOptimizer):
         self._elastic = None                  # ElasticCoordinator
         self._elastic_restore_step = None     # generation-pinned restore
 
-    def _check_drop_budget(self, skipped: int) -> None:
-        """Enforce the straggler knobs over the skipped-step ledger:
+    def _check_drop_budget(self, skipped: int, total: int) -> None:
+        """Enforce the straggler knobs over the skipped-step ledger,
+        ``skipped`` of the first ``total`` steps:
         ``drop_percentage`` is the expected/tolerated rate — crossing it
         warns once; ``max_drop_percentage`` is the hard cap — crossing
         it aborts (the reference aborts the epoch when dropped gradients
         exceed the budget, ``DistriOptimizer.scala:244-272``)."""
-        total = max(self.state["neval"] + 1, 1)
+        total = max(total, 1)
         if self.drop_percentage and not self._drop_warned and \
                 skipped > total * self.drop_percentage:
             self._drop_warned = True
@@ -166,6 +165,45 @@ class DistriOptimizer(LocalOptimizer):
                 f"{self.max_drop_percentage}: the model is diverging "
                 "(weights are intact from the last good step — lower "
                 "the learning rate or resume from a snapshot)")
+
+    def _record_skipped_step(self, stepno: int) -> int:
+        """The skip ledger, held to the straggler knobs' budget."""
+        skipped = super()._record_skipped_step(stepno)
+        self._check_drop_budget(skipped, stepno + 1)
+        return skipped
+
+    def _record_step(self, stepno, records, clr, ds_size, state, loss, dt,
+                     per_node: bool = False) -> None:
+        """The trainers' step record, and the reference's metric names
+        (``DistriOptimizer.scala:115-119,148-151,180-182,214``).  The
+        fused XLA step has no separate get-weights / aggregate phases to
+        time from the host — the collectives overlap with compute inside
+        one program — so the whole step lands under "computing time"
+        (``per_node``: the flat ring's per-node name too); a
+        jax.profiler trace taken with the run ledger on shows the
+        intra-step breakdown beside the tracer's spans."""
+        super()._record_step(stepno, records, clr, ds_size, state, loss, dt)
+        if per_node:
+            self.metrics.add("computing time for each node", dt * 1e9)
+        self.metrics.set("loss", loss, unit="scalar")
+
+    def _due(self):
+        """The SPMD loops' triggers, asked before the next dispatch (they
+        read the counters alone): a sharded snapshot, a validation, a File
+        snapshot.  Any that fires drains the step in flight first, so it
+        sees the state of exactly ``neval`` steps.  Returns the three
+        answers."""
+        due = (bool(self.sharded_checkpoint_trigger and
+                    self.sharded_checkpoint_path and
+                    self._elastic_should_write() and
+                    self.sharded_checkpoint_trigger(self.state)),
+               bool(self.validation_trigger and
+                    self.validation_trigger(self.state)),
+               bool(self.checkpoint_trigger and self.checkpoint_path and
+                    self.checkpoint_trigger(self.state)))
+        if any(due):
+            self._drain()
+        return due
 
     def _validate_from_shard(self, wshard, model_state):
         """Validation consuming the ZeRO-1 weight shard directly — the
@@ -804,125 +842,101 @@ class DistriOptimizer(LocalOptimizer):
         wall_start = time.time()
 
         cost_done = False          # one cost.analysis per optimize()
-        while True:
-            with tracer.span("loop.next"):      # the loop's head
-                if self.end_when(self.state):
-                    break
-                # elastic membership poll BEFORE the batch is taken: a
-                # committed generation change aborts exactly at a step
-                # boundary (no step in a stale world; the batch in flight
-                # from the old stream is dropped with this loop's feed)
-                self._elastic_step_boundary()
-                data, labels, bs = feed.take()
-                t0 = time.time()
-                self._rng, sub = jax.random.split(self._rng)
-                clr_val = self._current_clr()
-                clr = jnp.asarray(clr_val, jnp.float32)
+        with self._one_step_in_flight():
+            while True:
+                # the loop's head; the step before is still in flight (see
+                # the LocalOptimizer loop)
+                with tracer.span("loop.next"):
+                    if self.end_when(self.state):
+                        break
+                    # elastic membership poll BEFORE the batch is taken: a
+                    # committed generation change aborts exactly at a step
+                    # boundary (no step in a stale world; the step in
+                    # flight is drained as the loop unwinds, the batch in
+                    # flight from the old stream is dropped with this
+                    # loop's feed)
+                    self._elastic_step_boundary()
+                    data, labels, bs = feed.take()
+                    t0 = time.time()
+                    self._rng, sub = jax.random.split(self._rng)
+                    clr_val = self._current_clr()
+                    clr = jnp.asarray(clr_val, jnp.float32)
 
-            stepno = self.state["neval"]
-            if not cost_done:
-                cost_done = True
-                if costs.costs_enabled():
-                    # price the flat-ring step executable once (FLOPs/
-                    # bytes via XLA's cost model; one extra AOT compile,
-                    # span-attributed so coverage stays honest)
-                    with tracer.span("cost.analysis"):
-                        costs.emit_cost(
-                            "train.step", step, wshard, opt_shard,
-                            model_state, data, labels, sub,
-                            jnp.asarray(stepno, jnp.int32), clr,
-                            kind=type(self).__name__, sharding="flat")
-            new_state, loss = self._run_step(
-                feed, stepno, f"train step {stepno} (SPMD, n={n})", data,
-                lambda data: step(
-                    wshard, opt_shard, model_state, data, labels, sub,
-                    jnp.asarray(stepno, jnp.int32), clr),
-                n=n)
-            # the whole iteration but the first put, for throughput
-            dt = time.time() - t0
+                stepno = self.state["neval"]
+                if not cost_done:
+                    cost_done = True
+                    if costs.costs_enabled():
+                        # price the flat-ring step executable once (FLOPs/
+                        # bytes via XLA's cost model; one extra AOT
+                        # compile, span-attributed so coverage stays
+                        # honest)
+                        with tracer.span("cost.analysis"):
+                            costs.emit_cost(
+                                "train.step", step, wshard, opt_shard,
+                                model_state, data, labels, sub,
+                                jnp.asarray(stepno, jnp.int32), clr,
+                                kind=type(self).__name__, sharding="flat")
+                new_state, previous = self._run_step(
+                    feed, stepno, f"train step {stepno} (SPMD, n={n})", data,
+                    lambda data: step(
+                        wshard, opt_shard, model_state, data, labels, sub,
+                        jnp.asarray(stepno, jnp.int32), clr),
+                    t0=t0, n=n)
 
-            # Reference metric names (DistriOptimizer.scala:115-119,
-            # 148-151, 180-182, 214).  The fused XLA step has no separate
-            # get-weights / aggregate phases to time from the host — the
-            # collectives overlap with compute inside one program — so the
-            # whole step lands under "computing time"; a jax.profiler
-            # trace taken with the run ledger on shows the intra-step
-            # breakdown beside the tracer's spans.
-            # host-side loop tail span-attributed too (see the
-            # LocalOptimizer loop): counters, logging, snapshot and
-            # validation triggers; the step's state renamed first, so
-            # that letting the old one go is the tail's host time too
-            with tracer.span("loop.bookkeeping"):
-                wshard, opt_shard, model_state = new_state
-                costs.sample_hbm(step=stepno)
-                if self.skip_nonfinite and math.isnan(loss):
-                    self._check_drop_budget(self._record_skipped_step())
-                self.metrics.add("computing time average", dt * 1e9)
-                self.metrics.add("computing time for each node", dt * 1e9)
-                self.metrics.set("loss", loss, unit="scalar")
-                count_this_epoch += bs
-                self.state["neval"] += 1
-                self.state["recordsProcessedThisEpoch"] = count_this_epoch
-                self.state["isLastBatchOfEpoch"] = count_this_epoch >= ds_size
-                # post-update, pre-rollover: summary triggers see the
-                # completed-step counters (incl. isLastBatchOfEpoch)
-                self._emit_step_record(stepno, loss, bs, dt, clr_val)
-                logger.info(
-                    "Epoch %d %d/%d loss %.6f throughput %.1f records/second",
-                    self.state["epoch"], count_this_epoch, ds_size, loss,
-                    bs / max(dt, 1e-9))
+                # host-side loop tail span-attributed too (see the
+                # LocalOptimizer loop): this step's counters, the previous
+                # step's record, snapshot and validation triggers; the
+                # step's state renamed first, so that letting the old one
+                # go is the tail's host time too
+                with tracer.span("loop.bookkeeping"):
+                    wshard, opt_shard, model_state = new_state
+                    count_this_epoch = self._advance(
+                        bs, count_this_epoch, ds_size,
+                        partial(self._record_step, stepno, bs, clr_val,
+                                ds_size, per_node=True))
+                    if previous is not None:
+                        previous()
 
-                if count_this_epoch >= ds_size:
-                    self.state["epoch"] += 1
-                    count_this_epoch = 0
-                    self.state["recordsProcessedThisEpoch"] = 0
+                    do_sharded, do_val, do_ckpt = self._due()
+                    if do_sharded:
+                        from bigdl_tpu.utils import checkpoint as ckpt
+                        # async: returns after the device->host snapshot;
+                        # the write overlaps the next training steps
+                        with tracer.span("checkpoint.sharded.save",
+                                         step=self.state["neval"]):
+                            ckpt.save_sharded(self.sharded_checkpoint_path,
+                                              _snapshot(wshard, opt_shard,
+                                                        model_state),
+                                              step=self.state["neval"],
+                                              detach=layout.donates_state)
 
-                if self.sharded_checkpoint_trigger and \
-                        self.sharded_checkpoint_path and \
-                        self._elastic_should_write() and \
-                        self.sharded_checkpoint_trigger(self.state):
-                    from bigdl_tpu.utils import checkpoint as ckpt
-                    # async: returns after the device->host snapshot; the
-                    # write overlaps the next training steps
-                    with tracer.span("checkpoint.sharded.save",
-                                     step=self.state["neval"]):
-                        ckpt.save_sharded(self.sharded_checkpoint_path,
-                                          _snapshot(wshard, opt_shard,
-                                                    model_state),
-                                          step=self.state["neval"],
-                                          detach=layout.donates_state)
-
-                do_val = bool(self.validation_trigger and
-                              self.validation_trigger(self.state))
-                do_ckpt = bool(self.checkpoint_trigger and self.checkpoint_path
-                               and self.checkpoint_trigger(self.state))
-                multi = jax.process_count() > 1
-                if do_ckpt or (do_val and multi):
-                    # getModel parity (DistriOptimizer.scala:475-502): File
-                    # snapshots genuinely need host bytes, and multi-host
-                    # validation stays host-local (per-host data shards can't
-                    # be device_put against one global sharding) — ONE
-                    # reassembly serves both triggers
-                    with tracer.span("get_model"):
-                        self.model.params = layout.unflatten(
-                            _fetch_global(wshard).reshape(-1))
-                        self.model.state = model_state
-                if do_val:
-                    if multi:
-                        self.validate()
-                    else:
-                        # weights stay in HBM: the sharded evaluator
-                        # all_gathers the owned slices on-device (no getModel
-                        # host trip)
-                        self._validate_from_shard(wshard, model_state)
-                if do_ckpt:
-                    fetched = jax.tree_util.tree_map(_fetch_global, opt_shard)
-                    if jax.process_index() == 0:
-                        self._maybe_checkpoint(fetched)
-                self.state["isLastBatchOfEpoch"] = False
-                # injected preemption AFTER the snapshot logic: the crash a
-                # relaunch with auto_resume must recover from
-                FaultInjector.fire("train.step", step=self.state["neval"])
+                    multi = jax.process_count() > 1
+                    if do_ckpt or (do_val and multi):
+                        # getModel parity (DistriOptimizer.scala:475-502):
+                        # File snapshots genuinely need host bytes, and
+                        # multi-host validation stays host-local (per-host
+                        # data shards can't be device_put against one
+                        # global sharding) — ONE reassembly serves both
+                        # triggers
+                        with tracer.span("get_model"):
+                            self.model.params = layout.unflatten(
+                                _fetch_global(wshard).reshape(-1))
+                            self.model.state = model_state
+                    if do_val:
+                        if multi:
+                            self.validate()
+                        else:
+                            # weights stay in HBM: the sharded evaluator
+                            # all_gathers the owned slices on-device (no
+                            # getModel host trip)
+                            self._validate_from_shard(wshard, model_state)
+                    if do_ckpt:
+                        fetched = jax.tree_util.tree_map(_fetch_global,
+                                                         opt_shard)
+                        if jax.process_index() == 0:
+                            self._save_checkpoint(fetched)
+                    self.state["isLastBatchOfEpoch"] = False
+                    self._preempt()
 
         with tracer.span("get_model"):
             self.model.params = layout.unflatten(
@@ -1039,88 +1053,69 @@ class DistriOptimizer(LocalOptimizer):
         wall_start = time.time()
 
         cost_done = False          # one cost.analysis per optimize()
-        while True:
-            with tracer.span("loop.next"):      # the loop's head
-                if self.end_when(self.state):
-                    break
-                self._elastic_step_boundary()
-                data, labels, bs = feed.take()
-                t0 = time.time()
-                self._rng, sub = jax.random.split(self._rng)
-                clr_val = self._current_clr()
-                clr = jnp.asarray(clr_val, jnp.float32)
+        with self._one_step_in_flight():
+            while True:
+                with tracer.span("loop.next"):      # the loop's head
+                    if self.end_when(self.state):
+                        break
+                    self._elastic_step_boundary()
+                    data, labels, bs = feed.take()
+                    t0 = time.time()
+                    self._rng, sub = jax.random.split(self._rng)
+                    clr_val = self._current_clr()
+                    clr = jnp.asarray(clr_val, jnp.float32)
 
-            stepno = self.state["neval"]
-            if not cost_done:
-                cost_done = True
-                if costs.costs_enabled():
-                    with tracer.span("cost.analysis"):
-                        costs.emit_cost(
-                            "train.step", step, params, opt_state,
-                            model_state, data, labels, sub,
-                            jnp.asarray(stepno, jnp.int32), clr,
-                            kind=type(self).__name__, sharding="spec")
-            new_state, loss = self._run_step(
-                feed, stepno, f"train step {stepno} (spec, n={n})", data,
-                lambda data: step(
-                    params, opt_state, model_state, data, labels, sub,
-                    jnp.asarray(stepno, jnp.int32), clr),
-                n=n, sharding="spec")
-            dt = time.time() - t0
+                stepno = self.state["neval"]
+                if not cost_done:
+                    cost_done = True
+                    if costs.costs_enabled():
+                        with tracer.span("cost.analysis"):
+                            costs.emit_cost(
+                                "train.step", step, params, opt_state,
+                                model_state, data, labels, sub,
+                                jnp.asarray(stepno, jnp.int32), clr,
+                                kind=type(self).__name__, sharding="spec")
+                new_state, previous = self._run_step(
+                    feed, stepno, f"train step {stepno} (spec, n={n})", data,
+                    lambda data: step(
+                        params, opt_state, model_state, data, labels, sub,
+                        jnp.asarray(stepno, jnp.int32), clr),
+                    t0=t0, n=n, sharding="spec")
 
-            with tracer.span("loop.bookkeeping"):
-                params, opt_state, model_state = new_state   # as the flat
-                costs.sample_hbm(step=stepno)
-                if self.skip_nonfinite and math.isnan(loss):
-                    self._check_drop_budget(self._record_skipped_step())
-                self.metrics.add("computing time average", dt * 1e9)
-                self.metrics.set("loss", loss, unit="scalar")
-                count_this_epoch += bs
-                self.state["neval"] += 1
-                self.state["recordsProcessedThisEpoch"] = count_this_epoch
-                self.state["isLastBatchOfEpoch"] = \
-                    count_this_epoch >= ds_size
-                self._emit_step_record(stepno, loss, bs, dt, clr_val)
-                logger.info(
-                    "Epoch %d %d/%d loss %.6f throughput %.1f "
-                    "records/second", self.state["epoch"],
-                    count_this_epoch, ds_size, loss, bs / max(dt, 1e-9))
+                with tracer.span("loop.bookkeeping"):   # as the flat loop
+                    params, opt_state, model_state = new_state
+                    count_this_epoch = self._advance(
+                        bs, count_this_epoch, ds_size,
+                        partial(self._record_step, stepno, bs, clr_val,
+                                ds_size))
+                    if previous is not None:
+                        previous()
 
-                if count_this_epoch >= ds_size:
-                    self.state["epoch"] += 1
-                    count_this_epoch = 0
-                    self.state["recordsProcessedThisEpoch"] = 0
-
-                if self.sharded_checkpoint_trigger and \
-                        self.sharded_checkpoint_path and \
-                        self._elastic_should_write() and \
-                        self.sharded_checkpoint_trigger(self.state):
-                    from bigdl_tpu.utils import checkpoint as ckpt
-                    with tracer.span("checkpoint.sharded.save",
-                                     step=self.state["neval"]):
-                        ckpt.save_sharded(self.sharded_checkpoint_path,
-                                          _snapshot(params, opt_state,
-                                                    model_state),
-                                          step=self.state["neval"],
-                                          detach=step.donates_state)
-
-                if self.validation_trigger and \
-                        self.validation_trigger(self.state):
-                    # sharded params apply directly under jit — GSPMD
-                    # gathers on use, no host reassembly
-                    self.model.params = params
-                    self.model.state = model_state
-                    self.validate()
-                if self.checkpoint_trigger and self.checkpoint_path and \
-                        self.checkpoint_trigger(self.state):
-                    with tracer.span("get_model"):
-                        self.model.params = jax.tree_util.tree_map(
-                            _fetch_global, params)
+                    do_sharded, do_val, do_ckpt = self._due()
+                    if do_sharded:
+                        from bigdl_tpu.utils import checkpoint as ckpt
+                        with tracer.span("checkpoint.sharded.save",
+                                         step=self.state["neval"]):
+                            ckpt.save_sharded(self.sharded_checkpoint_path,
+                                              _snapshot(params, opt_state,
+                                                        model_state),
+                                              step=self.state["neval"],
+                                              detach=step.donates_state)
+                    if do_val:
+                        # sharded params apply directly under jit — GSPMD
+                        # gathers on use, no host reassembly
+                        self.model.params = params
                         self.model.state = model_state
-                    self._maybe_checkpoint(jax.tree_util.tree_map(
-                        _fetch_global, opt_state))
-                self.state["isLastBatchOfEpoch"] = False
-                FaultInjector.fire("train.step", step=self.state["neval"])
+                        self.validate()
+                    if do_ckpt:
+                        with tracer.span("get_model"):
+                            self.model.params = jax.tree_util.tree_map(
+                                _fetch_global, params)
+                            self.model.state = model_state
+                        self._save_checkpoint(jax.tree_util.tree_map(
+                            _fetch_global, opt_state))
+                    self.state["isLastBatchOfEpoch"] = False
+                    self._preempt()
 
         with tracer.span("get_model"):
             self.model.params = jax.tree_util.tree_map(_fetch_global,

@@ -20,8 +20,9 @@ import os
 import re
 import time
 import threading
+from contextlib import contextmanager
 from functools import partial
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +38,7 @@ from bigdl_tpu.optim.optim_method import SGD, Default, OptimMethod
 from bigdl_tpu.optim.trigger import Trigger
 from bigdl_tpu.optim.validation import ValidationMethod
 from bigdl_tpu.resilience.fault_injector import FaultInjector
-from bigdl_tpu.resilience.watchdog import Watchdog
+from bigdl_tpu.resilience.watchdog import Watchdog, WatchdogTimeout
 from bigdl_tpu.utils.file import File
 from bigdl_tpu.utils.table import T, Table
 
@@ -46,6 +47,10 @@ logger = logging.getLogger("bigdl_tpu.optim")
 # metric/ledger name for non-finite skipped steps (the reference's
 # dropped-gradient accounting, DistriOptimizer.scala:244-272)
 SKIPPED_STEPS = "skipped steps (non-finite)"
+# counter of the steps dispatched while the step before them was still in
+# flight: over ``neval`` the share of steps whose launch the host's turn
+# did not hold back
+STEPS_AHEAD = "steps dispatched ahead"
 
 
 def _default_step_timeout() -> Optional[float]:
@@ -69,6 +74,18 @@ def state_donation(platforms) -> bool:
     cache corrupt the CPU heap, and memory is not the constraint
     there)."""
     return not set(platforms) <= {"cpu"}
+
+
+class _Flight:
+    """A dispatched step whose loss the host has not read yet: the loss,
+    still on the device, and ``record(loss, dt)``, the step's bookkeeping,
+    which the loop sets once it has counted the step."""
+
+    __slots__ = ("stepno", "loss", "label", "t0", "record")
+
+    def __init__(self, stepno: int, loss, label: str, t0: float):
+        self.stepno, self.loss, self.label, self.t0 = stepno, loss, label, t0
+        self.record: Optional[Callable[[float, float], None]] = None
 
 
 class LocalOptimizer:
@@ -107,6 +124,9 @@ class LocalOptimizer:
         self.step_timeout = _default_step_timeout()
         self.auto_resume = False         # discover latest snapshot at start
         self._resume_path: Optional[str] = None   # explicit resume_from
+        # -- the loop's one step in flight (_run_step, _drain) --
+        self._flight: Optional[_Flight] = None
+        self._synced = 0.0               # host clock at the last sync's end
 
     # -- builder API (Optimizer.scala parity) -------------------------------
 
@@ -120,7 +140,7 @@ class LocalOptimizer:
 
     def set_state(self, state: Table):
         """Restore optimizer progress.  Accepts either a bare state Table
-        or a ``state.<neval>`` snapshot written by ``_maybe_checkpoint``
+        or a ``state.<neval>`` snapshot written by ``_save_checkpoint``
         (``{"state": ..., "opt_state": ...}``) — the snapshot form also
         restores the optim-method state (momentum buffers etc.) at the
         next ``optimize()``."""
@@ -382,16 +402,17 @@ class LocalOptimizer:
                     "(epoch %d, neval %d)", path, suffix or " (overwrite)",
                     self.state["epoch"], self.state["neval"])
 
-    def _record_skipped_step(self) -> int:
-        """Ledger a non-finite skipped step; returns the running count."""
+    def _record_skipped_step(self, stepno: int) -> int:
+        """Ledger step ``stepno`` as a non-finite skipped step; returns
+        the running count."""
         skipped = self.state.get("skippedSteps", 0) + 1
         self.state["skippedSteps"] = skipped
         self.metrics.incr(SKIPPED_STEPS)
-        run_ledger.emit("event", kind="step.skipped",
-                        step=self.state["neval"], total=skipped)
+        run_ledger.emit("event", kind="step.skipped", step=stepno,
+                        total=skipped)
         logger.warning(
             "step %d: non-finite loss/gradient — update skipped, weights "
-            "kept (%d skipped so far)", self.state["neval"], skipped)
+            "kept (%d skipped so far)", stepno, skipped)
         return skipped
 
     # -- observability (run ledger + summaries) ------------------------------
@@ -448,29 +469,32 @@ class LocalOptimizer:
         led.flush()
 
     def _emit_step_record(self, stepno: int, loss: float, records: int,
-                          dur_s: float, clr: float) -> None:
+                          dur_s: float, clr: float, state=None) -> None:
         # isfinite, not isnan: an INF loss (diverging, or guard off)
         # must also become null — a bare inf would make the strict-JSON
         # writer replace the whole step record
+        state = self.state if state is None else state
         finite = math.isfinite(loss)
-        run_ledger.emit("step", step=stepno, epoch=self.state["epoch"],
+        run_ledger.emit("step", step=stepno, epoch=state["epoch"],
                         loss=loss if finite else None, records=records,
                         dur_s=dur_s,
                         records_per_s=records / max(dur_s, 1e-9),
                         skipped=math.isnan(loss) and self.skip_nonfinite)
         ts = self.train_summary
         if ts is not None:
-            # called AFTER the loop updates neval/isLastBatchOfEpoch, so
-            # the triggers read the same post-step state the checkpoint/
-            # validation triggers do — one Trigger spec fires summaries
-            # and snapshots at the same steps.  ``clr`` is the rate the
-            # step ACTUALLY ran with (re-evaluating the schedule here,
-            # post-increment, would log the next step's rate).
+            # ``state`` holds the counters as they stood right after this
+            # step (neval, isLastBatchOfEpoch; before the epoch's
+            # rollover), so the triggers read the same post-step state the
+            # checkpoint/validation triggers do — one Trigger spec fires
+            # summaries and snapshots at the same steps — though the loop
+            # has counted the next step by the time this step's loss is
+            # read.  ``clr`` is the rate the step ACTUALLY ran with
+            # (re-evaluating the schedule here would log a later step's).
             for tag, val in (("Loss", loss),
                              ("Throughput", records / max(dur_s, 1e-9)),
                              ("LearningRate", clr)):
                 trig = ts.trigger_for(tag)
-                if (trig is None or trig(self.state)) and \
+                if (trig is None or trig(state)) and \
                         math.isfinite(val):
                     ts.add_scalar(tag, val, stepno)
 
@@ -485,40 +509,152 @@ class LocalOptimizer:
     # -- main loop -----------------------------------------------------------
 
     def _run_step(self, feed: BatchAhead, stepno: int, label: str, data,
-                  dispatch, donates_state: Optional[bool] = None, **attrs):
+                  dispatch, *, t0: float,
+                  donates_state: Optional[bool] = None, **attrs):
         """One step of any of the trainer loops, the next batch started
-        under it: ``dispatch(data)`` enqueues the step program and
-        returns ``(*new_state, loss)``; ``feed.start()`` then fetches and
-        puts batch N+1 while the device runs step N; the sync on the
-        loss comes last.  Returns ``(new_state, float(loss))``.
+        under it and the PREVIOUS step's loss read after both:
+        ``dispatch(data)`` enqueues step N's program and returns
+        ``(*new_state, loss)``; ``feed.start()`` then fetches and puts
+        batch N+1; only then does the host wait on step N-1's loss
+        (``train.sync``, which names the step it waits on and carries
+        ``ahead`` true: step N is already queued behind it).  Step N is
+        left in flight (``_flight``) for the next call, or for ``_drain``.
+        Returns ``(new_state, previous)``: step N's state, still on the
+        device, and ``previous()``, which records step N-1 (None for the
+        first step and after a drain).  ``t0`` is the host clock at step
+        N's loop head.
 
         The order matters: a put BEFORE the dispatch would delay the
         step by the time the host spends in it (the SPMD loops block on
-        the copy); after the sync nothing overlaps it.  The watchdog
-        guards the dispatch and the sync, each with the whole timeout,
-        and not the fetch between them: a slow decode is not a hung
-        step.  ``donates_state``, where the loop knows it, rides on the
-        ``train.dispatch`` span."""
-        watchdog = partial(Watchdog, self.step_timeout, label=label)
+        the copy); a sync before the next dispatch leaves the device idle
+        through the sync's return, the host's turn and the launch.  The
+        watchdog guards the dispatch and the sync, each with the whole
+        timeout, and not the fetch between them: a slow decode is not a
+        hung step.  ``donates_state``, where the loop knows it, rides on
+        the ``train.dispatch`` span."""
         with tracer.span("train.step", step=stepno, **attrs):
-            with watchdog():
+            with Watchdog(self.step_timeout, label=label):
                 if FaultInjector.should("grad.nan", stepno):
                     # inside the span: the poison (first use compiles
                     # full_like) is step work, not an inter-span hole in
                     # the coverage accounting
                     data = jnp.full_like(data, jnp.nan)  # NaN fwd -> grads
                 # the call returns once the program is enqueued; the
-                # wait for it is the sync's
+                # wait for it is the next step's sync, or the drain's
                 with tracer.span("train.dispatch", **(
                         {} if donates_state is None
                         else {"donates_state": donates_state})):
                     *new_state, loss = dispatch(data)
+            prev, self._flight = self._flight, _Flight(stepno, loss, label,
+                                                       t0)
             feed.start()
-            # blocks: the whole fused step (compute + collectives) — the
-            # hang point the watchdog guards (a wedged host stalls every
-            # other host's collective exactly here)
-            with watchdog(), tracer.span("train.sync"):
-                return new_state, float(loss)
+            if prev is None:
+                return new_state, None
+            self.metrics.incr(STEPS_AHEAD)
+            return new_state, partial(prev.record,
+                                      *self._sync(prev, ahead=True))
+
+    def _sync(self, flight: _Flight, ahead: bool):
+        """Wait for ``flight``'s loss: the whole fused step (compute +
+        collectives) — the hang point the watchdog guards (a wedged host
+        stalls every other host's collective exactly here).  Returns
+        ``(loss, dt)``, ``dt`` the host time the step added: from the
+        later of its loop head and the previous sync's end to this
+        sync's end."""
+        with Watchdog(self.step_timeout, label=flight.label), \
+                tracer.span("train.sync", step=flight.stepno, ahead=ahead):
+            loss = float(flight.loss)
+        now = time.time()
+        dt = now - max(flight.t0, self._synced)
+        self._synced = now
+        return loss, dt
+
+    def _drain(self) -> None:
+        """Read and record the step in flight, if any (``train.sync`` with
+        ``ahead`` false: nothing is queued behind it).  The loops drain
+        before whatever reads or replaces the training state — a
+        validation, a snapshot, the injected preemption — and when they
+        leave (``_one_step_in_flight``), so each of those sees every
+        dispatched step recorded and the state of exactly ``neval``
+        steps: the step that would run next was not dispatched."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            loss, dt = self._sync(flight, ahead=False)
+            if flight.record is not None:
+                flight.record(loss, dt)
+
+    @contextmanager
+    def _one_step_in_flight(self):
+        """The span of a trainer loop, which keeps at most one step in
+        flight: drained when the loop ends, and when it leaves by an
+        exception (an input error, an elastic world change, a failed
+        record), which is then raised as it was — a failure to record the
+        drained step is only logged.  A watchdog timeout leaves the step:
+        a wedged device cannot be drained."""
+        self._flight, self._synced = None, 0.0
+        try:
+            yield
+        except WatchdogTimeout:
+            self._flight = None
+            raise
+        except BaseException:
+            try:
+                with tracer.span("loop.bookkeeping"):
+                    self._drain()
+            except Exception:
+                logger.warning("the step in flight could not be recorded "
+                               "as the loop unwound", exc_info=True)
+            raise
+        with tracer.span("loop.bookkeeping"):
+            self._drain()
+
+    def _advance(self, records: int, count: int, ds_size: int,
+                 record) -> int:
+        """Count the step just dispatched (the one in flight) at once, so
+        the triggers asked next read the steps dispatched: ``neval``, the
+        epoch's records and ``isLastBatchOfEpoch``, then the epoch's
+        rollover.  The step's ``record(state, loss, dt)`` is bound to the
+        counters as they stand before the rollover.  Returns the epoch's
+        record count."""
+        count += records
+        self.state["neval"] += 1
+        # persisted so a mid-epoch state snapshot resumes the epoch where
+        # it left off instead of replaying it from zero
+        self.state["recordsProcessedThisEpoch"] = count
+        self.state["isLastBatchOfEpoch"] = count >= ds_size
+        self._flight.record = partial(record, dict(self.state))
+        if count >= ds_size:
+            self.state["epoch"] += 1
+            count = 0
+            self.state["recordsProcessedThisEpoch"] = 0
+        return count
+
+    def _record_step(self, stepno: int, records: int, clr: float,
+                     ds_size: int, state, loss: float, dt: float) -> None:
+        """Step ``stepno``'s bookkeeping once its loss is read: metrics,
+        the skip ledger, the step record and summary, the log line.
+        ``state``: the counters right after the step (``_advance``)."""
+        self.metrics.add("computing time average", dt * 1e9)
+        # HBM high-watermark sample (mem.hbm; no-op on backends
+        # without memory_stats — one memoized check)
+        costs.sample_hbm(step=stepno)
+        if self.skip_nonfinite and math.isnan(loss):
+            self._record_skipped_step(stepno)
+        self._emit_step_record(stepno, loss, records, dt, clr, state)
+        logger.info(
+            "Epoch %d %d/%d loss %.6f throughput %.1f records/second",
+            state["epoch"], state["recordsProcessedThisEpoch"], ds_size,
+            loss, records / max(dt, 1e-9))
+
+    def _preempt(self) -> None:
+        """The injected preemption (``train.step``), AFTER the snapshot
+        logic: the crash a relaunch with auto_resume must recover from.
+        The step in flight is drained first, as a killed run would have
+        recorded it."""
+        neval = self.state["neval"]
+        if FaultInjector.armed("train.step", neval):
+            self._drain()
+        FaultInjector.fire("train.step", step=neval)
 
     def optimize(self):
         self._run_start(donates_state=self._donates_state())
@@ -559,88 +695,81 @@ class LocalOptimizer:
         wall_start = time.time()
 
         cost_done = False          # one cost.analysis per optimize()
-        while True:
-            # the loop's head, from the bookkeeping's end to the step:
-            # the end trigger, the batch taken, the key and the rate
-            with tracer.span("loop.next"):
-                if self.end_when(self.state):
-                    break
-                data, labels, bs = feed.take()
-                self._rng, sub = jax.random.split(self._rng)
+        with self._one_step_in_flight():
+            while True:
+                # the loop's head, from the bookkeeping's end to the step:
+                # the end trigger, the batch taken, the key and the rate.
+                # The step before is still in flight: the counters count
+                # it, its loss is read under the next dispatch
+                with tracer.span("loop.next"):
+                    if self.end_when(self.state):
+                        break
+                    data, labels, bs = feed.take()
+                    self._rng, sub = jax.random.split(self._rng)
 
-                stepno = self.state["neval"]
-                t0 = time.time()
-                clr_val = self._current_clr()
-                clr = jnp.asarray(clr_val, jnp.float32)
-            if not cost_done:
-                cost_done = True
-                if costs.costs_enabled():
-                    # price the train-step executable once (FLOPs/bytes
-                    # via XLA's cost model).  One extra AOT compile,
-                    # under its own top-level span so the report's
-                    # coverage figure stays honest about the time.
-                    with tracer.span("cost.analysis"):
-                        costs.emit_cost(
-                            "train.step", step, params, opt_state,
-                            model_state, data, labels, sub,
-                            jnp.asarray(stepno, jnp.int32), clr,
-                            kind=type(self).__name__)
+                    stepno = self.state["neval"]
+                    t0 = time.time()
+                    clr_val = self._current_clr()
+                    clr = jnp.asarray(clr_val, jnp.float32)
+                if not cost_done:
+                    cost_done = True
+                    if costs.costs_enabled():
+                        # price the train-step executable once (FLOPs/
+                        # bytes via XLA's cost model).  One extra AOT
+                        # compile, under its own top-level span so the
+                        # report's coverage figure stays honest about the
+                        # time.
+                        with tracer.span("cost.analysis"):
+                            costs.emit_cost(
+                                "train.step", step, params, opt_state,
+                                model_state, data, labels, sub,
+                                jnp.asarray(stepno, jnp.int32), clr,
+                                kind=type(self).__name__)
 
-            def dispatch(data):
-                nonlocal params, opt_state, model_state
-                params, opt_state, model_state, loss = step(
-                    params, opt_state, model_state, data, labels, sub,
-                    jnp.asarray(stepno, jnp.int32), clr)
-                # the call may have consumed the state it was given: from
-                # here on the facade names its result, also when the loop
-                # leaves by an exception before the bookkeeping
-                self.model.params, self.model.state = params, model_state
-                return params, opt_state, model_state, loss
+                def dispatch(data):
+                    nonlocal params, opt_state, model_state
+                    params, opt_state, model_state, loss = step(
+                        params, opt_state, model_state, data, labels, sub,
+                        jnp.asarray(stepno, jnp.int32), clr)
+                    # the call may have consumed the state it was given:
+                    # from here on the facade names its result, also when
+                    # the loop leaves by an exception before the
+                    # bookkeeping
+                    self.model.params, self.model.state = params, model_state
+                    return params, opt_state, model_state, loss
 
-            _, loss = self._run_step(
-                feed, stepno, f"train step {stepno}", data, dispatch,
-                donates_state=step.donates_state)
-            dt = time.time() - t0
-            # everything after the step itself — metrics/ledger/summary
-            # bookkeeping, logging, the epoch's counters, validation and
-            # checkpoint triggers — is span-attributed too, so the
-            # run-report breakdown accounts for the loop's host-side
-            # time, not just its device time
-            with tracer.span("loop.bookkeeping"):
-                self.metrics.add("computing time average", dt * 1e9)
-                # HBM high-watermark sample (mem.hbm; no-op on backends
-                # without memory_stats — one memoized check)
-                costs.sample_hbm(step=stepno)
-                if self.skip_nonfinite and math.isnan(loss):
-                    self._record_skipped_step()
-
-                count_this_epoch += bs
-                self.state["neval"] += 1
-                # persisted so a mid-epoch state snapshot resumes the
-                # epoch where it left off instead of replaying it from
-                # zero
-                self.state["recordsProcessedThisEpoch"] = count_this_epoch
-                self.state["isLastBatchOfEpoch"] = \
-                    count_this_epoch >= ds_size
-                # post-update, pre-rollover: summary triggers see the
-                # completed-step counters (incl. isLastBatchOfEpoch)
-                self._emit_step_record(stepno, loss, bs, dt, clr_val)
-                logger.info(
-                    "Epoch %d %d/%d loss %.6f throughput %.1f "
-                    "records/second", self.state["epoch"],
-                    count_this_epoch, ds_size, loss, bs / max(dt, 1e-9))
-
-                if count_this_epoch >= ds_size:
-                    self.state["epoch"] += 1
-                    count_this_epoch = 0
-                    self.state["recordsProcessedThisEpoch"] = 0
-
-                self._maybe_validate()
-                self._maybe_checkpoint(opt_state)
-                self.state["isLastBatchOfEpoch"] = False
-                # injected preemption AFTER the snapshot logic: the
-                # crash a relaunch with auto_resume must recover from
-                FaultInjector.fire("train.step", step=self.state["neval"])
+                _, previous = self._run_step(
+                    feed, stepno, f"train step {stepno}", data, dispatch,
+                    t0=t0, donates_state=step.donates_state)
+                # everything after the step itself — this step's counters,
+                # the previous step's record (metrics/ledger/summary,
+                # logging), validation and checkpoint triggers — is
+                # span-attributed too, so the run-report breakdown
+                # accounts for the loop's host-side time, not just its
+                # device time
+                with tracer.span("loop.bookkeeping"):
+                    count_this_epoch = self._advance(
+                        bs, count_this_epoch, ds_size,
+                        partial(self._record_step, stepno, bs, clr_val,
+                                ds_size))
+                    if previous is not None:
+                        previous()
+                    # the triggers read the counters only, so they are
+                    # asked before the next dispatch: a snapshot or a
+                    # validation drains the step first and sees its state
+                    do_val = bool(self.validation_trigger and
+                                  self.validation_trigger(self.state))
+                    do_ckpt = bool(self.checkpoint_trigger and
+                                   self.checkpoint_path and
+                                   self.checkpoint_trigger(self.state))
+                    if do_val or do_ckpt:
+                        self._drain()
+                    if do_val:
+                        self.validate()
+                    if do_ckpt:
+                        self._save_checkpoint(opt_state)
+                    self.state["isLastBatchOfEpoch"] = False
+                    self._preempt()
 
         wall = time.time() - wall_start
         logger.info("Training finished in %.1fs (%d iterations)",
@@ -650,12 +779,6 @@ class LocalOptimizer:
         return self.model
 
     # -- validation / checkpoint ---------------------------------------------
-
-    def _maybe_validate(self):
-        if not self.validation_trigger or \
-                not self.validation_trigger(self.state):
-            return None
-        return self.validate()
 
     def validate(self):
         with tracer.span("validate", step=self.state.get("neval", 0)):
@@ -672,10 +795,9 @@ class LocalOptimizer:
         self._tee_val_scalars(results)
         return results
 
-    def _maybe_checkpoint(self, opt_state):
-        if not self.checkpoint_trigger or not self.checkpoint_path or \
-                not self.checkpoint_trigger(self.state):
-            return
+    def _save_checkpoint(self, opt_state):
+        """The File snapshot pair of ``neval`` steps (the caller asked the
+        checkpoint trigger, and drained)."""
         neval = self.state["neval"]
         suffix = "" if self.overwrite_checkpoint else f".{neval}"
         with tracer.span("checkpoint.save", step=neval):

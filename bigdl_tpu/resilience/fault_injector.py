@@ -200,6 +200,18 @@ class FaultInjector:
                                    else ""))
 
     @classmethod
+    def armed(cls, site: str, step: int) -> bool:
+        """Whether ``fire(site, step)`` would raise, without consuming the
+        fault: a loop that must settle its state before the fault strikes
+        asks first.  (A check with a step never moves a fault's count of
+        step-less checks.)"""
+        inj = cls.active()
+        if inj is None:
+            return False
+        with cls._lock:
+            return any(f.matches(site, step) for f in inj.faults)
+
+    @classmethod
     def should(cls, site: str, step: Optional[int] = None) -> bool:
         """Non-raising query form (e.g. ``grad.nan``: the caller poisons
         data instead of raising)."""

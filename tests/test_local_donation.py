@@ -76,7 +76,8 @@ def _batches():
 
 class _Losses:
     """A ``TrainSummary`` stand-in that records the loss of every step and
-    raises at step ``fail_at`` (after that step's dispatch)."""
+    raises at step ``fail_at`` (while recording it, which the loop does
+    once the NEXT step is dispatched)."""
 
     def __init__(self, fail_at=None):
         self.losses, self.fail_at = [], fail_at
@@ -91,7 +92,8 @@ class _Losses:
             raise RuntimeError(f"summary failed at step {step}")
 
 
-def _train(model, steps, summary=None, checkpoint=None, resume=None):
+def _train(model, steps, summary=None, checkpoint=None, resume=None,
+           watch=None):
     opt = LocalOptimizer(model, nn.ClassNLLCriterion(),
                          DataSet.array(_batches(), seed=2),
                          Trigger.max_iteration(steps))
@@ -103,6 +105,8 @@ def _train(model, steps, summary=None, checkpoint=None, resume=None):
         opt.set_checkpoint(path, lambda state: state["neval"] == at)
     if resume is not None:
         opt.resume_from(resume)
+    if watch is not None:
+        watch(opt)
     opt.optimize()
     return opt
 
@@ -123,6 +127,7 @@ def _child_main(tmp):
     tests assert, as one JSON line on stdout."""
     import bigdl_tpu.optim.local_optimizer as lo
     from bigdl_tpu.observability import ledger as run_ledger
+    from tests.checkers import record_steps
     from bigdl_tpu.observability.report import load_ledger
     from bigdl_tpu.resilience.fault_injector import FaultInjector
 
@@ -150,8 +155,9 @@ def _child_main(tmp):
     model = _lenet()
     before = model.params
     init_w = _leaves(before)
-    got = _Losses()
-    _train(model, STEPS, got)
+    got, seen = _Losses(), []
+    _train(model, STEPS, got, watch=lambda opt: seen.extend(
+        [record_steps(opt)]))
     run_ledger.flush()
     run_ledger.set_run_dir(None)
     records, _ = load_ledger(run_dir)
@@ -168,6 +174,30 @@ def _child_main(tmp):
     facts["caller_unchanged"] = facts["caller_not_deleted"] and \
         _same(_leaves(before), init_w)
 
+    # the plain donating step by hand over the batches the loop handed its
+    # step (the same keys, the same rate): the loop that reads each loss
+    # one step late gives its losses and weights, bit for bit
+    plain = _lenet()
+    hand = LocalOptimizer(plain, nn.ClassNLLCriterion(), None)
+    hand.set_optim_method(SGD(learning_rate=0.05, momentum=0.9))
+    step = hand._build_step()
+    state = jax.device_put((plain.params,
+                            hand.optim_method.init_state(plain.params),
+                            plain.state), may_alias=False)
+    rng = jax.random.PRNGKey(7)
+    clr = jnp.asarray(hand._current_clr(), jnp.float32)
+    labels = jnp.asarray(_batches()[0].labels)
+    plain_losses = []
+    for stepno, data in seen[0]:
+        rng, sub = jax.random.split(rng)
+        *state, loss = step(*state, data, labels, sub,
+                            jnp.asarray(stepno, jnp.int32), clr)
+        plain_losses.append(float(loss))
+    facts["plain_steps"] = [n for n, _ in seen[0]]
+    facts["plain_losses_equal"] = plain_losses == got.losses
+    facts["plain_weights_equal"] = _same(_leaves(state[0]),
+                                         _leaves(model.params))
+
     # a File snapshot written mid-run resumes to the same final weights
     snap = os.path.join(tmp, "snap")
     _train(_lenet(), STEPS, checkpoint=(snap, 2))
@@ -175,7 +205,8 @@ def _child_main(tmp):
     _train(resumed, STEPS, resume=snap)
     facts["resume_equal"] = _same(_leaves(resumed.params), ref_w)
 
-    # the guard: a poisoned last step is skipped, the weights kept
+    # the guard: a poisoned last step is skipped, the weights kept (and
+    # three steps' weights serve the exception case below)
     three = _lenet()
     _train(three, STEPS - 1)
     FaultInjector.install(FaultInjector().add("grad.nan", step=STEPS - 1))
@@ -188,19 +219,21 @@ def _child_main(tmp):
     facts["nan_weights_kept"] = _same(_leaves(poisoned.params),
                                       _leaves(three.params))
 
-    # an exception after a dispatch: model.params is the step's result
-    failing = _lenet()
+    # an exception after a dispatch: recording step 1 fails while step 2
+    # runs (its dispatch consumed step 1's state); step 2 is drained and
+    # recorded, and model.params is its result
+    failing, failing_losses = _lenet(), _Losses(fail_at=1)
     try:
-        _train(failing, STEPS, _Losses(fail_at=1))
+        _train(failing, STEPS, failing_losses)
         facts["raised"] = False
     except RuntimeError:
         facts["raised"] = True
     leaves = jax.tree_util.tree_leaves(failing.params)
     facts["after_raise_readable"] = not any(l.is_deleted() for l in leaves)
-    two = _lenet()
-    _train(two, 2)
+    facts["after_raise_recorded"] = \
+        failing_losses.losses == ref.losses[:3]
     facts["after_raise_is_newest"] = facts["after_raise_readable"] and \
-        _same(_leaves(failing.params), _leaves(two.params))
+        _same(_leaves(failing.params), _leaves(three.params))
     print(json.dumps(facts))
 
 
@@ -230,6 +263,12 @@ def test_donated_run_is_bit_equal_to_the_undonated(donated):
     assert donated["weights_equal"]
 
 
+def test_the_lagged_donated_run_is_the_plain_steps(donated):
+    assert donated["plain_steps"] == list(range(STEPS))
+    assert donated["plain_losses_equal"]
+    assert donated["plain_weights_equal"]
+
+
 def test_the_callers_tree_survives(donated):
     assert donated["caller_not_deleted"]
     assert donated["caller_unchanged"]
@@ -252,4 +291,5 @@ def test_a_poisoned_step_is_skipped_with_the_weights_kept(donated):
 def test_an_exception_after_a_dispatch_leaves_params_readable(donated):
     assert donated["raised"]
     assert donated["after_raise_readable"]
+    assert donated["after_raise_recorded"]
     assert donated["after_raise_is_newest"]

@@ -317,22 +317,31 @@ def test_dispatch_and_sync_are_children_of_train_step(tmp_path, kind):
     records, bad = load_ledger(str(tmp_path))
     assert bad == 0
     spans = [r for r in records if r.get("type") == "span"]
-    steps = {r["span"]: r for r in spans if r["name"] == "train.step"}
-    assert len(steps) == 3
-    for child in ("train.dispatch", "train.sync"):
-        kids = [r for r in spans if r["name"] == child]
-        assert len(kids) == 3
-        assert {r["parent"] for r in kids} == set(steps)
-        for r in kids:                      # inside its step, in time too
-            p = steps[r["parent"]]
-            assert p["mono"] <= r["mono"]
-            assert r["mono"] + r["dur_s"] <= p["mono"] + p["dur_s"] + 1e-3
-    # the input is one batch ahead (``optim/batch_ahead.py``): step 0's
-    # batch is put in the open, then one more under every step, between
-    # its dispatch and its sync; the put under the last step feeds none,
-    # so three steps make FOUR puts
     by_start = lambda name: sorted(
         (r for r in spans if r["name"] == name), key=lambda r: r["mono"])
+    end = lambda r: r["mono"] + r["dur_s"]
+    steps = by_start("train.step")
+    assert [r["attrs"]["step"] for r in steps] == [0, 1, 2]
+    # the loss is read one step late: step N's span holds its dispatch and
+    # then the sync of step N-1, already queued behind step N (``ahead``);
+    # the last step is drained after the loop's end, nothing behind it
+    dispatches, syncs = by_start("train.dispatch"), by_start("train.sync")
+    assert [r["parent"] for r in dispatches] == [r["span"] for r in steps]
+    assert [(r["attrs"]["step"], r["attrs"]["ahead"]) for r in syncs] == \
+        [(0, True), (1, True), (2, False)]
+    assert [r.get("parent") for r in syncs[:2]] == \
+        [r["span"] for r in steps[1:]]
+    drain = syncs[2]
+    assert drain.get("parent") not in {r["span"] for r in steps}
+    assert drain["mono"] >= end(steps[2])
+    for r in dispatches + syncs[:2]:      # inside its step, in time too
+        p = next(s for s in steps if s["span"] == r["parent"])
+        assert p["mono"] <= r["mono"]
+        assert end(r) <= end(p) + 1e-3
+    # the input is one batch ahead (``optim/batch_ahead.py``): step 0's
+    # batch is put in the open, then one more under every step, after its
+    # dispatch and before the sync it holds; the put under the last step
+    # feeds none, so three steps make FOUR puts
     h2d, fetches = by_start("h2d"), by_start("data.next")
     assert len(h2d) == len(fetches) == 4
     assert all(r["attrs"]["bytes"] == 8 * 4 * 4 + 8 * 4 for r in h2d)
@@ -341,13 +350,12 @@ def test_dispatch_and_sync_are_children_of_train_step(tmp_path, kind):
     heads = {r["span"] for r in spans if r["name"] == "loop.next"}
     assert h2d[0]["parent"] == fetches[0]["parent"] and \
         h2d[0]["parent"] in heads
-    end = lambda r: r["mono"] + r["dur_s"]
-    for n, (dispatch, sync) in enumerate(zip(by_start("train.dispatch"),
-                                             by_start("train.sync"))):
+    for n, dispatch in enumerate(dispatches):
         fetch, put = fetches[n + 1], h2d[n + 1]
         assert fetch["parent"] == put["parent"] == dispatch["parent"]
         assert end(dispatch) <= fetch["mono"] <= end(fetch) <= put["mono"]
-        assert end(put) <= sync["mono"] + 1e-3
+        if n:                   # the sync step n holds comes after the put
+            assert end(put) <= syncs[n - 1]["mono"] + 1e-3
     assert [r["type"] for r in records].count("clock") == 1
 
 
