@@ -14,13 +14,19 @@ model's serving state follows from the mixers it holds.
   RMS norm over each head's query and key channels is ``qk_norm``, which a
   pattern whose state-space layers carry the order switches off: its one
   kind of attention layer, ``"full"``, then has NO rope and NO head norm),
-  and ``"mamba2"`` (:class:`~bigdl_tpu.nn.Mamba2Mixer`: the Mamba-2
+  ``"mamba2"`` (:class:`~bigdl_tpu.nn.Mamba2Mixer`: the Mamba-2
   state-space recurrence, a float32 state ``(H, P, N)`` and a convolution
-  tail per sequence);
+  tail per sequence), and ``"dsa"``
+  (:class:`~bigdl_tpu.nn.SparseGroupedQueryAttention`: roped, head-normed
+  GQA whose every query reads only the ``topk`` keys a lightning indexer
+  picks, pages of K, V and the indexer's keys);
 * feed-forward parts: ``"dense"`` (:class:`~bigdl_tpu.nn.GatedMLP`),
-  ``"experts"`` (sigmoid group-limited routing over ALL the layer's
-  experts, the grouped product over the ``experts_held`` this chip holds
-  from ``expert_offset`` on, plus a shared expert added once) and
+  ``"experts"`` (``router`` ``"sigmoid"``: group-limited routing over ALL
+  the layer's experts, or ``"softmax"``: the top ``experts_per_token`` of
+  a float32 softmax over all of them, gates renormalised over those; the
+  grouped product over the ``experts_held`` this chip holds from
+  ``expert_offset`` on, plus a shared expert added once where
+  ``shared_expert``) and
   ``"latent_experts"`` (the same routing on the hidden state, but the
   routed experts work in a LATENT of ``latent_size``: one projection
   down in front of them and one back up behind their gated sum, both
@@ -53,11 +59,13 @@ from bigdl_tpu.core.module import Module, child_rng
 from bigdl_tpu.models.transformer import _embed_rows
 from bigdl_tpu.nn.attention import _proj
 from bigdl_tpu.parallel.expert import (EXPERT_FORMS, held_experts_apply,
-                                       sigmoid_group_route, streams_hit)
+                                       sigmoid_group_route, streams_hit,
+                                       topk_route)
 
 _F32 = jnp.float32
-MIXERS = ("kda", "mla", "swa", "full", "mamba2")
-PAGED = ("mla", "full")     # mixers whose cache is pages; the others' a slot's
+MIXERS = ("kda", "mla", "swa", "full", "mamba2", "dsa")
+PAGED = ("mla", "full", "dsa")  # mixers whose cache is pages; others' a slot's
+ROUTERS = ("sigmoid", "softmax")
 ROUTER_GAIN = 4.0           # standard deviation of a fresh router's logits
 EXPERT_GAIN = 0.1           # a routed expert's output, of unit gain
 FFNS = ("dense", "experts", "latent_experts")
@@ -95,7 +103,10 @@ class HybridLM(Module):
                  ssm_state: int = 128, ssm_groups: int = 8,
                  ssm_chunk: int = 128, latent_size: Optional[int] = None,
                  shared_dim: Optional[int] = None,
-                 expert_act: str = "swiglu"):
+                 expert_act: str = "swiglu", router: str = "sigmoid",
+                 shared_expert: bool = True, index_heads: int = 16,
+                 index_dim: int = 64, index_rope_dim: int = 32,
+                 topk: int = 2048, index_block: int = 512):
         super().__init__()
         layers = [tuple(l) for l in layers]
         if len(layers) != num_layers:
@@ -110,6 +121,8 @@ class HybridLM(Module):
                                  f"one of the two may be None")
         if expert_act not in EXPERT_FORMS:
             raise ValueError(f"expert_act {expert_act!r}: {EXPERT_FORMS}")
+        if router not in ROUTERS:
+            raise ValueError(f"router {router!r}: {ROUTERS}")
         if not latent_size and any(f == "latent_experts" for _, f in layers):
             raise ValueError("a pattern with 'latent_experts' layers states "
                              "its latent_size")
@@ -132,6 +145,15 @@ class HybridLM(Module):
         self.latent_size = latent_size
         self.shared_dim = shared_dim or expert_dim
         self.expert_act = expert_act
+        self.router = router
+        self.shared_expert = bool(shared_expert)
+        if any(m == "dsa" for m, _ in layers):
+            # what a decode step's sparse layers read: the keys each row
+            # scored and the keys it then attended over
+            self.decode_counters = dict(HybridLM.decode_counters,
+                                        keys_indexed="sum",
+                                        keys_selected="sum")
+        self.topk = int(topk)
         if not 0 <= self.expert_offset \
                 <= num_experts - self.experts_held:
             raise ValueError(
@@ -158,6 +180,11 @@ class HybridLM(Module):
                 return nn.LatentAttention(embed_dim, num_heads, latent_dim,
                                           rope_dim, nope_dim, v_dim,
                                           rope_theta, norm_eps)
+            if kind == "dsa":
+                return nn.SparseGroupedQueryAttention(
+                    embed_dim, num_heads, num_kv_heads or num_heads,
+                    head_dim, rope_theta, norm_eps, index_heads, index_dim,
+                    index_rope_dim, topk, index_block)
             return nn.GroupedQueryAttention(
                 embed_dim, num_heads, num_kv_heads or num_heads, head_dim,
                 window if kind == "swa" else None, kind == "swa",
@@ -191,19 +218,21 @@ class HybridLM(Module):
         # exchanged for its runner-up (which a hidden state rounded to
         # bfloat16 does to a token in ten a layer, in any implementation)
         # moves the logits less than the rounding itself does.
-        return {
+        out = {
             "router": jax.random.normal(kr, (self.num_experts, e))
             * self.router_gain * e ** -0.5,
-            "bias": jnp.zeros((self.num_experts,), _F32),
             # (in, out) per expert, as the grouped product reads them;
             # gate and up side by side
             "experts": {
                 "w_gate_up": jax.random.normal(kg, (g, e, 2 * f))
                 * e ** -0.5,
                 "w_down": jax.random.normal(kd, (g, f, e))
-                * self.expert_gain * f ** -0.5},
-            "shared": self.shared.init_params(ks),
-        }
+                * self.expert_gain * f ** -0.5}}
+        if self.router == "sigmoid":
+            out["bias"] = jnp.zeros((self.num_experts,), _F32)
+        if self.shared_expert:
+            out["shared"] = self.shared.init_params(ks)
+        return out
 
     def _init_shared(self, rng):
         if self.expert_act == "swiglu":
@@ -315,10 +344,13 @@ class HybridLM(Module):
 
     def _route(self, p, x):
         """(ids, gates) (T, k) of ``x`` (T, E) over ALL the layer's
-        experts: router product, sigmoid and gates in float32."""
+        experts: router product, scores and gates in float32."""
         with jax.default_matmul_precision("highest"):
-            scores = jax.nn.sigmoid(
-                x.astype(_F32) @ p["router"].astype(_F32).T)
+            logits = x.astype(_F32) @ p["router"].astype(_F32).T
+        if self.router == "softmax":
+            ids, gates = topk_route(logits, self.experts_per_token)
+            return ids.astype(jnp.int32), gates * self.routed_scale
+        scores = jax.nn.sigmoid(logits)
         return sigmoid_group_route(
             scores, p["bias"].astype(_F32), self.experts_per_token,
             self.n_group, self.topk_group, self.routed_scale)
@@ -332,8 +364,9 @@ class HybridLM(Module):
                 x, ids, gates, valid, p["experts"]["w_gate_up"],
                 p["experts"]["w_down"], self.expert_offset,
                 n_experts=self.num_experts)
-        with jax.named_scope("shared"):
-            y = y + self.shared.apply(p["shared"], {}, x)[0]
+        if self.shared_expert:
+            with jax.named_scope("shared"):
+                y = y + self.shared.apply(p["shared"], {}, x)[0]
         return y, counters
 
     def _shared(self, p, x):
@@ -376,6 +409,16 @@ class HybridLM(Module):
         zero = jnp.zeros((), jnp.int32)
         counts = {"expert_pairs": zero, "experts_hit": zero,
                   "expert_pairs_max": zero}
+        sparse = sum(m == "dsa" for m, _ in self.layers)
+        if sparse:
+            # a token at position p scores the p + 1 keys up to it and
+            # attends over min(p + 1, topk) of them, in every sparse layer
+            seen = (jnp.asarray(pos, jnp.int32)[:, None]
+                    + jnp.arange(s, dtype=jnp.int32) + 1).reshape(-1)
+            counts.update(
+                keys_indexed=sparse * jnp.sum(jnp.where(valid, seen, 0)),
+                keys_selected=sparse * jnp.sum(jnp.where(
+                    valid, jnp.minimum(seen, self.topk), 0)))
         new_pages, new_slots = list(cache["pages"]), list(cache["slots"])
         for i, ((kind, ffn), mixer) in enumerate(zip(self.layers,
                                                      self.mixers)):
@@ -413,11 +456,11 @@ class HybridLM(Module):
                                 else self._experts)(
                             p["ffn"], h.reshape(b * s, -1), valid)
                         x = x + y.reshape(b, s, -1)
-                    counts = {
-                        "expert_pairs": counts["expert_pairs"] + c["pairs"],
-                        "experts_hit": counts["experts_hit"] + c["hit"],
-                        "expert_pairs_max": jnp.maximum(
-                            counts["expert_pairs_max"], c["max"])}
+                    counts.update(
+                        expert_pairs=counts["expert_pairs"] + c["pairs"],
+                        experts_hit=counts["experts_hit"] + c["hit"],
+                        expert_pairs_max=jnp.maximum(
+                            counts["expert_pairs_max"], c["max"]))
         with jax.named_scope("logits"):
             if lengths is not None:
                 # a prefill wants the logits after its last real token
@@ -444,7 +487,8 @@ class HybridLM(Module):
         contract).  Returns (log-probs, cache', counters): the counters
         are ``decode_counters``'s keys, int32 scalars, the expert layers'
         ``expert_pairs`` and ``experts_hit`` summed and
-        ``expert_pairs_max`` their largest."""
+        ``expert_pairs_max`` their largest; with ``"dsa"`` layers also
+        ``keys_indexed`` and ``keys_selected`` over the valid tokens."""
         del state
         ids = jnp.asarray(tokens, jnp.int32) - 1
         return self._forward(params, ids, cache, pages, pos, active, slots,

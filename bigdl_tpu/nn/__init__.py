@@ -8,7 +8,8 @@ reference maps 1:1 onto this package.
 from bigdl_tpu.core.module import (Container, Criterion, Module,
                                    flatten_params, unflatten_params)
 from bigdl_tpu.nn.attention import (GroupedQueryAttention, LatentAttention,
-                                    MultiHeadAttention)
+                                    MultiHeadAttention,
+                                    SparseGroupedQueryAttention)
 from bigdl_tpu.nn.linear_attention import DeltaAttention
 from bigdl_tpu.nn.state_space import Mamba2Mixer
 from bigdl_tpu.parallel.expert import MixtureOfExperts

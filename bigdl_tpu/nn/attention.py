@@ -874,3 +874,141 @@ class GroupedQueryAttention(Module):
                             jnp.broadcast_to(jnp.arange(s), (b, s)))
         return self._out(params, self._own_tokens(q, k, v)
                          .astype(input.dtype)), state
+
+
+class SparseGroupedQueryAttention(GroupedQueryAttention):
+    """Grouped-query attention whose every query reads only the ``topk``
+    keys a LIGHTNING INDEXER picks (DeepSeek Sparse Attention, DeepSeek-V3.2
+    arXiv:2512.02556 section 2.1).  Queries, keys and values are
+    :class:`GroupedQueryAttention`'s (head norm, rope by half-split pairs,
+    no window).  Beside them, per token ``x`` (the normed input): ``Hi``
+    indexer query heads ``qI_j = rope(WqI_j x)`` of ``Di`` channels, ONE
+    indexer key ``kI = rope(LayerNorm(WkI x))`` shared by them, and the
+    heads' weights ``wI = WwI x`` times ``Hi^-1/2 Di^-1/2``; the rope turns
+    the first ``index_rope_dim`` channels of each (half-split pairs), the
+    rest pass.  Query ``t`` scores key ``s <= t`` by ``I[t, s] = sum_j
+    wI_tj ReLU(qI_tj . kI_s)`` and attends, softmax as usual, over the
+    ``topk`` keys of largest ``I`` (all of them while ``t < topk``).
+
+    Served through the page pool with a THIRD pool beside K and V: each
+    token's roped ``kI``, ``(P + 1, ps, 128)`` (its ``Di`` lanes padded to
+    one tile).  A decode step writes K, V and ``kI``, scores its row's
+    ``kI`` over its pages, selects the exact top ``topk`` and gathers only
+    those K and V rows (``ops.sparse_attention``).  A longer input is a
+    prefill FROM POSITION 0 over its own tokens, ``block`` queries at a
+    time (``sparse_prefill``)."""
+
+    def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int = 128, rope_theta: float = 1e7,
+                 eps: float = 1e-6, index_heads: int = 16,
+                 index_dim: int = 64, index_rope_dim: int = 32,
+                 topk: int = 2048, block: int = 512):
+        super().__init__(embed_dim, num_heads, num_kv_heads, head_dim,
+                         None, True, rope_theta, eps, True)
+        self.index_heads = index_heads
+        self.index_dim = index_dim
+        self.index_rope_dim = index_rope_dim
+        self.topk = int(topk)
+        self.block = int(block)
+
+    def init_params(self, rng):
+        k0, k1 = jax.random.split(rng)
+        params = super().init_params(k0)
+        ks = jax.random.split(k1, 3)
+        e, hi, di = self.embed_dim, self.index_heads, self.index_dim
+
+        def w(k, out):
+            return jax.random.normal(k, (out, e)) * e ** -0.5
+
+        params.update(
+            wq_index=w(ks[0], hi * di), wk_index=w(ks[1], di),
+            w_index=w(ks[2], hi),
+            k_index_norm={"weight": jnp.ones((di,), jnp.float32),
+                          "bias": jnp.zeros((di,), jnp.float32)})
+        return params
+
+    def init_paged_cache(self, num_pages: int, page_size: int,
+                         dtype=jnp.float32):
+        """K and V as a full layer keeps them, and the indexer's keys
+        ``"ki"`` ``(num_pages + 1, page_size, 128)``, the trash page last
+        in all three."""
+        from bigdl_tpu.ops.attention import paged_pool_width
+        cache = super().init_paged_cache(num_pages, page_size, dtype)
+        cache["ki"] = jnp.zeros((num_pages + 1, page_size, paged_pool_width(
+            1, self.index_dim)), dtype)
+        return cache
+
+    def _index_rope(self, x, positions):
+        r = self.index_rope_dim
+        return jnp.concatenate([apply_rope(x[..., :r], positions,
+                                           self.rope_theta), x[..., r:]],
+                               axis=-1)
+
+    def _index_qk(self, params, x, positions):
+        """The indexer's queries (B, S, Hi, Di), the heads' weights (B, S,
+        Hi) float32 with both scales in, and the keys (B, S, Di)."""
+        b, s, _ = x.shape
+        hi, di = self.index_heads, self.index_dim
+        qi = _proj(x, params["wq_index"]).reshape(b, s, hi, di)
+        qi = self._index_rope(qi.transpose(0, 2, 1, 3),
+                              positions).transpose(0, 2, 1, 3)
+        ki = _proj(x, params["wk_index"]).astype(jnp.float32)
+        mu = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mu), axis=-1, keepdims=True)
+        norm = params["k_index_norm"]
+        ki = ((ki - mu) * jax.lax.rsqrt(var + self.eps)
+              * norm["weight"].astype(jnp.float32)
+              + norm["bias"].astype(jnp.float32)).astype(x.dtype)
+        ki = self._index_rope(ki[:, None], positions)[:, 0]
+        wi = _proj(x, params["w_index"]).astype(jnp.float32) \
+            * (hi * di) ** -0.5
+        return qi, wi, ki
+
+    def apply_decode_pages(self, params, x_t, cache, pages, pos, active):
+        """``GroupedQueryAttention.apply_decode_pages``'s contract with the
+        third pool (writes of inactive rows and unmapped positions go to
+        the trash page in all three).  One token a row reads its row's
+        ``kI`` over its pages, and of K and V only the rows it selected.
+        Returns (y (B, S, E), cache')."""
+        from bigdl_tpu.ops import sparse_attention as sa
+        from bigdl_tpu.ops.attention import paged_pool_dims
+        s = x_t.shape[1]
+        positions = jnp.asarray(pos)[:, None] + jnp.arange(s)    # (B, S)
+        with jax.named_scope("qkv"):
+            q, k, v = self._qkv(params, x_t, positions)
+        with jax.named_scope("index.qk"):
+            qi, wi, ki = self._index_qk(params, x_t, positions)
+        pages = jnp.asarray(pages, jnp.int32)
+        ps, trash = paged_pool_dims(cache["k"])[0], cache["k"].shape[0] - 1
+        phys, offs = _page_slots(pages, positions, ps, trash, active)
+        with jax.named_scope("kv.write"):
+            ck = _write_rows(cache["k"], k, phys, offs)
+            cv = _write_rows(cache["v"], v, phys, offs)
+            cki = _write_rows(cache["ki"], ki[:, None], phys, offs)
+        if s > 1:
+            o = sa.sparse_prefill(q, k, v, qi, wi, ki, self.topk,
+                                  self.scale, self.block)
+        else:
+            with jax.named_scope("indexer"):
+                scores = sa.paged_index_scores(qi[:, 0], wi[:, 0], cki,
+                                               pages, positions[:, 0])
+            with jax.named_scope("select"):
+                idx, chosen = sa.select_topk(scores, self.topk)
+            with jax.named_scope("attn.sparse"):
+                o = sa.gathered_attention(q[:, :, 0], ck, cv, pages, idx,
+                                          chosen, positions[:, 0],
+                                          self.scale,
+                                          self.num_kv_heads)[:, :, None]
+        return self._out(params, o.astype(x_t.dtype)), \
+            {"k": ck, "v": cv, "ki": cki}
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        """A whole sequence from position 0; nothing kept."""
+        from bigdl_tpu.ops.sparse_attention import sparse_prefill
+        b, s, _ = input.shape
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+        q, k, v = self._qkv(params, input, positions)
+        qi, wi, ki = self._index_qk(params, input, positions)
+        o = sparse_prefill(q, k, v, qi, wi, ki, self.topk, self.scale,
+                           self.block)
+        return self._out(params, o.astype(input.dtype)), state
